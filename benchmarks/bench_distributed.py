@@ -27,6 +27,8 @@ REPO = pathlib.Path(__file__).resolve().parent.parent
 def run(quick: bool = False):
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
+    # the child is a fake-device CPU census; the parent may hold the chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = str(REPO / "src") + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
     cmd = [sys.executable, "-m", "benchmarks._distributed_child"]

@@ -22,9 +22,12 @@ benchmarks (CI smoke target: validates the fused Pallas pipeline — both
 directions — and the comm cost model on CPU in a couple of minutes).
 """
 import argparse
+import os
 import subprocess
 import sys
 import time
+
+from repro.compile_cache import use_persistent_cache
 
 from . import (bench_exec_time, bench_speedup, bench_efficiency,
                bench_karpflatt, bench_flops, bench_comm, bench_roofline,
@@ -59,13 +62,17 @@ def main(argv=None):
                     help="run the interpret-mode kernel tests plus the "
                          "quick distributed comm benchmark and exit")
     args = ap.parse_args(argv)
+    use_persistent_cache()
     if args.smoke:
         # multidevice-marked tests are excluded (they pay a child
         # interpreter each and run in CI's dedicated multidevice job);
         # the quick distributed bench below is the multi-device signal
+        # the interpret-mode suite is a CPU target: keep its child off
+        # the chip
         rc = subprocess.call(
             [sys.executable, "-m", "pytest", "-q",
-             "-m", "not multidevice", *SMOKE_TESTS])
+             "-m", "not multidevice", *SMOKE_TESTS],
+            env={**os.environ, "JAX_PLATFORMS": "cpu"})
         if rc == 0:
             bench_distributed.run(quick=True)
             bench_grads.run(quick=True)

@@ -492,18 +492,44 @@ def simulate_metrics(n: int, ps, sp: SimParams = SimParams()) -> dict:
     return out
 
 
-# TPU v5e hardware constants (roofline; see launch/dryrun + roofline pkg).
-TPU_V5E_BF16_FLOPS = 197e12       # per chip
-TPU_V5E_HBM_BW = 819e9            # bytes/s
-TPU_V5E_ICI_BW = 50e9             # bytes/s per link
-TPU_V5E_ICI_LATENCY = 1e-6        # per collective round (s), order of mag
+@dataclass(frozen=True)
+class DevicePeaks:
+    """Published per-chip peaks of one accelerator."""
+    peak_flops: float                # bf16 FLOP/s
+    hbm_bw: float                    # bytes/s
+    hbm_bytes: float                 # HBM capacity
+    ici_bw: float                    # bytes/s per interconnect link
+
+
+# One peaks table, keyed by ``jax.Device.device_kind``.  Source: Google
+# Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB of HBM at
+# 819 GB/s, 1,600 Gbit/s of interchip interconnect (four 50 GB/s links).
+PEAKS = {
+    "TPU v5 lite": DevicePeaks(peak_flops=197e12, hbm_bw=819e9,
+                               hbm_bytes=16e9, ici_bw=50e9),
+}
+
+
+def device_peaks(device_kind: str) -> DevicePeaks:
+    """Peaks of ``device_kind``; a kind missing from :data:`PEAKS` is an
+    error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}") from None
+
+
+# The chip the cost models plan for (autotune ranking, scheme choice).
+V5E = device_peaks("TPU v5 lite")
+ICI_LATENCY = 1e-6                # per collective round (s), order of mag
 
 
 def pipelined_bytes_score(read_bytes: float, write_bytes: float,
                           flops: float, *, pipeline_depth: int = 1,
                           grid_steps: int = 1,
-                          flop_rate: float = TPU_V5E_BF16_FLOPS,
-                          hbm_bw: float = TPU_V5E_HBM_BW) -> float:
+                          flop_rate: float = V5E.peak_flops,
+                          hbm_bw: float = V5E.hbm_bw) -> float:
     """Roofline score (HBM-byte-equivalents) of a bound leaf program under
     DMA pipelining (DESIGN.md §16).
 
@@ -557,9 +583,9 @@ class GramCommCost:
     flops: float            # per-device MAC flops (incl. duplicated work)
     mem_input_factor: int   # input replication (c for bfs25d, else 1)
 
-    def time(self, *, alpha: float = TPU_V5E_ICI_LATENCY,
-             ici_bw: float = TPU_V5E_ICI_BW,
-             flop_rate: float = TPU_V5E_BF16_FLOPS) -> float:
+    def time(self, *, alpha: float = ICI_LATENCY,
+             ici_bw: float = V5E.ici_bw,
+             flop_rate: float = V5E.peak_flops) -> float:
         """alpha * rounds + bytes / bw + flops / rate."""
         return (alpha * self.messages + self.wire_bytes / ici_bw
                 + self.flops / flop_rate)
@@ -637,8 +663,8 @@ def rank_gram_schemes(m: int, n: int, *, rows: int = 1,
                       ring: int | None = None, rep: int | None = None,
                       dtype_bytes: int = 4,
                       out_bytes: int | None = None,
-                      alpha: float = TPU_V5E_ICI_LATENCY,
-                      ici_bw: float = TPU_V5E_ICI_BW,
+                      alpha: float = ICI_LATENCY,
+                      ici_bw: float = V5E.ici_bw,
                       flop_rate: float | None = None,
                       schemes=None) -> list[GramCommCost]:
     """Feasibility-agnostic ranking (cheapest modeled time first) of the
@@ -650,7 +676,7 @@ def rank_gram_schemes(m: int, n: int, *, rows: int = 1,
     wire term; schemes engage different device counts, so a mismatched
     rate would bias the ranking non-uniformly."""
     if flop_rate is None:
-        flop_rate = TPU_V5E_BF16_FLOPS * 2.0 / max(dtype_bytes, 2)
+        flop_rate = V5E.peak_flops * 2.0 / max(dtype_bytes, 2)
     if schemes is None:
         schemes = ["allreduce", "reducescatter"]
         if ring:

@@ -66,7 +66,6 @@ __all__ = [
     "distributed_gram", "ring_layout_coords", "assemble_ring_gram",
     "ring_stack_len", "feasible_schemes", "default_gram_axes",
     "scheme_fallback_chain", "shrink_mesh", "SCHEME_LADDER",
-    "shard_map_compat",
 ]
 
 # Degradation order for the serving layer's scheme fallback: most
@@ -75,33 +74,6 @@ __all__ = [
 # bandwidth optimality for fewer ways to fail (fewer collectives, fewer
 # axes involved).
 SCHEME_LADDER = ("bfs25d", "ring", "reducescatter", "allreduce")
-
-
-def shard_map_compat():
-    """``(shard_map, unchecked_kwargs)`` across jax versions.
-
-    Resolves the import location (``jax.shard_map`` vs the 0.4.x
-    experimental module) and the ``check_rep`` -> ``check_vma`` kwarg
-    rename *independently* — the import path does not imply the kwarg
-    set, so the kwarg is keyed on the function signature.  Single shared
-    shim for every shard_map call site in the repo.
-    """
-    import inspect
-    try:
-        from jax import shard_map as sm
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-    try:
-        params = inspect.signature(sm).parameters
-    except (TypeError, ValueError):
-        params = {}
-    if "check_vma" in params:
-        unchecked = {"check_vma": False}
-    elif "check_rep" in params:
-        unchecked = {"check_rep": False}
-    else:
-        unchecked = {}
-    return sm, unchecked
 
 
 # ---------------------------------------------------------------------------
@@ -148,7 +120,7 @@ def gram_ring(a_local: jax.Array, col_axis: str,
               row_axis: Optional[str] = None, *,
               levels=2, leaf: int = 256,
               variant: str = "strassen", mode: str = "auto",
-              out_dtype=None, axis_size: Optional[int] = None,
+              out_dtype=None,
               interpret: Optional[bool] = None) -> jax.Array:
     """Half-ring symmetric collective gram (beyond-paper TPU schedule).
 
@@ -162,17 +134,7 @@ def gram_ring(a_local: jax.Array, col_axis: str,
     entry s on device c is C[c, (c - s) % T] (lower-circulant layout; see
     ``ring_layout_coords``), already reduced over ``row_axis`` if given.
     """
-    # The ring length must be static (it drives the Python hop loop);
-    # jax.lax.axis_size is missing on older jax, so callers that know the
-    # mesh (distributed_gram) pass it explicitly.
-    if axis_size is not None:
-        T = axis_size
-    elif hasattr(jax.lax, "axis_size"):
-        T = jax.lax.axis_size(col_axis)
-    else:
-        raise ValueError(
-            "gram_ring needs a static ring length and this jax version has "
-            "no jax.lax.axis_size — pass axis_size=mesh.shape[col_axis]")
+    T = jax.lax.axis_size(col_axis)       # static: drives the hop loop
     c = jax.lax.axis_index(col_axis)
     n_loc = a_local.shape[1]
     half = T // 2
@@ -456,8 +418,6 @@ def distributed_gram(a: jax.Array, mesh: Mesh, *,
                          message count + per-device flops) and run the
                          cheapest.
     """
-    shard_map, unchecked = shard_map_compat()
-
     if scheme == "auto":
         from . import cost_model
         cands = feasible_schemes(a.shape[0], a.shape[1], mesh,
@@ -493,9 +453,9 @@ def distributed_gram(a: jax.Array, mesh: Mesh, *,
         # a profile (or HLO census) attributes traffic to the scheme the
         # cost model actually picked
         with jax.named_scope(f"gram_dist:{scheme}"):
-            return shard_map(
+            return jax.shard_map(
                 fn, mesh=mesh, in_specs=P(row_axis, None),
-                out_specs=out_spec, **unchecked,
+                out_specs=out_spec, check_vma=False,
             )(a)
 
     if scheme in ("ring", "bfs25d"):
@@ -509,7 +469,7 @@ def distributed_gram(a: jax.Array, mesh: Mesh, *,
                 return gram_ring(a_local, col_axis, row_axis,
                                  levels=levels, leaf=leaf, variant=variant,
                                  mode=mode, out_dtype=out_dtype,
-                                 axis_size=T, interpret=interpret)
+                                 interpret=interpret)
         else:
             if rep_axis is None:
                 raise ValueError("bfs25d scheme needs rep_axis")
@@ -523,13 +483,13 @@ def distributed_gram(a: jax.Array, mesh: Mesh, *,
                                    interpret=interpret)
 
         with jax.named_scope(f"gram_dist:{scheme}"):
-            stacks = shard_map(
+            stacks = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=P(row_axis, col_axis),
                 # stack: (half+1, n/T, n/T) per device -> gather cols of
                 # blocks
                 out_specs=P(None, None, col_axis),
-                **unchecked,
+                check_vma=False,
             )(a)
         if not assemble:
             return stacks        # production: circulant layout, sharded

@@ -430,8 +430,9 @@ def resolve_block_defaults(kind: str, m: int, n: int, dtype,
     """Fill ``None`` block sizes from the autotune cache (256 fallback).
 
     The hook through which ``kernels/ops.py`` / ``core`` consult the
-    cache: explicit caller values always win; a missing cache (or any
-    cache error) degrades to the historical hardcoded default.  Only
+    cache: explicit caller values always win; a missing or unreadable
+    cache file (``load_cache``) degrades to the historical hardcoded
+    default.  Only
     ``mode="fused"`` winners carry meaningful block sizes (blocking is a
     fused-kernel knob — reference entries hold placeholders), so other
     entries are ignored here.
@@ -440,10 +441,7 @@ def resolve_block_defaults(kind: str, m: int, n: int, dtype,
         return blocks
     best = None
     if kind in ("ata", "matmul", "ata_bwd", "aat", "rank_k"):
-        try:
-            best = lookup(m, n, dtype=jnp.dtype(dtype).name, kind=kind)
-        except Exception:
-            best = None
+        best = lookup(m, n, dtype=jnp.dtype(dtype).name, kind=kind)
         if best is not None and best.get("mode") != "fused":
             best = None
     return {k: int(v if v is not None

@@ -50,7 +50,7 @@ from ..obs import metrics as _metrics
 from ..obs import trace as _trace
 from ..core.distributed import (assemble_ring_gram, gram_bfs25d,
                                 gram_reducescatter, gram_ring,
-                                ring_stack_len, shard_map_compat)
+                                ring_stack_len)
 from ..core.strassen import AUTO_MAX_LEVELS, resolve_mode
 from ..core.symmetry import pack_tril, tril_vector_from_blocks, unpack_tril
 
@@ -345,7 +345,6 @@ def distributed_update(state: jax.Array, chunk: jax.Array, mesh: Mesh, *,
     for the ring family the chunk is also column-sharded (and, for
     ``bfs25d``, replicated over ``rep_axis`` — the 2.5D trade applies
     per chunk, so each update ships only ceil(half/c) permute hops)."""
-    shard_map, unchecked = shard_map_compat()
     spec = _state_spec(scheme, row_axis, col_axis)
 
     if scheme == "reducescatter":
@@ -361,8 +360,7 @@ def distributed_update(state: jax.Array, chunk: jax.Array, mesh: Mesh, *,
             if scheme == "ring":
                 delta = gram_ring(chunk_local, col_axis, row_axis,
                                   levels=levels, leaf=leaf, variant=variant,
-                                  mode=mode, out_dtype=stack.dtype,
-                                  axis_size=T)
+                                  mode=mode, out_dtype=stack.dtype)
             else:
                 if rep_axis is None:
                     raise ValueError("bfs25d streaming needs rep_axis")
@@ -374,8 +372,8 @@ def distributed_update(state: jax.Array, chunk: jax.Array, mesh: Mesh, *,
             return stack + delta
         chunk_spec = P(row_axis, col_axis)
 
-    return shard_map(body, mesh=mesh, in_specs=(spec, chunk_spec),
-                     out_specs=spec, **unchecked)(state, chunk)
+    return jax.shard_map(body, mesh=mesh, in_specs=(spec, chunk_spec),
+                         out_specs=spec, check_vma=False)(state, chunk)
 
 
 def distributed_finalize(state: jax.Array, mesh: Mesh, *,

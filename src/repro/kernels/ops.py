@@ -13,7 +13,6 @@ from __future__ import annotations
 
 import functools
 import logging
-import os
 
 import jax
 import jax.numpy as jnp
@@ -40,29 +39,21 @@ _INTERPRET_LOGGED: set = set()
 def _auto_interpret(interpret, site=None):
     """Resolve the ``interpret`` knob for one kernel call site.
 
-    Explicit arguments always win.  Otherwise the ``REPRO_INTERPRET``
-    env var ("1"/"true" forces interpret, "0"/"false" forces compiled)
-    overrides, then the per-backend default applies: compiled on TPU,
-    interpret on CPU/GPU where the kernels are unsupported.  Each
-    distinct (site, backend) decision is logged once.
+    An explicit argument wins; otherwise the platform decides: compiled
+    on TPU, interpret on CPU/GPU where the kernels are unsupported.
+    Each distinct (site, backend) decision is logged once.
     """
     if interpret is not None:
         return interpret
     backend = jax.default_backend()
-    env = os.environ.get("REPRO_INTERPRET", "").strip().lower()
-    if env in ("1", "true", "yes", "on"):
-        decision, why = True, "REPRO_INTERPRET override"
-    elif env in ("0", "false", "no", "off"):
-        decision, why = False, "REPRO_INTERPRET override"
-    else:
-        decision = backend not in _COMPILED_BACKENDS
-        why = ("native pallas lowering" if not decision
-               else "kernel unsupported off-TPU")
+    decision = backend not in _COMPILED_BACKENDS
     key = (site, backend, decision)
     if key not in _INTERPRET_LOGGED:
         _INTERPRET_LOGGED.add(key)
         _log.info("pallas interpret=%s at %s [backend=%s: %s]",
-                  decision, site or "<unnamed site>", backend, why)
+                  decision, site or "<unnamed site>", backend,
+                  "kernel unsupported off-TPU" if decision
+                  else "native pallas lowering")
     return decision
 
 
@@ -70,14 +61,12 @@ def _resolve_blocks(kind, m, n, dtype, **blocks):
     """Fill ``None`` block sizes from the gram autotune cache
     (``artifacts/autotune/gram_autotune.json``; see gram/autotune.py)
     instead of the historical hardcoded 256s.  Explicit values win; a
-    missing/broken cache degrades to 256."""
+    missing or unreadable cache file degrades to 256 (inside
+    ``autotune.load_cache``)."""
     if all(v is not None for v in blocks.values()):
         return blocks
-    try:
-        from ..gram.autotune import resolve_block_defaults
-        return resolve_block_defaults(kind, m, n, dtype, **blocks)
-    except Exception:
-        return {k: (256 if v is None else v) for k, v in blocks.items()}
+    from ..gram.autotune import resolve_block_defaults
+    return resolve_block_defaults(kind, m, n, dtype, **blocks)
 
 
 def _pad_to(x, mults):

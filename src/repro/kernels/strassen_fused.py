@@ -167,22 +167,26 @@ def _warn_fan_in_clamp(kind: str, variant: str, gram: str, requested: int,
     warnings.warn(
         f"fused {kind} schedule: levels={requested} (variant={variant!r}, "
         f"gram={gram!r}) exceeds the MAX_OPERAND_TERMS={MAX_OPERAND_TERMS} "
-        f"VMEM operand fan-in; clamped to levels={clamped}",
+        f"VMEM operand fan-in or the SMEM_TABLE_BYTES={SMEM_TABLE_BYTES} "
+        f"scalar-prefetch tables; clamped to levels={clamped}",
         stacklevel=3)
 
 
 def _fan_in_clamp(kind: str, levels: int, variant: str,
                   gram: str = "strassen") -> int:
-    """Clamp ``levels`` until the program's operand fan-in fits VMEM,
-    warning once per distinct clamp (the shape-driven clamp above this is
-    expected behaviour and stays silent).  ``rank_k`` shares the ``ata``
-    program, ``symm`` warns under its own name as before."""
+    """Clamp ``levels`` until the program's operand fan-in fits VMEM and
+    its lowered tables fit SMEM, warning once per distinct clamp (the
+    shape-driven clamp above this is expected behaviour and stays
+    silent).  ``rank_k`` shares the ``ata`` program, ``symm`` warns under
+    its own name as before."""
     prog_kind = "ata" if kind == "rank_k" else kind
     g = gram if prog_kind in ("ata", "aat") else "strassen"
     requested = levels
-    while levels > 0 and compile_program(prog_kind, levels, variant,
-                                         gram=g).max_terms \
-            > MAX_OPERAND_TERMS:
+    while levels > 0:
+        prog = compile_program(prog_kind, levels, variant, gram=g)
+        if prog.max_terms <= MAX_OPERAND_TERMS \
+                and _table_bytes(prog) <= SMEM_TABLE_BYTES:
+            break
         levels -= 1
     if levels < requested:
         _warn_fan_in_clamp(kind, variant, g, requested, levels)
@@ -382,18 +386,55 @@ def _bind(prog: LeafProgram, *, n_out, n_tj, q_i, q_j, n_k, bi, bj, bc,
 
 
 # ---------------------------------------------------------------------------
-# Scalar-prefetch tables: the program lowered to arrays indexed by
-# (leaf destination, contribution slot[, term slot]) — int32 index
-# tables, float32 coefficient tables (rational gram-algebra coefficients
-# like dps's +-1/2, +-1/4 must survive lowering).  Empty slots carry
-# coefficient 0 (the kernel skips them) and index block (0, 0) (a
-# harmless fetch).  Uniform across kinds: coeff + (row, col, coeff) per
-# side + the right-side trans table (per-term mirrors only ever occur on
+# Scalar-prefetch tables: the program lowered to flat 1-D arrays indexed by
+# (leaf destination, contribution slot[, term slot]) — ``_slot`` and
+# ``_term`` give the offsets.  Flat, because SMEM pads the last dimension
+# of a multi-dimensional array to 128 words: a 3-D (n_dest, n_c, tmax)
+# table at ata levels 3 holds 18 KiB of data in 576 KiB of SMEM.  Per
+# side, one int32 word packs a term's (row, col[, mirror]) and one
+# float32 table holds its coefficient (rational gram-algebra
+# coefficients like dps's +-1/2, +-1/4 must survive lowering).  Empty
+# slots carry coefficient 0 (the kernel skips them) and index block
+# (0, 0) (a harmless fetch).  Per-term mirrors only ever occur on
 # tri-stored right operands; left per-term trans is asserted unused at
 # lowering — the left side's transposes are whole-operand OperandSpec
 # flags, and transposed gram destinations were normalized into
-# side-swapped contributions at the IR layer).
+# side-swapped contributions at the IR layer.
 # ---------------------------------------------------------------------------
+
+# packed term word: mirror << 30 | row << 15 | col
+_IDX_BITS = 15
+_IDX_MASK = (1 << _IDX_BITS) - 1
+
+# SMEM the scalar-prefetch tables may take.  A v5e TensorCore has 1 MiB of
+# SMEM, which also holds the kernel's own scalars; matmul/symm at levels 3
+# (the deepest programs the fan-in clamp keeps) need 528 KiB.
+SMEM_TABLE_BYTES = 768 * 1024
+
+
+def _table_bytes(prog: LeafProgram) -> int:
+    """SMEM bytes of the lowered tables: per (destination, contribution)
+    slot one sign word plus, per term and side, an index and a
+    coefficient word."""
+    return 4 * prog.n_dests() * prog.max_contributions \
+        * (1 + 4 * prog.max_terms)
+
+
+def _slot(ld, c, spec):
+    """Flat offset of (destination, contribution) in the sign table."""
+    return ld * spec.n_c + c
+
+
+def _term(ld, c, p, spec):
+    """Flat offset of term ``p`` of (destination, contribution)."""
+    return (ld * spec.n_c + c) * spec.tmax + p
+
+
+def _unpack(word):
+    """Packed term word -> (row, col, mirror)."""
+    return ((word >> _IDX_BITS) & _IDX_MASK, word & _IDX_MASK,
+            word >> (2 * _IDX_BITS))
+
 
 @functools.lru_cache(maxsize=None)
 def _program_tables(kind: str, levels: int, variant: str,
@@ -403,25 +444,24 @@ def _program_tables(kind: str, levels: int, variant: str,
                            trans_a=trans_a, trans_b=trans_b)
     n_dest, n_c, tmax = prog.n_dests(), prog.max_contributions, \
         prog.max_terms
+    assert max(prog.blocks_m, prog.blocks_k, prog.blocks_n) <= _IDX_MASK
     sign = np.zeros((n_dest, n_c), np.float32)
-    lrow = np.zeros((n_dest, n_c, tmax), np.int32)
-    lcol = np.zeros_like(lrow)
+    lidx = np.zeros((n_dest, n_c, tmax), np.int32)
     lsgn = np.zeros((n_dest, n_c, tmax), np.float32)
-    rrow = np.zeros_like(lrow)
-    rcol = np.zeros_like(lrow)
+    ridx = np.zeros_like(lidx)
     rsgn = np.zeros_like(lsgn)
-    rtrn = np.zeros_like(lrow)
     for (di, dj), contribs in prog.by_dest().items():
         ld = prog.dest_index(di, dj)
         for s, contrib in enumerate(contribs):
             sign[ld, s] = contrib.sign
             for p, (r, c, sg, tr) in enumerate(contrib.left):
                 assert tr == 0, "per-term left transposes are not lowered"
-                lrow[ld, s, p], lcol[ld, s, p], lsgn[ld, s, p] = r, c, sg
+                lidx[ld, s, p] = r << _IDX_BITS | c
+                lsgn[ld, s, p] = sg
             for q, (r, c, sg, tr) in enumerate(contrib.right):
-                rrow[ld, s, q], rcol[ld, s, q] = r, c
-                rsgn[ld, s, q], rtrn[ld, s, q] = sg, tr
-    return sign, lrow, lcol, lsgn, rrow, rcol, rsgn, rtrn
+                ridx[ld, s, q] = tr << (2 * _IDX_BITS) | r << _IDX_BITS | c
+                rsgn[ld, s, q] = sg
+    return tuple(t.reshape(-1) for t in (sign, lidx, lsgn, ridx, rsgn))
 
 
 # a re-registered algebra table must invalidate the lowered tables too —
@@ -448,31 +488,83 @@ def _dest_ld(gi, gj, spec: _Spec):
     return di * spec.blocks_j + dj
 
 
-def _tri_term_coords(rrow_ref, rcol_ref, rtrn_ref, ld, c, qt, spec, k, jq):
-    """Conceptual global tile coords (gr, gc) of a tri-stored right term.
+def _tri_term_coords(ridx_ref, ld, c, qt, spec, k, jq):
+    """Conceptual global tile coords (gr, gc) and mirror flag of a
+    tri-stored right term.
 
-    Program-mirrored terms (rtrn == 1) store the transposed leaf, so
+    Program-mirrored terms (mirror == 1) store the transposed leaf, so
     their within-leaf offsets swap; diagonal leaves straddle the stored
     triangle at tile granularity, handled downstream by max/min +
     transpose."""
-    t = rtrn_ref[ld, c, qt]
-    gr = rrow_ref[ld, c, qt] * spec.q_j + jnp.where(t != 0, jq, k)
-    gc = rcol_ref[ld, c, qt] * spec.q_j + jnp.where(t != 0, k, jq)
-    return gr, gc
+    row, col, t = _unpack(ridx_ref[_term(ld, c, qt, spec)])
+    gr = row * spec.q_j + jnp.where(t != 0, jq, k)
+    gc = col * spec.q_j + jnp.where(t != 0, k, jq)
+    return gr, gc, t
 
 
-def _signed_sum(refs, sgn_ref, ld, c):
-    """Sum[p] sgn[p] * refs[p], formed in fp32 in VMEM (never in HBM)."""
-    acc = None
-    for p, ref in enumerate(refs):
-        term = ref[...].astype(jnp.float32) * sgn_ref[ld, c, p].astype(
-            jnp.float32)
-        acc = term if acc is None else acc + term
-    return acc
+def _left_block(lidx_ref, ld, c, p, k, gi, spec):
+    """Block index of left term ``p`` — shared by the depth-1 index maps
+    and the depth>=2 in-kernel DMAs."""
+    row, col, _ = _unpack(lidx_ref[_term(ld, c, p, spec)])
+    if spec.left_trans:
+        # stored leaf is (contraction, out_i)
+        return row * spec.n_k + k, col * spec.q_i + gi % spec.q_i
+    return row * spec.q_i + gi % spec.q_i, col * spec.n_k + k
 
 
-def _leaf_kernel(sign_ref, lrow_ref, lcol_ref, lsgn_ref,
-                 rrow_ref, rcol_ref, rsgn_ref, rtrn_ref, *refs,
+def _right_block(ridx_ref, ld, c, q, k, jq, spec):
+    """Block index of right term ``q`` (see :func:`_left_block`)."""
+    if spec.right_tri:
+        gr, gc, _ = _tri_term_coords(ridx_ref, ld, c, q, spec, k, jq)
+        # the mirror, folded into the index map: always fetch the stored
+        # lower-triangle tile
+        fr = jnp.maximum(gr, gc)
+        fc = jnp.minimum(gr, gc)
+        return fr * (fr + 1) // 2 + fc, 0
+    row, col, _ = _unpack(ridx_ref[_term(ld, c, q, spec)])
+    if spec.right_trans:
+        # stored leaf is (out_j, contraction)
+        return row * spec.q_j + jq, col * spec.n_k + k
+    return row * spec.n_k + k, col * spec.q_j + jq
+
+
+def _right_sum(tiles, ridx_ref, rsgn_ref, ld, c, k, jq, spec):
+    """Signed sum of the right operand's gathered tiles, in fp32 in VMEM
+    (never in HBM), with the tri-stored mirrors and the whole-side
+    transpose applied."""
+    right = None
+    for qt, tile in enumerate(tiles):
+        tile = tile.astype(jnp.float32)
+        if spec.right_tri:
+            gr, gc, t = _tri_term_coords(ridx_ref, ld, c, qt, spec, k, jq)
+            # the index map fetched the stored (max, min) tile;
+            # transpose in VMEM whenever the conceptual read was above
+            # the diagonal or the term itself was mirrored
+            tile = jnp.where((t != 0) | (gr < gc), tile.T, tile)
+            if spec.diag_sym:
+                # the S + S^t operand: diagonal tiles double
+                tile = jnp.where(gr == gc, tile + tile.T, tile)
+        term = tile * rsgn_ref[_term(ld, c, qt, spec)].astype(jnp.float32)
+        right = term if right is None else right + term
+    if spec.right_trans and not spec.right_tri:
+        right = right.T
+    return right
+
+
+def _left_sum(tiles, lsgn_ref, ld, c, spec):
+    """Signed sum of the left operand's gathered tiles (see
+    :func:`_right_sum`)."""
+    left = None
+    for p, tile in enumerate(tiles):
+        term = tile.astype(jnp.float32) \
+            * lsgn_ref[_term(ld, c, p, spec)].astype(jnp.float32)
+        left = term if left is None else left + term
+    # whole-side transposes flip the gathered sum once —
+    # (sum s_p X_p)^t = sum s_p X_p^t, one transpose per gather.
+    return left.T if spec.left_trans else left
+
+
+def _leaf_kernel(sign_ref, lidx_ref, lsgn_ref, ridx_ref, rsgn_ref, *refs,
                  spec: _Spec):
     tmax = spec.tmax
     l_refs = refs[:tmax]
@@ -483,7 +575,7 @@ def _leaf_kernel(sign_ref, lrow_ref, lcol_ref, lsgn_ref,
     gi, gj = _decode_out(t, spec)
     ld = _dest_ld(gi, gj, spec)
     jq = gj % spec.q_j
-    sgn = sign_ref[ld, c]
+    sgn = sign_ref[_slot(ld, c, spec)]
 
     @pl.when((c == 0) & (k == 0))
     def _init():
@@ -496,31 +588,9 @@ def _leaf_kernel(sign_ref, lrow_ref, lcol_ref, lsgn_ref,
 
     @pl.when(sgn != 0)
     def _accumulate():
-        # whole-side transposes flip the gathered sum once —
-        # (sum s_p X_p)^t = sum s_p X_p^t, one transpose per gather.
-        left = _signed_sum(l_refs, lsgn_ref, ld, c)
-        if spec.left_trans:
-            left = left.T
-        if spec.right_tri:
-            right = None
-            for qt, ref in enumerate(r_refs):
-                gr, gc = _tri_term_coords(rrow_ref, rcol_ref, rtrn_ref,
-                                          ld, c, qt, spec, k, jq)
-                tile = ref[...].astype(jnp.float32)
-                # the index map fetched the stored (max, min) tile;
-                # transpose in VMEM whenever the conceptual read was
-                # above the diagonal or the term itself was mirrored
-                mirrored = (rtrn_ref[ld, c, qt] != 0) | (gr < gc)
-                tile = jnp.where(mirrored, tile.T, tile)
-                if spec.diag_sym:
-                    # the S + S^t operand: diagonal tiles double
-                    tile = jnp.where(gr == gc, tile + tile.T, tile)
-                term = tile * rsgn_ref[ld, c, qt].astype(jnp.float32)
-                right = term if right is None else right + term
-        else:
-            right = _signed_sum(r_refs, rsgn_ref, ld, c)
-            if spec.right_trans:
-                right = right.T
+        left = _left_sum([r[...] for r in l_refs], lsgn_ref, ld, c, spec)
+        right = _right_sum([r[...] for r in r_refs], ridx_ref, rsgn_ref,
+                           ld, c, k, jq, spec)
         contrib = sgn.astype(jnp.float32) * jnp.dot(
             left, right, preferred_element_type=jnp.float32)
         acc_ref[...] += contrib.astype(acc_ref.dtype)
@@ -530,9 +600,8 @@ def _leaf_kernel(sign_ref, lrow_ref, lcol_ref, lsgn_ref,
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
-def _pipelined_kernel(sign_ref, lrow_ref, lcol_ref, lsgn_ref,
-                      rrow_ref, rcol_ref, rsgn_ref, rtrn_ref, *refs,
-                      spec: _Spec, l_shape, r_shape):
+def _pipelined_kernel(sign_ref, lidx_ref, lsgn_ref, ridx_ref, rsgn_ref,
+                      *refs, spec: _Spec, l_shape, r_shape):
     """Depth>=2 executor body: one grid step per output tile; the
     (contribution, K) sweep runs in-kernel behind a revolving-buffer
     manual-DMA pipeline (DESIGN.md §16).
@@ -561,29 +630,6 @@ def _pipelined_kernel(sign_ref, lrow_ref, lcol_ref, lsgn_ref,
     ld = _dest_ld(gi, gj, spec)
     jq = gj % spec.q_j
 
-    # the same index arithmetic as the depth-1 BlockSpec maps, evaluated
-    # in-kernel on the scalar-prefetch tables (block indices -> element
-    # offsets via the tile edges)
-    def left_block(p, c, k):
-        if spec.left_trans:
-            return (lrow_ref[ld, c, p] * n_k + k,
-                    lcol_ref[ld, c, p] * spec.q_i + gi % spec.q_i)
-        return (lrow_ref[ld, c, p] * spec.q_i + gi % spec.q_i,
-                lcol_ref[ld, c, p] * n_k + k)
-
-    def right_block(q, c, k):
-        if spec.right_tri:
-            gr, gc = _tri_term_coords(rrow_ref, rcol_ref, rtrn_ref,
-                                      ld, c, q, spec, k, jq)
-            fr = jnp.maximum(gr, gc)
-            fc = jnp.minimum(gr, gc)
-            return (fr * (fr + 1) // 2 + fc, 0)
-        if spec.right_trans:
-            return (rrow_ref[ld, c, q] * spec.q_j + jq,
-                    rcol_ref[ld, c, q] * n_k + k)
-        return (rrow_ref[ld, c, q] * n_k + k,
-                rcol_ref[ld, c, q] * spec.q_j + jq)
-
     def _copies(s):
         """The 2*tmax async tile copies of step ``s`` (start and wait
         must describe the identical transfers)."""
@@ -591,13 +637,13 @@ def _pipelined_kernel(sign_ref, lrow_ref, lcol_ref, lsgn_ref,
         c, k = s // n_k, s % n_k
         cps = []
         for p in range(tmax):
-            br, bc_ = left_block(p, c, k)
+            br, bc_ = _left_block(lidx_ref, ld, c, p, k, gi, spec)
             cps.append(pltpu.make_async_copy(
                 left_hbm.at[pl.ds(br * l_shape[0], l_shape[0]),
                             pl.ds(bc_ * l_shape[1], l_shape[1])],
                 l_bufs.at[slot, p], l_sems.at[slot, p]))
         for q in range(tmax):
-            br, bc_ = right_block(q, c, k)
+            br, bc_ = _right_block(ridx_ref, ld, c, q, k, jq, spec)
             cps.append(pltpu.make_async_copy(
                 right_hbm.at[pl.ds(br * r_shape[0], r_shape[0]),
                              pl.ds(bc_ * r_shape[1], r_shape[1])],
@@ -629,37 +675,14 @@ def _pipelined_kernel(sign_ref, lrow_ref, lcol_ref, lsgn_ref,
 
         _wait(s)
         c, k = s // n_k, s % n_k
-        sgn = sign_ref[ld, c]
+        sgn = sign_ref[_slot(ld, c, spec)]
 
         @pl.when(sgn != 0)
         def _accumulate():
-            left = None
-            for p in range(tmax):
-                term = l_bufs[slot, p].astype(jnp.float32) \
-                    * lsgn_ref[ld, c, p].astype(jnp.float32)
-                left = term if left is None else left + term
-            if spec.left_trans:
-                left = left.T
-            if spec.right_tri:
-                right = None
-                for qt in range(tmax):
-                    gr, gc = _tri_term_coords(rrow_ref, rcol_ref, rtrn_ref,
-                                              ld, c, qt, spec, k, jq)
-                    tile = r_bufs[slot, qt].astype(jnp.float32)
-                    mirrored = (rtrn_ref[ld, c, qt] != 0) | (gr < gc)
-                    tile = jnp.where(mirrored, tile.T, tile)
-                    if spec.diag_sym:
-                        tile = jnp.where(gr == gc, tile + tile.T, tile)
-                    term = tile * rsgn_ref[ld, c, qt].astype(jnp.float32)
-                    right = term if right is None else right + term
-            else:
-                right = None
-                for qt in range(tmax):
-                    term = r_bufs[slot, qt].astype(jnp.float32) \
-                        * rsgn_ref[ld, c, qt].astype(jnp.float32)
-                    right = term if right is None else right + term
-                if spec.right_trans:
-                    right = right.T
+            left = _left_sum([l_bufs[slot, p] for p in range(tmax)],
+                             lsgn_ref, ld, c, spec)
+            right = _right_sum([r_bufs[slot, q] for q in range(tmax)],
+                               ridx_ref, rsgn_ref, ld, c, k, jq, spec)
             contrib = sgn.astype(jnp.float32) * jnp.dot(
                 left, right, preferred_element_type=jnp.float32)
             acc_ref[...] += contrib.astype(acc_ref.dtype)
@@ -695,7 +718,31 @@ def _execute_pipelined(spec: _Spec, tables, left, right, out_dtype,
     """Depth>=2 ``pallas_call`` site: grid = output tiles only; the
     operands stay in HBM/ANY and the kernel streams their tiles through
     revolving VMEM buffers with manual async copies (DMA semaphores),
-    overlapping the next step's fetch with the current MXU work."""
+    overlapping the next step's fetch with the current MXU work.
+
+    Pallas cannot batch a kernel whose operands stay in HBM, so under
+    ``vmap`` (the engine's slot batch) the batch elements run the kernel
+    one after another in a ``lax.map``."""
+    operands = (left, right) if c_in is None else (left, right, c_in)
+
+    @jax.custom_batching.custom_vmap
+    def run(*ops):
+        return _pipelined_call(spec, tables, ops, out_dtype, interpret)
+
+    @run.def_vmap
+    def _run_batched(axis_size, in_batched, *ops):
+        def one(batched):
+            it = iter(batched)
+            return run(*[next(it) if b else x
+                         for x, b in zip(ops, in_batched)])
+        return jax.lax.map(one, [x for x, b in zip(ops, in_batched)
+                                 if b]), True
+
+    return run(*operands)
+
+
+def _pipelined_call(spec: _Spec, tables, operands, out_dtype, interpret):
+    left, right = operands[:2]
     n_tab = len(tables)
     depth, tmax = spec.pipeline_depth, spec.tmax
     l_shape, r_shape = _operand_shapes(spec)
@@ -705,12 +752,10 @@ def _execute_pipelined(spec: _Spec, tables, left, right, out_dtype,
             return (t, 0)
         return (t // spec.n_tj, t % spec.n_tj)
 
-    in_specs = [pl.BlockSpec(memory_space=pltpu.ANY),
-                pl.BlockSpec(memory_space=pltpu.ANY)]
-    operands = [left, right]
+    in_specs = [pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY)]
     if spec.accumulate:
         in_specs.append(pl.BlockSpec((spec.bi, spec.bj), out_map))
-        operands.append(c_in)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=n_tab,
@@ -736,7 +781,7 @@ def _execute_pipelined(spec: _Spec, tables, left, right, out_dtype,
             # only the output-tile axis remains a grid axis and its
             # tiles are independent -> megacore partitions freely; the
             # sequential sweep lives inside the kernel body.
-            compiler_params=pltpu.TPUCompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel",)),
             interpret=interpret,
         )(*tables, *operands)
@@ -765,37 +810,17 @@ def _execute(spec: _Spec, left: jax.Array, right: jax.Array,
     n_tab = len(tables)
 
     def left_map(p):
-        def index_map(t, c, k, *tabs):
-            lrow, lcol = tabs[1], tabs[2]
+        def index_map(t, c, k, sign, lidx, *tabs):
             gi, gj = _decode_out(t, spec)
-            ld = _dest_ld(gi, gj, spec)
-            if spec.left_trans:
-                # stored leaf is (contraction, out_i)
-                return (lrow[ld, c, p] * spec.n_k + k,
-                        lcol[ld, c, p] * spec.q_i + gi % spec.q_i)
-            return (lrow[ld, c, p] * spec.q_i + gi % spec.q_i,
-                    lcol[ld, c, p] * spec.n_k + k)
+            return _left_block(lidx, _dest_ld(gi, gj, spec), c, p, k, gi,
+                               spec)
         return index_map
 
     def right_map(q):
-        def index_map(t, c, k, *tabs):
-            rrow, rcol, rtrn = tabs[4], tabs[5], tabs[7]
+        def index_map(t, c, k, sign, lidx, lsgn, ridx, rsgn):
             gi, gj = _decode_out(t, spec)
-            ld = _dest_ld(gi, gj, spec)
-            if spec.right_tri:
-                gr, gc = _tri_term_coords(rrow, rcol, rtrn, ld, c, q,
-                                          spec, k, gj % spec.q_j)
-                # the mirror, folded into the index map: always fetch
-                # the stored lower-triangle tile
-                fr = jnp.maximum(gr, gc)
-                fc = jnp.minimum(gr, gc)
-                return (fr * (fr + 1) // 2 + fc, 0)
-            if spec.right_trans:
-                # stored leaf is (out_j, contraction)
-                return (rrow[ld, c, q] * spec.q_j + gj % spec.q_j,
-                        rcol[ld, c, q] * spec.n_k + k)
-            return (rrow[ld, c, q] * spec.n_k + k,
-                    rcol[ld, c, q] * spec.q_j + gj % spec.q_j)
+            return _right_block(ridx, _dest_ld(gi, gj, spec), c, q, k,
+                                gj % spec.q_j, spec)
         return index_map
 
     def out_map(t, c, k, *tabs):
@@ -835,7 +860,7 @@ def _execute(spec: _Spec, left: jax.Array, right: jax.Array,
             # output tiles (t) are independent -> megacore partitions
             # them; the (contribution, K) sweep carries the VMEM
             # accumulator and must stay sequential per tile.
-            compiler_params=pltpu.TPUCompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary", "arbitrary")),
             interpret=interpret,
         )(*tables, *operands)

@@ -91,7 +91,7 @@ def syrk_packed(
         scratch_shapes=[pltpu.VMEM((bn, bn), jnp.float32)],
         # output tiles (t) are independent -> megacore can partition them;
         # the K sweep carries the VMEM accumulator and stays sequential.
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(a, a)

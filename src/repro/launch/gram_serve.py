@@ -39,6 +39,7 @@ from pathlib import Path
 
 import numpy as np
 
+from ..compile_cache import use_persistent_cache
 from ..gram import GramEngine, autotune_bucket, bucket_shape
 from ..obs import metrics as obs_metrics
 from ..obs import trace as obs_trace
@@ -129,6 +130,7 @@ def main(argv=None):
                          "measured/predicted ratio leaves "
                          "[1/theta, theta]")
     args = ap.parse_args(argv)
+    use_persistent_cache()
     levels = args.levels if args.levels == "auto" else int(args.levels)
     verify = args.verify if args.verify in ("off", "finite") \
         else int(args.verify)

@@ -3,16 +3,10 @@ jax device state (device count is locked on first jax init)."""
 from __future__ import annotations
 
 import jax
-
-try:                                   # jax >= 0.5
-    from jax.sharding import AxisType
-except ImportError:                    # 0.4.x: meshes are Auto by default
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def make_mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 

@@ -552,8 +552,6 @@ def apply_moe(p, x, cfg: ModelConfig):
             {k_: v for k_, v in p.items() if k_ != "shared"},
             x.reshape(b * s, d), cfg)
     else:
-        from ..core.distributed import shard_map_compat
-        shard_map, unchecked = shard_map_compat()
         mesh = pol.mesh
         dp = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
         tp_size = mesh.shape[tp]
@@ -600,11 +598,11 @@ def apply_moe(p, x, cfg: ModelConfig):
                 aux = jax.lax.pmean(aux, dp)
             return out.reshape(tb, ts, d), aux
 
-        out, aux = shard_map(
+        out, aux = jax.shard_map(
             body, mesh=mesh,
             in_specs=(x_spec, pspecs),
             out_specs=(x_spec, P()),
-            **unchecked,
+            check_vma=False,
         )(x, pl)
         out = out.reshape(b * s, d)
         aux = aux.reshape(())

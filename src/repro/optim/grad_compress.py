@@ -50,12 +50,6 @@ def int8_dequantize(q: jax.Array, scale: jax.Array) -> jax.Array:
     return q.astype(jnp.float32) * scale
 
 
-def _axis_size(axis: str):
-    # jax.lax.axis_size is missing on older jax and the size is only a
-    # divisor here, so the traced psum(1) form is version-portable
-    return getattr(jax.lax, "axis_size", lambda a: jax.lax.psum(1, a))(axis)
-
-
 def _int8_leaf(g, r, axis: str, n):
     """One leaf of the int8 error-feedback reduction: (mean grad, residual).
 
@@ -79,7 +73,7 @@ def compressed_psum(grads, axis: str, ef: ErrorFeedback):
     Must run inside shard_map with ``axis`` in scope.  Returns
     (mean-reduced fp32 grads, new ErrorFeedback).
     """
-    n = _axis_size(axis)
+    n = jax.lax.axis_size(axis)
     flat_g, treedef = jax.tree.flatten(grads)
     flat_r = treedef.flatten_up_to(ef.residual)
     outs = [_int8_leaf(g, r, axis, n) for g, r in zip(flat_g, flat_r)]
@@ -121,7 +115,7 @@ def lowrank_psum(grads, axis: str, ef: ErrorFeedback, *, rank: int = 8,
     path.  Must run inside shard_map with ``axis`` in scope.  Returns
     (mean-reduced fp32 grads, new ErrorFeedback).
     """
-    n_dev = _axis_size(axis)
+    n_dev = jax.lax.axis_size(axis)
 
     def leaf_fn(g, r):
         m_n = g.shape

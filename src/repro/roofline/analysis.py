@@ -1,4 +1,5 @@
-"""Three-term roofline from dry-run artifacts (TPU v5e constants).
+"""Three-term roofline from dry-run artifacts (TPU v5e peaks from
+``core.cost_model.PEAKS``).
 
 Per (arch x shape x mesh) cell, from the compiled dry-run:
   compute term    = HLO_FLOPs_per_device / peak_FLOP/s
@@ -19,20 +20,9 @@ from __future__ import annotations
 import glob
 import json
 import os
-from dataclasses import dataclass
 from typing import Dict, List, Optional
 
-
-@dataclass(frozen=True)
-class HW:
-    """TPU v5e."""
-    peak_flops: float = 197e12       # bf16 / chip
-    hbm_bw: float = 819e9            # bytes/s
-    ici_bw: float = 50e9             # bytes/s per link
-    hbm_bytes: float = 16e9          # capacity / chip
-
-
-V5E = HW()
+from ..core.cost_model import V5E, DevicePeaks
 
 
 def model_flops(artifact: dict) -> Optional[float]:
@@ -49,7 +39,7 @@ def model_flops(artifact: dict) -> Optional[float]:
     return per_token * tokens
 
 
-def roofline_terms(artifact: dict, hw: HW = V5E) -> Dict:
+def roofline_terms(artifact: dict, hw: DevicePeaks = V5E) -> Dict:
     if artifact.get("status") != "ok":
         return {"cell": artifact.get("cell"), "status": artifact.get("status")}
     cost = artifact["cost"]

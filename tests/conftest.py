@@ -55,6 +55,7 @@ def _multidevice_runner(nodeid: str, n: int, timeout: float):
     def run(**_fixtures):
         env = dict(os.environ)
         env[_CHILD_ENV] = str(n)
+        env["JAX_PLATFORMS"] = "cpu"       # forced host devices, by design
         env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                             + f" --xla_force_host_platform_device_count={n}"
                             ).strip()

@@ -116,3 +116,13 @@ def test_invalid_scheme_and_missing_ring_raise():
         gram_comm_cost("ring", 64, 32, rows=2)          # ring size missing
     with pytest.raises(ValueError):
         gram_comm_cost("bfs25d", 64, 32, rows=2)
+
+
+def test_device_peaks_keyed_by_device_kind():
+    """One published peaks table, keyed by ``jax.Device.device_kind``; an
+    unknown kind is an error, never a silent v5e default."""
+    from repro.core.cost_model import device_peaks
+    v5e = device_peaks("TPU v5 lite")
+    assert (v5e.peak_flops, v5e.hbm_bw) == (197e12, 819e9)
+    with pytest.raises(KeyError, match="TPU v6e"):
+        device_peaks("TPU v6e")

@@ -70,8 +70,10 @@ def test_scheme_parity_8dev(scheme, multidevice_count):
             for levels, dtype, tol in cases:
                 a = jax.random.normal(
                     jax.random.PRNGKey(0), (m, n)).astype(dtype)
-                got = distributed_gram(a, mesh, scheme=scheme,
-                                       levels=levels, leaf=8, **kw)
+                # jitted: an eager shard_map dispatches the recursion's
+                # ops one by one, ~20x slower for the same program
+                got = jax.jit(lambda x: distributed_gram(
+                    x, mesh, scheme=scheme, levels=levels, leaf=8, **kw))(a)
                 got = np.asarray(jax.device_get(got), np.float64)
                 want = _oracle(a)
                 err = np.abs(got - want).max() / (np.abs(want).max() + 1e-9)

@@ -237,6 +237,28 @@ def test_fan_in_clamp_warns_once_with_clamped_value():
                     if "MAX_OPERAND_TERMS" in str(w.message)]
 
 
+def test_smem_table_clamp():
+    """The scalar-prefetch tables live in SMEM: bb322 matmul at levels 3
+    fits the operand fan-in but not the table budget, so it clamps; the
+    defaults' deepest programs (ata/aat levels 3, dps levels 2, Strassen
+    matmul/symm levels 3) keep their levels."""
+    import warnings as _warnings
+    from repro.core.leaf_ir import compile_program
+    from repro.kernels import strassen_fused as sf
+
+    prog = compile_program("matmul", 3, "bb322")
+    assert prog.max_terms <= sf.MAX_OPERAND_TERMS
+    assert sf._table_bytes(prog) > sf.SMEM_TABLE_BYTES
+    with _warnings.catch_warnings():
+        _warnings.simplefilter("ignore")
+        assert sf._fan_in_clamp("matmul", 3, "bb322") == 2
+    for kind, levels, gram in [("ata", 3, "strassen"), ("aat", 3, "strassen"),
+                               ("ata", 2, "dps"), ("rank_k", 3, "strassen"),
+                               ("matmul", 3, "strassen"),
+                               ("symm", 3, "strassen")]:
+        assert sf._fan_in_clamp(kind, levels, "strassen", gram) == levels
+
+
 def test_dimension_semantics_parity_interpret():
     """All three Pallas grids now declare dimension_semantics (output
     tiles "parallel", contribution/K sweeps "arbitrary") so TPU megacore

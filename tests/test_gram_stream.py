@@ -95,14 +95,12 @@ def test_sharded_streaming_8dev(multidevice_count):
     forced-host devices (ported from the old ad-hoc subprocess script to
     the ``multidevice`` marker)."""
     from jax.sharding import PartitionSpec as P
-    from repro.core.distributed import shard_map_compat
 
     P_DEV, m, n = 8, 128, 64
     a = jax.random.normal(jax.random.PRNGKey(0), (m, n), jnp.float32)
     want = _oracle(a)
 
     mesh = jax.make_mesh((P_DEV,), ("data",))
-    shard_map, unchecked = shard_map_compat()
 
     def stream(chunks):
         # per-device: fold row-sharded chunks into the block-row shard of C
@@ -113,10 +111,10 @@ def test_sharded_streaming_8dev(multidevice_count):
 
     chunk_bounds = [(0, 48), (48, 128)]   # ragged: 48 and 80 rows
     chunks = tuple(a[lo:hi] for lo, hi in chunk_bounds)
-    got = shard_map(
+    got = jax.shard_map(
         stream, mesh=mesh,
         in_specs=(P("data", None),),     # pytree prefix: every chunk by rows
-        out_specs=P("data", None), **unchecked,
+        out_specs=P("data", None), check_vma=False,
     )(chunks)
     got = np.asarray(jax.device_get(got), np.float64)
     assert got.shape == (n, n)

@@ -140,11 +140,9 @@ def test_lowrank_psum_error_feedback_invariant():
     gradient, and tall 2-D leaves take the low-rank path (residual is the
     orthogonal complement, not a quantization residual)."""
     from repro.optim import lowrank_psum
-    from repro.core.distributed import shard_map_compat
     from jax.sharding import PartitionSpec as P
 
     mesh = jax.make_mesh((1,), ("pod",))
-    shard_map, unchecked = shard_map_compat()
     g = {"tall": jax.random.normal(jax.random.PRNGKey(5), (64, 8)),
          "bias": jnp.linspace(-1, 1, 16)}
     ef = ErrorFeedback.init(g)
@@ -154,8 +152,9 @@ def test_lowrank_psum_error_feedback_invariant():
                                    rank=4, levels=1, leaf=4)
         return out, new_ef.residual
 
-    out, resid = shard_map(body, mesh=mesh, in_specs=(P(), P()),
-                           out_specs=(P(), P()), **unchecked)(g, ef.residual)
+    out, resid = jax.shard_map(body, mesh=mesh, in_specs=(P(), P()),
+                               out_specs=(P(), P()),
+                               check_vma=False)(g, ef.residual)
     # emitted + residual == true gradient, leafwise (EF invariant, 1 dev)
     for k in g:
         np.testing.assert_allclose(np.asarray(out[k] + resid[k]),

@@ -77,8 +77,6 @@ def test_parallel_model_matches_reference(multidevice_count):
     assert err < 4e-2, f"stationary decode mismatch: {err}"
 
     # 3) compressed psum over a 2-group axis
-    from repro.core.distributed import shard_map_compat
-    shard_map, unchecked = shard_map_compat()
     from repro.optim.grad_compress import compressed_psum, ErrorFeedback
     g = jax.random.normal(key, (2, 64), jnp.float32)  # row per "pod"
 
@@ -88,10 +86,10 @@ def test_parallel_model_matches_reference(multidevice_count):
         red, ef = compressed_psum(grads, "data", ef)
         return red["w"][None], ef.residual["w"][None]
 
-    red, _resid = shard_map(
+    red, _resid = jax.shard_map(
         body, mesh=mesh, in_specs=P(("data",), None),
         out_specs=(P(("data",), None), P(("data",), None)),
-        **unchecked)(g)
+        check_vma=False)(g)
     exact = np.asarray(g, np.float32).mean(0)
     got = np.asarray(jax.device_get(red), np.float32)[0]
     # int8 quantization error bound: scale/2 per participant

@@ -102,3 +102,18 @@ def test_serve_cli_end_to_end():
     finished = main(["--arch", "qwen2.5-3b", "--requests", "2",
                      "--slots", "2", "--max-seq", "64", "--max-new", "4"])
     assert len(finished) == 2
+
+
+def test_chip_smoke_refuses_cpu():
+    """chip_smoke.py measures the chip or nothing: with no TPU it exits
+    non-zero before any phase and prints no result line."""
+    import os
+    import subprocess
+    import sys
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    out = subprocess.run([sys.executable, str(REPO / "chip_smoke.py")],
+                         env=env, cwd=REPO, capture_output=True, text=True,
+                         timeout=120)
+    assert out.returncode != 0
+    assert "no TPU" in out.stdout
+    assert '"ok"' not in out.stdout
