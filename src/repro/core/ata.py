@@ -213,7 +213,9 @@ def _ata_rec(a, levels, leaf, variant, syrk, base_matmul):
 
 def ata_full(a: jax.Array, **kw) -> jax.Array:
     """Full symmetric ``a.T @ a`` (mirrors C21 into C12, per the paper)."""
-    return symmetrize_from_lower(ata(a, **kw))
+    lower = ata(a, **kw)
+    with jax.named_scope("gram:mirror"):
+        return symmetrize_from_lower(lower)
 
 
 def ata_levels_for(m: int, n: int, leaf: int = DEFAULT_LEAF) -> int:

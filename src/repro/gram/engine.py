@@ -1531,10 +1531,7 @@ class GramEngine:
                                        "gram.engine.operand", clean)
                 exe = self._local_executable(key, cfg)
                 t_x0 = time.perf_counter()
-                if _trace.tracing_enabled():
-                    with jax.profiler.TraceAnnotation(f"gram_exec:{b}"):
-                        out = np.asarray(exe(jnp.asarray(stack)))
-                else:
+                with _trace.span("exec", bucket=b, path="local"):
                     out = np.asarray(exe(jnp.asarray(stack)))
                 t_x1 = time.perf_counter()
                 self._m_exec.observe(t_x1 - t_x0, engine=self.engine_label,

@@ -999,7 +999,8 @@ def _fused_ata_packed_exec(
     out_dtype = (jnp.promote_types(a.dtype, jnp.float32)
                  if out_dtype is None else jnp.dtype(out_dtype))
     if (M, N) != (m, n):
-        a = jnp.pad(a, ((0, M - m), (0, N - n)))
+        with jax.named_scope("gram:pad"):
+            a = jnp.pad(a, ((0, M - m), (0, N - n)))
     if operand_dtype is not None:
         # the quantization step: operand tiles are STORED (and DMA'd) at
         # the low precision; every compute upcasts tile-wise to fp32
@@ -1062,9 +1063,10 @@ def _fused_ata_dense(a, levels, variant, gram, bk, bn, out_dtype, interpret,
     packed, n_pad = _fused_ata_packed_exec(
         a, levels, variant, gram, bk, bn, out_dtype, interpret,
         pipeline_depth, operand_dtype, acc_dtype)
-    dense = unpack_tril_blocks(packed, n_pad, bn, symmetrize=False)
-    # diagonal blocks are computed full — drop their upper halves
-    return jnp.tril(dense)[:n, :n]
+    with jax.named_scope("gram:unpack"):
+        dense = unpack_tril_blocks(packed, n_pad, bn, symmetrize=False)
+        # diagonal blocks are computed full — drop their upper halves
+        return jnp.tril(dense)[:n, :n]
 
 
 def _fused_ata_dense_fwd(a, levels, variant, gram, bk, bn, out_dtype,

@@ -1,6 +1,6 @@
 """Observability: the flight recorder for the Gram service (DESIGN.md §14).
 
-Three layers, one timeline:
+Three layers, one timeline, and two process hooks:
 
 - ``trace``   — request-scoped spans + instant events in a bounded ring
                 buffer; Chrome trace-event JSON (Perfetto-loadable) and
@@ -11,13 +11,16 @@ Three layers, one timeline:
 - ``drift``   — online cost-model drift detection: EWMA of the
                 measured/predicted ratio per (bucket, winner), findings
                 when a bucket leaves the ``[1/theta, theta]`` band.
+- ``hooks``   — JAX compiles (registry counters; ``compile`` records
+                while tracing) and garbage-collector passes (``gc``
+                spans while tracing), installed at import.
 
 The paper's claims are quantitative (2/7·n^log2(7) products, minimal
 messages); ``cost_model`` / ``ata_traffic_model`` predict them, and this
 package makes the prediction-vs-reality comparison a continuously
 running, inspectable part of the serving stack.
 """
-from . import drift, metrics, trace  # noqa: F401
+from . import drift, hooks, metrics, trace  # noqa: F401
 from .drift import DriftDetector, DriftFinding  # noqa: F401
 from .metrics import (  # noqa: F401
     MetricsRegistry, counter, gauge, histogram, get_registry,
@@ -28,8 +31,10 @@ from .trace import (  # noqa: F401
     tracing_enabled,
 )
 
+hooks.install()
+
 __all__ = [
-    "trace", "metrics", "drift",
+    "trace", "metrics", "drift", "hooks",
     "Tracer", "get_tracer", "set_tracer", "span", "instant", "add_span",
     "tracing_enabled",
     "MetricsRegistry", "counter", "gauge", "histogram", "get_registry",

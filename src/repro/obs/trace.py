@@ -11,8 +11,8 @@ Design constraints, in order:
    goes through the module-level helpers (:func:`span`, :func:`instant`,
    :func:`add_span`), which are a single attribute check when the tracer
    is off — no allocation, no lock, no timestamp read.  The default
-   tracer starts disabled; chaos drills and ``--trace-out`` runs enable
-   it.
+   tracer starts disabled; traced benchmark windows (``bench/run.py
+   --trace 1``) and ``gram_serve --trace-out`` enable it.
 2. **Request-scoped.**  A span carries a ``trace_id`` (the serving layer
    threads the request uid); children inherit it from the enclosing span
    (per-thread stack), so one request's submit → queue-wait → execute →
@@ -26,25 +26,52 @@ Design constraints, in order:
    explicit start/end timestamps after the fact — Chrome trace events
    carry their own ``ts``/``dur``, so the export is indistinguishable
    from a live span.
+5. **On the device trace's clock.**  While the tracer is enabled, a live
+   span also enters a ``jax.profiler.TraceAnnotation`` named
+   ``gram_exec:<name>`` (the name only; attributes stay in the ring), so
+   a profiler session shows it beside the device's operations.  Every
+   other record (instants, :func:`add_span`, :func:`instant_at`) leaves
+   a near-zero-length ``gram_exec:<name>`` mark on the profiler's
+   timeline at the moment it is recorded, and keeps the ``perf_counter``
+   read taken with it (:attr:`TraceEvent.mark`).  :func:`place` maps the
+   ring onto a trace's clock from those marks.
+
+Two process hooks (:mod:`repro.obs.hooks`, installed at ``import
+repro.obs``) feed this timeline: each JAX compile phase (trace, lowering,
+backend compile or cache load) becomes a ``compile`` record with its
+``phase``, ``fun_name`` and duration, and each pass of Python's garbage
+collector a live ``gc`` span with its ``generation`` and ``collected``.
 
 All timestamps are ``time.perf_counter()`` (monotonic); the export
 rebases them to microseconds since the tracer's epoch.
 """
 from __future__ import annotations
 
+import bisect
 import itertools
 import json
 import threading
 import time
-from collections import deque
+from collections import defaultdict, deque
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
+
+import numpy as np
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "TraceEvent", "Span", "Tracer", "get_tracer", "set_tracer",
     "span", "instant", "add_span", "tracing_enabled",
-    "disabled_hook_cost",
+    "disabled_hook_cost", "MIRROR", "clock_offset", "place",
 ]
+
+# Prefix of the program's spans and marks on the profiler's timeline.
+MIRROR = "gram_exec:"
+# How far a mark's profiler timestamp may sit from its ``perf_counter``
+# read, once the clocks' offset is known, and still be taken as its own.
+MATCH_NS = 20_000
+# Marks of one name that vote on the clocks' offset, spread over the ring.
+VOTERS = 64
 
 
 @dataclass
@@ -60,6 +87,9 @@ class TraceEvent:
     trace_id: Optional[int]      # request uid (or None for engine-level)
     tid: int                     # thread ident
     attrs: Dict[str, Any] = field(default_factory=dict)
+    # perf_counter seconds at which the record's ``gram_exec:<name>``
+    # annotation opened on the profiler's timeline (t0 for live spans)
+    mark: Optional[float] = None
 
     @property
     def duration_s(self) -> float:
@@ -75,7 +105,7 @@ class Span:
     """
 
     __slots__ = ("_tracer", "name", "span_id", "parent_id", "trace_id",
-                 "attrs", "t0")
+                 "attrs", "t0", "_mirror")
 
     def __init__(self, tracer: "Tracer", name: str,
                  trace_id: Optional[int], attrs: Dict[str, Any]):
@@ -86,6 +116,7 @@ class Span:
         self.trace_id = trace_id
         self.attrs = attrs
         self.t0 = 0.0
+        self._mirror = None
 
     def annotate(self, **attrs) -> "Span":
         self.attrs.update(attrs)
@@ -99,11 +130,14 @@ class Span:
             if self.trace_id is None:
                 self.trace_id = parent.trace_id
         stack.append(self)
+        self._mirror = TraceAnnotation(MIRROR + self.name)
+        self._mirror.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         t1 = time.perf_counter()
+        self._mirror.__exit__(None, None, None)
         stack = self._tracer._stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -115,7 +149,7 @@ class Span:
             name=self.name, ph="X", t0=self.t0, t1=t1,
             span_id=self.span_id, parent_id=self.parent_id,
             trace_id=self.trace_id, tid=threading.get_ident(),
-            attrs=self.attrs))
+            attrs=self.attrs, mark=self.t0))
         return False
 
 
@@ -150,7 +184,9 @@ class Tracer:
         self.enabled = enabled
         self.capacity = capacity
         self._buf: deque = deque(maxlen=capacity)
-        self._lock = threading.Lock()
+        # re-entrant: the GC hook records from inside whatever Python code
+        # the collector interrupted, which may hold this lock
+        self._lock = threading.RLock()
         self._ids = itertools.count(1)
         self._local = threading.local()
         self.epoch = time.perf_counter()
@@ -192,11 +228,12 @@ class Tracer:
         parent = stack[-1] if stack else None
         if trace_id is None and parent is not None:
             trace_id = parent.trace_id
-        now = time.perf_counter()
+        now = _mark(name)
         self._record(TraceEvent(
             name=name, ph="i", t0=now, t1=now, span_id=self._next_id(),
             parent_id=parent.span_id if parent else None,
-            trace_id=trace_id, tid=threading.get_ident(), attrs=attrs))
+            trace_id=trace_id, tid=threading.get_ident(), attrs=attrs,
+            mark=now))
 
     def instant_at(self, name: str, t: float, *,
                    trace_id: Optional[int] = None, **attrs) -> None:
@@ -210,7 +247,7 @@ class Tracer:
         self._record(TraceEvent(
             name=name, ph="i", t0=t, t1=t, span_id=self._next_id(),
             parent_id=None, trace_id=trace_id,
-            tid=threading.get_ident(), attrs=attrs))
+            tid=threading.get_ident(), attrs=attrs, mark=_mark(name)))
 
     def add_span(self, name: str, t0: float, t1: float, *,
                  trace_id: Optional[int] = None, **attrs) -> None:
@@ -222,7 +259,7 @@ class Tracer:
         self._record(TraceEvent(
             name=name, ph="X", t0=t0, t1=max(t1, t0),
             span_id=self._next_id(), parent_id=None, trace_id=trace_id,
-            tid=threading.get_ident(), attrs=attrs))
+            tid=threading.get_ident(), attrs=attrs, mark=_mark(name)))
 
     # -- introspection / export -------------------------------------------
     def events(self) -> List[TraceEvent]:
@@ -295,6 +332,84 @@ class Tracer:
     def write_jsonl(self, path) -> None:
         with open(path, "w") as f:
             f.write(self.to_jsonl())
+
+
+def _mark(name: str) -> float:
+    """Leave a near-zero-length ``gram_exec:<name>`` annotation on the
+    profiler's timeline (a no-op outside a profiler session) and return
+    the ``perf_counter`` read taken inside it."""
+    with TraceAnnotation(MIRROR + name):
+        return time.perf_counter()
+
+
+def _ns(t: float) -> int:
+    return int(round(t * 1e9))
+
+
+def _mirrored(host) -> Dict[str, List[int]]:
+    """Sorted start times of a trace's ``gram_exec:*`` host spans, by the
+    ring name they mirror."""
+    out: Dict[str, List[int]] = defaultdict(list)
+    for h in host:
+        if h.name.startswith(MIRROR):
+            out[h.name[len(MIRROR):]].append(int(h.start))
+    return {k: sorted(v) for k, v in out.items()}
+
+
+def clock_offset(events, host) -> Optional[int]:
+    """Nanoseconds to add to a ``perf_counter`` reading to put it on the
+    clock of a profiler trace, from the ring's marks and the trace's
+    ``gram_exec:*`` host spans (objects with ``name`` and ``start``,
+    e.g. ``bench.trace_reduce.Event``); None when the trace holds none of
+    the ring's marks.
+
+    Each mark pairs with the span of its name that it opened, so the
+    offset every true pair agrees on, within ``MATCH_NS``, is the one
+    most pairs of a mark and a same-named span agree on.  Up to
+    ``VOTERS`` marks of each name, spread over the ring, vote."""
+    spans = _mirrored(host)
+    diffs = []
+    for name, starts in spans.items():
+        marks = sorted(_ns(e.mark) for e in events
+                       if e.name == name and e.mark is not None)
+        if not marks:
+            continue
+        marks = marks[::max(1, len(marks) // VOTERS)]
+        diffs.append(np.subtract.outer(np.asarray(starts, np.int64),
+                                       np.asarray(marks, np.int64)).ravel())
+    if not diffs:
+        return None
+    d = np.sort(np.concatenate(diffs))
+    ends = np.searchsorted(d, d + MATCH_NS, side="right")
+    i = int(np.argmax(ends - np.arange(len(d))))
+    return int(np.median(d[i:ends[i]]))
+
+
+def place(events, host) -> List[Tuple[TraceEvent, int, int]]:
+    """``(event, start_ns, end_ns)`` of each ring event on the clock of
+    the profiler trace whose host spans are ``host``; [] when the trace
+    holds none of the ring's marks.
+
+    A record whose own mark is in the trace takes that mark's offset
+    (the mark's trace time minus its ``perf_counter`` read); any other
+    takes :func:`clock_offset`."""
+    off = clock_offset(events, host)
+    if off is None:
+        return []
+    spans = _mirrored(host)
+    out = []
+    for e in events:
+        d = off
+        starts = spans.get(e.name)
+        if e.mark is not None and starts:
+            want = _ns(e.mark) + off
+            j = bisect.bisect_left(starts, want)
+            near = min(starts[max(j - 1, 0):j + 1],
+                       key=lambda s: abs(s - want))
+            if abs(near - want) <= MATCH_NS:
+                d = near - _ns(e.mark)
+        out.append((e, _ns(e.t0) + d, _ns(e.t1) + d))
+    return out
 
 
 def _jsonable(v):

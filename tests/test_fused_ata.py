@@ -382,3 +382,17 @@ def test_acceptance_512_parity_and_hbm_ratio():
     misaligned = ata_traffic_model(257, 511, levels=2, bk=64, bn=64)
     assert misaligned["padded_shape"] == (512, 512)
     assert misaligned["intermediate_bytes"] == 512 * 512 * 4
+
+
+@pytest.mark.parametrize("scope", ["gram:pad", "gram:unpack", "gram:mirror"])
+def test_fused_stages_carry_named_scopes_in_compiled_hlo(scope):
+    """The fused Gram's non-kernel device stages keep their names in the
+    compiled program's ``op_name`` metadata, which profilers show."""
+    a = jnp.ones((40, 36), jnp.float32)     # pads to the 16-tile grid
+    f = jax.jit(lambda x: ata_full(x, levels=1, mode="fused", block=16,
+                                   interpret=True))
+    hlo = f.lower(a).compile().as_text()
+    tagged = [line for line in hlo.splitlines()
+              if f"/{scope}/" in line.partition('op_name="')[2]
+              .partition('"')[0]]
+    assert tagged, f"no instruction of the compiled program is in {scope}"

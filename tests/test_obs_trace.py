@@ -291,3 +291,175 @@ def test_chaos_trace_has_complete_chains_faults_and_rung_transitions():
     names = {rec["name"] for rec in doc["traceEvents"]}
     assert "rung_transition" in names
     assert any(n.startswith("fault:") for n in names)
+
+
+# ---------------------------------------------------------------------------
+# The device trace's clock: mirrored spans, marks, and the process hooks
+# ---------------------------------------------------------------------------
+
+def _profile(tmp_path, body):
+    """Run ``body()`` under a CPU profiler session; the session's host
+    events as [(name, start_ns, end_ns)]."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    return [(e.name, int(e.start_ns), int(e.end_ns))
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events]
+
+
+class _Host:
+    def __init__(self, name, start, end):
+        self.name, self.start, self.end = name, start, end
+
+
+@pytest.fixture
+def no_auto_gc():
+    """Only the test's own ``gc.collect()`` runs the collector."""
+    import gc
+    was = gc.isenabled()
+    gc.disable()
+    yield
+    if was:
+        gc.enable()
+
+
+@pytest.fixture
+def fresh_registry():
+    from repro.obs import metrics
+    saved = metrics.get_registry()
+    reg = metrics.set_registry(None)
+    yield reg
+    metrics.set_registry(saved)
+
+
+def test_every_record_keeps_its_mark():
+    t = Tracer(enabled=True)
+    with t.span("live"):
+        t.instant("now")
+    t.add_span("late", 1.0, 2.0)
+    t.instant_at("then", 1.5)
+    evs = {e.name: e for e in t.events()}
+    assert evs["live"].mark == evs["live"].t0
+    assert evs["now"].mark == evs["now"].t0
+    # after-the-fact records are marked when recorded, not when they
+    # happened
+    assert evs["late"].mark > 2.0 and evs["then"].mark > 1.5
+
+
+def test_gc_pass_is_one_gc_span_only_while_enabled(no_auto_gc):
+    import gc
+    tracer = trace.set_tracer(Tracer(enabled=True))
+    gc.collect()
+    (ev,) = [e for e in tracer.events() if e.name == "gc"]
+    assert ev.ph == "X" and ev.attrs["generation"] == 2
+    assert ev.attrs["collected"] >= 0 and ev.duration_s >= 0
+    # switched off the way bench/run.py does it: the attribute, not
+    # set_tracer
+    tracer.enabled = False
+    gc.collect()
+    assert [e.name for e in tracer.events()] == ["gc"]
+    trace.set_tracer(None)
+    gc.collect()
+    assert len(trace.get_tracer()) == 0
+
+
+def test_fresh_jit_records_compile_phases_and_counters_once(fresh_registry):
+    import jax
+    import jax.numpy as jnp
+    tracer = trace.set_tracer(Tracer(enabled=True))
+
+    def fresh(x):
+        return x * 5.0 - 2.0
+    f = jax.jit(fresh)
+    x = jnp.ones((3, 17))
+    f(x).block_until_ready()
+    phases = {e.attrs["phase"] for e in tracer.events()
+              if e.name == "compile"
+              and "fresh" in e.attrs["fun_name"]}
+    assert {"trace", "lower", "backend"} <= phases
+    compiles = fresh_registry.counter("jax_compiles_total")
+    assert compiles.value(phase="backend") >= 1
+    assert fresh_registry.counter("jax_compile_seconds_total").value(
+        phase="backend") > 0
+    before = (len(tracer.events()), compiles.total())
+    f(x).block_until_ready()
+    assert (len([e for e in tracer.events() if e.name == "compile"]),
+            compiles.total()) == (
+        len([e for e in tracer.events()[:before[0]]
+             if e.name == "compile"]), before[1])
+    # the counters run with the tracer off too
+    trace.set_tracer(None)
+    jax.jit(lambda y: y - 7.0)(x).block_until_ready()
+    assert compiles.total() > before[1]
+    assert len(trace.get_tracer()) == 0
+
+
+def test_clock_offset_found_among_periodic_unmatched_marks():
+    """Records marked every 10 ms, the first third outside the profiler
+    session, each mark a few µs off its span: the offset every true pair
+    agrees on wins over the period's false ones."""
+    off = -63_000_000_123
+    evs = [trace.TraceEvent("exec", "X", 100.0 + i * 0.010,
+                            100.0 + i * 0.010 + 0.004, i, None, None, 0,
+                            mark=100.0 + i * 0.010 + 0.004000123)
+           for i in range(30)]
+    jitter = [1_500, -800, 2_200, 0, 900]
+    host = [_Host(trace.MIRROR + "exec",
+                  round(e.mark * 1e9) + off + jitter[i % 5], 0)
+            for i, e in enumerate(evs[10:])]
+    host.append(_Host("bench:call", 5, 9))
+    assert abs(trace.clock_offset(evs, host) - off) <= 2_200
+    placed = trace.place(evs, host)
+    assert [p[0] for p in placed] == evs
+    for (e, s, end), h in zip(placed[10:], host):
+        # a record whose mark is in the trace takes its own offset
+        assert s == round(e.t0 * 1e9) + h.start - round(e.mark * 1e9)
+        assert abs(end - s - 4_000_000) <= 1
+    for e, s, _ in placed[:10]:
+        assert abs(s - round(e.t0 * 1e9) - off) <= 2_200
+    assert trace.clock_offset(evs, host[-1:]) is None
+    assert trace.place(evs, []) == []
+
+
+def test_add_span_placed_on_the_profile_clock_within_1ms(tmp_path):
+    tracer = trace.set_tracer(Tracer(enabled=True))
+    import jax
+    marks = {}
+
+    def body():
+        with jax.profiler.TraceAnnotation("truth"):
+            marks["t0"] = time.perf_counter()
+            time.sleep(0.004)
+            marks["t1"] = time.perf_counter()
+        time.sleep(0.003)
+        trace.add_span("queue_wait", marks["t0"], marks["t1"], trace_id=1)
+    host = [_Host(*h) for h in _profile(tmp_path, body)]
+    (truth,) = [h for h in host if h.name == "truth"]
+    (late,) = [(s, e) for ev, s, e in trace.place(tracer.events(), host)
+               if ev.name == "queue_wait"]
+    assert abs(late[0] - truth.start) < 1_000_000
+    assert abs(late[1] - truth.end) < 1_000_000
+    assert any(h.name == "gram_exec:queue_wait" for h in host)
+
+
+def test_engine_execution_is_one_exec_span():
+    tracer = trace.set_tracer(Tracer(enabled=True))
+    eng = GramEngine(slots=2, levels=1, leaf=8, min_bucket=16)
+    eng.submit(np.ones((20, 12), np.float32))
+    assert len(eng.run_to_completion()) == 1
+    (ex,) = [e for e in tracer.events() if e.name == "exec"]
+    assert ex.attrs["path"] == "local" and ex.attrs["bucket"]
+    (batch,) = [e for e in tracer.events() if e.name == "batch"]
+    assert batch.t0 <= ex.t0 <= ex.t1 <= batch.t1
