@@ -40,8 +40,9 @@ transpose-folded matmul programs for matmul), with ``bwd="dense"``
 keeping the dense-dot baselines selectable for benchmarking.
 
 The analytic HBM traffic model is likewise IR-driven: :func:`_traffic`
-scores a bound :class:`_Spec` (reads = grid DMA tile fetches including
-the padded contribution slots, writes = one store per output tile), and
+scores a bound :class:`_Spec` (reads = the real tile fetches of the
+depth>=2 walk, with the padded grid beside them; writes = one store per
+output tile), and
 the per-kind models (``ata_traffic_model`` etc.) are thin geometry
 wrappers over it — the model shares the executor's binding code, so it
 cannot drift from the kernel's clamping/padding.
@@ -50,6 +51,7 @@ from __future__ import annotations
 
 import functools
 import math
+import operator
 import warnings
 from dataclasses import dataclass
 from typing import Optional
@@ -393,18 +395,26 @@ def _bind(prog: LeafProgram, *, n_out, n_tj, q_i, q_j, n_k, bi, bj, bc,
 # table at ata levels 3 holds 18 KiB of data in 576 KiB of SMEM.  Per
 # side, one int32 word packs a term's (row, col[, mirror]) and one
 # float32 table holds its coefficient (rational gram-algebra
-# coefficients like dps's +-1/2, +-1/4 must survive lowering).  Empty
-# slots carry coefficient 0 (the kernel skips them) and index block
-# (0, 0) (a harmless fetch).  Per-term mirrors only ever occur on
-# tri-stored right operands; left per-term trans is asserted unused at
-# lowering — the left side's transposes are whole-operand OperandSpec
-# flags, and transposed gram destinations were normalized into
-# side-swapped contributions at the IR layer.
+# coefficients like dps's +-1/2, +-1/4 must survive lowering).  Real
+# entries come first in every row; the padding behind them carries
+# coefficient 0 and indexes block (0, 0).  The depth-1 grid walk fetches
+# those padded slots through its BlockSpecs and multiplies them by 0; the
+# depth>=2 kernel reads the count table and never touches them: per
+# destination ``_dest_count`` words hold its real contributions, and per
+# (destination, contribution) one ``n_left << 16 | n_right`` word its
+# real terms.  Per-term mirrors only ever occur on tri-stored right
+# operands; left per-term trans is asserted unused at lowering — the left
+# side's transposes are whole-operand OperandSpec flags, and transposed
+# gram destinations were normalized into side-swapped contributions at
+# the IR layer.
 # ---------------------------------------------------------------------------
 
 # packed term word: mirror << 30 | row << 15 | col
 _IDX_BITS = 15
 _IDX_MASK = (1 << _IDX_BITS) - 1
+# packed term-count word: n_left << 16 | n_right
+_COUNT_BITS = 16
+_COUNT_MASK = (1 << _COUNT_BITS) - 1
 
 # SMEM the scalar-prefetch tables may take.  A v5e TensorCore has 1 MiB of
 # SMEM, which also holds the kernel's own scalars; matmul/symm at levels 3
@@ -414,10 +424,11 @@ SMEM_TABLE_BYTES = 768 * 1024
 
 def _table_bytes(prog: LeafProgram) -> int:
     """SMEM bytes of the lowered tables: per (destination, contribution)
-    slot one sign word plus, per term and side, an index and a
-    coefficient word."""
-    return 4 * prog.n_dests() * prog.max_contributions \
-        * (1 + 4 * prog.max_terms)
+    slot one sign word, one term-count word and, per term and side, an
+    index and a coefficient word; per destination one contribution-count
+    word."""
+    n_dest, n_c = prog.n_dests(), prog.max_contributions
+    return 4 * n_dest * (n_c * (2 + 4 * prog.max_terms) + 1)
 
 
 def _slot(ld, c, spec):
@@ -428,6 +439,18 @@ def _slot(ld, c, spec):
 def _term(ld, c, p, spec):
     """Flat offset of term ``p`` of (destination, contribution)."""
     return (ld * spec.n_c + c) * spec.tmax + p
+
+
+def _dest_count(ld, spec):
+    """Flat offset of a destination's real-contribution count in the
+    count table; its per-contribution term counts follow it."""
+    return ld * (spec.n_c + 1)
+
+
+def _term_counts(cnt_ref, ld, c, spec):
+    """(real left terms, real right terms) of (destination, contribution)."""
+    word = cnt_ref[_dest_count(ld, spec) + 1 + c]
+    return word >> _COUNT_BITS, word & _COUNT_MASK
 
 
 def _unpack(word):
@@ -450,10 +473,14 @@ def _program_tables(kind: str, levels: int, variant: str,
     lsgn = np.zeros((n_dest, n_c, tmax), np.float32)
     ridx = np.zeros_like(lidx)
     rsgn = np.zeros_like(lsgn)
+    counts = np.zeros((n_dest, 1 + n_c), np.int32)
     for (di, dj), contribs in prog.by_dest().items():
         ld = prog.dest_index(di, dj)
+        counts[ld, 0] = len(contribs)
         for s, contrib in enumerate(contribs):
             sign[ld, s] = contrib.sign
+            counts[ld, 1 + s] = (len(contrib.left) << _COUNT_BITS
+                                 | len(contrib.right))
             for p, (r, c, sg, tr) in enumerate(contrib.left):
                 assert tr == 0, "per-term left transposes are not lowered"
                 lidx[ld, s, p] = r << _IDX_BITS | c
@@ -461,7 +488,8 @@ def _program_tables(kind: str, levels: int, variant: str,
             for q, (r, c, sg, tr) in enumerate(contrib.right):
                 ridx[ld, s, q] = tr << (2 * _IDX_BITS) | r << _IDX_BITS | c
                 rsgn[ld, s, q] = sg
-    return tuple(t.reshape(-1) for t in (sign, lidx, lsgn, ridx, rsgn))
+    return tuple(t.reshape(-1)
+                 for t in (sign, lidx, lsgn, ridx, rsgn, counts))
 
 
 # a re-registered algebra table must invalidate the lowered tables too —
@@ -528,37 +556,53 @@ def _right_block(ridx_ref, ld, c, q, k, jq, spec):
     return row * spec.n_k + k, col * spec.q_j + jq
 
 
-def _right_sum(tiles, ridx_ref, rsgn_ref, ld, c, k, jq, spec):
-    """Signed sum of the right operand's gathered tiles, in fp32 in VMEM
-    (never in HBM), with the tri-stored mirrors and the whole-side
-    transpose applied."""
-    right = None
-    for qt, tile in enumerate(tiles):
-        tile = tile.astype(jnp.float32)
+def _signed_sum(term, tmax, n_terms=None):
+    """``term(0) + term(1) + ...`` in term order.
+
+    ``n_terms=None`` sums all ``tmax`` slots (the grid walk, whose padded
+    slots hold coefficient 0); otherwise only the first ``n_terms``, a
+    run-time count, so a padded slot — whose buffer holds stale data —
+    is neither read nor added.  The real terms are summed in the same
+    order either way, so both forms agree bit for bit on finite data."""
+    def upto(n):
+        return lambda: functools.reduce(
+            operator.add, [term(p) for p in range(n)])
+    if n_terms is None:
+        return upto(tmax)()
+    return jax.lax.switch(n_terms - 1,
+                          [upto(n) for n in range(1, tmax + 1)])
+
+
+def _right_sum(tile, ridx_ref, rsgn_ref, ld, c, k, jq, spec, n_terms=None):
+    """Signed sum of the right operand's gathered tiles (``tile(q)`` reads
+    term ``q``), in fp32 in VMEM (never in HBM), with the tri-stored
+    mirrors and the whole-side transpose applied."""
+    def term(qt):
+        t_ = tile(qt).astype(jnp.float32)
         if spec.right_tri:
             gr, gc, t = _tri_term_coords(ridx_ref, ld, c, qt, spec, k, jq)
             # the index map fetched the stored (max, min) tile;
             # transpose in VMEM whenever the conceptual read was above
             # the diagonal or the term itself was mirrored
-            tile = jnp.where((t != 0) | (gr < gc), tile.T, tile)
+            t_ = jnp.where((t != 0) | (gr < gc), t_.T, t_)
             if spec.diag_sym:
                 # the S + S^t operand: diagonal tiles double
-                tile = jnp.where(gr == gc, tile + tile.T, tile)
-        term = tile * rsgn_ref[_term(ld, c, qt, spec)].astype(jnp.float32)
-        right = term if right is None else right + term
+                t_ = jnp.where(gr == gc, t_ + t_.T, t_)
+        return t_ * rsgn_ref[_term(ld, c, qt, spec)].astype(jnp.float32)
+
+    right = _signed_sum(term, spec.tmax, n_terms)
     if spec.right_trans and not spec.right_tri:
         right = right.T
     return right
 
 
-def _left_sum(tiles, lsgn_ref, ld, c, spec):
+def _left_sum(tile, lsgn_ref, ld, c, spec, n_terms=None):
     """Signed sum of the left operand's gathered tiles (see
     :func:`_right_sum`)."""
-    left = None
-    for p, tile in enumerate(tiles):
-        term = tile.astype(jnp.float32) \
-            * lsgn_ref[_term(ld, c, p, spec)].astype(jnp.float32)
-        left = term if left is None else left + term
+    left = _signed_sum(
+        lambda p: tile(p).astype(jnp.float32)
+        * lsgn_ref[_term(ld, c, p, spec)].astype(jnp.float32),
+        spec.tmax, n_terms)
     # whole-side transposes flip the gathered sum once —
     # (sum s_p X_p)^t = sum s_p X_p^t, one transpose per gather.
     return left.T if spec.left_trans else left
@@ -588,8 +632,8 @@ def _leaf_kernel(sign_ref, lidx_ref, lsgn_ref, ridx_ref, rsgn_ref, *refs,
 
     @pl.when(sgn != 0)
     def _accumulate():
-        left = _left_sum([r[...] for r in l_refs], lsgn_ref, ld, c, spec)
-        right = _right_sum([r[...] for r in r_refs], ridx_ref, rsgn_ref,
+        left = _left_sum(lambda p: l_refs[p][...], lsgn_ref, ld, c, spec)
+        right = _right_sum(lambda q: r_refs[q][...], ridx_ref, rsgn_ref,
                            ld, c, k, jq, spec)
         contrib = sgn.astype(jnp.float32) * jnp.dot(
             left, right, preferred_element_type=jnp.float32)
@@ -601,25 +645,34 @@ def _leaf_kernel(sign_ref, lidx_ref, lsgn_ref, ridx_ref, rsgn_ref, *refs,
 
 
 def _pipelined_kernel(sign_ref, lidx_ref, lsgn_ref, ridx_ref, rsgn_ref,
-                      *refs, spec: _Spec, l_shape, r_shape):
+                      cnt_ref, *refs, spec: _Spec, l_shape, r_shape):
     """Depth>=2 executor body: one grid step per output tile; the
     (contribution, K) sweep runs in-kernel behind a revolving-buffer
     manual-DMA pipeline (DESIGN.md §16).
+
+    Real-entry walk: the tile's destination has ``n_real`` real
+    contributions (count table), so the sweep is ``n_real * n_k`` steps
+    long, and step ``s`` fetches, waits on and adds only its
+    contribution's real left and right terms.  The padded slots of the
+    program tables cost no DMA, no wait and no VPU add.  A term's start
+    and its wait sit under the identical predicate (``p < n_left``,
+    ``q < n_right``, read from the same count word): a wait without its
+    start would hang the core.
 
     Slot protocol: step ``s`` computes out of slot ``s % depth`` while
     the copies for step ``s + depth - 1`` stream into slot
     ``(s + depth - 1) % depth`` — the slot whose compute retired at step
     ``s - 1`` (the sweep is sequential per tile), so a buffer is never
     overwritten while in use.  The flattened step order
-    ``s = c * n_k + k`` reproduces the depth-1 grid walk (k fastest), so
-    the accumulation order — and therefore the result — is bit-exact vs
-    ``pipeline_depth=1``.  The epilogue contract is unchanged: the
-    accumulator is (c_in-)seeded before the sweep and stored exactly
-    once after it.
+    ``s = c * n_k + k`` reproduces the depth-1 grid walk (k fastest) and
+    the terms are summed in table order, so the accumulation order — and
+    therefore the result, on finite operands — is bit-exact vs
+    ``pipeline_depth=1``, whose padded slots only ever add +-0.  The
+    epilogue contract is unchanged: the accumulator is (c_in-)seeded
+    before the sweep and stored exactly once after it.
     """
     depth, tmax = spec.pipeline_depth, spec.tmax
     n_k = spec.n_k
-    n_steps = spec.n_c * n_k
     left_hbm, right_hbm = refs[0], refs[1]
     cin_ref = refs[2] if spec.accumulate else None
     o_ref = refs[3] if spec.accumulate else refs[2]
@@ -629,42 +682,48 @@ def _pipelined_kernel(sign_ref, lidx_ref, lsgn_ref, ridx_ref, rsgn_ref,
     gi, gj = _decode_out(t, spec)
     ld = _dest_ld(gi, gj, spec)
     jq = gj % spec.q_j
+    n_steps = cnt_ref[_dest_count(ld, spec)] * n_k
 
-    def _copies(s):
-        """The 2*tmax async tile copies of step ``s`` (start and wait
-        must describe the identical transfers)."""
+    def _copy(s, side, p):
+        """Async copy of term ``p`` of one side of step ``s``."""
         slot = s % depth
         c, k = s // n_k, s % n_k
-        cps = []
-        for p in range(tmax):
+        if side == 0:
             br, bc_ = _left_block(lidx_ref, ld, c, p, k, gi, spec)
-            cps.append(pltpu.make_async_copy(
+            return pltpu.make_async_copy(
                 left_hbm.at[pl.ds(br * l_shape[0], l_shape[0]),
                             pl.ds(bc_ * l_shape[1], l_shape[1])],
-                l_bufs.at[slot, p], l_sems.at[slot, p]))
-        for q in range(tmax):
-            br, bc_ = _right_block(ridx_ref, ld, c, q, k, jq, spec)
-            cps.append(pltpu.make_async_copy(
-                right_hbm.at[pl.ds(br * r_shape[0], r_shape[0]),
-                             pl.ds(bc_ * r_shape[1], r_shape[1])],
-                r_bufs.at[slot, q], r_sems.at[slot, q]))
-        return cps
+                l_bufs.at[slot, p], l_sems.at[slot, p])
+        br, bc_ = _right_block(ridx_ref, ld, c, p, k, jq, spec)
+        return pltpu.make_async_copy(
+            right_hbm.at[pl.ds(br * r_shape[0], r_shape[0]),
+                         pl.ds(bc_ * r_shape[1], r_shape[1])],
+            r_bufs.at[slot, p], r_sems.at[slot, p])
+
+    def _each_real_copy(s, op):
+        """``op`` ("start" or "wait") on every real term copy of step
+        ``s`` — the one predicate both ``_start`` and ``_wait`` go
+        through."""
+        counts = _term_counts(cnt_ref, ld, s // n_k, spec)
+        for side in (0, 1):
+            for p in range(tmax):
+                @pl.when(p < counts[side])
+                def _():
+                    getattr(_copy(s, side, p), op)()
 
     def _start(s):
-        for cp in _copies(s):
-            cp.start()
+        _each_real_copy(s, "start")
 
     def _wait(s):
-        for cp in _copies(s):
-            cp.wait()
+        _each_real_copy(s, "wait")
 
     if spec.accumulate:
         acc_ref[...] = cin_ref[...].astype(acc_ref.dtype)
     else:
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    for i in range(min(depth - 1, n_steps)):      # pipeline warm-up
-        _start(i)
+    for i in range(depth - 1):                     # pipeline warm-up
+        pl.when(i < n_steps)(functools.partial(_start, i))
 
     def body(s, carry):
         slot = s % depth
@@ -675,18 +734,14 @@ def _pipelined_kernel(sign_ref, lidx_ref, lsgn_ref, ridx_ref, rsgn_ref,
 
         _wait(s)
         c, k = s // n_k, s % n_k
-        sgn = sign_ref[_slot(ld, c, spec)]
-
-        @pl.when(sgn != 0)
-        def _accumulate():
-            left = _left_sum([l_bufs[slot, p] for p in range(tmax)],
-                             lsgn_ref, ld, c, spec)
-            right = _right_sum([r_bufs[slot, q] for q in range(tmax)],
-                               ridx_ref, rsgn_ref, ld, c, k, jq, spec)
-            contrib = sgn.astype(jnp.float32) * jnp.dot(
-                left, right, preferred_element_type=jnp.float32)
-            acc_ref[...] += contrib.astype(acc_ref.dtype)
-
+        n_left, n_right = _term_counts(cnt_ref, ld, c, spec)
+        left = _left_sum(lambda p: l_bufs[slot, p], lsgn_ref, ld, c, spec,
+                         n_left)
+        right = _right_sum(lambda q: r_bufs[slot, q], ridx_ref, rsgn_ref,
+                           ld, c, k, jq, spec, n_right)
+        contrib = sign_ref[_slot(ld, c, spec)].astype(jnp.float32) \
+            * jnp.dot(left, right, preferred_element_type=jnp.float32)
+        acc_ref[...] += contrib.astype(acc_ref.dtype)
         return carry
 
     jax.lax.fori_loop(0, n_steps, body, 0)
@@ -807,6 +862,8 @@ def _execute(spec: _Spec, left: jax.Array, right: jax.Array,
     if spec.pipeline_depth > 1:
         return _execute_pipelined(spec, tables, left, right, out_dtype,
                                   interpret, c_in)
+    # the grid walk visits every padded slot, so it takes no count table
+    tables = tables[:5]
     n_tab = len(tables)
 
     def left_map(p):
@@ -1477,26 +1534,57 @@ def fused_symm_matmul(
 # analytically.
 # ---------------------------------------------------------------------------
 
+def _dest_tiles(spec: _Spec, n_dest: int) -> np.ndarray:
+    """Output tiles of each leaf destination, in table order."""
+    if not spec.out_tri:
+        return np.full(n_dest, spec.q_i * spec.q_j)
+    q, b = spec.q_i, math.isqrt(8 * n_dest + 1) // 2   # b(b+1)/2 dests
+    diag = np.array([di == dj for di in range(b) for dj in range(di + 1)])
+    # a diagonal leaf block is stored as its tile-level lower triangle
+    return np.where(diag, q * (q + 1) // 2, q * q)
+
+
 def _traffic(spec: _Spec, *, left_bytes: int, right_bytes: int,
              out_bytes: int, cin_bytes: int = 0) -> dict:
-    """Core HBM model of one bound program: streamed tile fetches
-    (incl. padded null contribution slots — the contribution axis is
-    padded to ``max_contributions``, so the read term honestly reflects
-    that amplification), one write per output tile, plus the incoming
-    stack read for accumulating programs."""
-    grid = spec.grid_steps
-    l_tile = spec.bi * spec.bc
-    r_tile = (spec.bj * spec.bj) if spec.right_tri else spec.bj * spec.bc
-    reads = grid * spec.tmax * (l_tile * left_bytes + r_tile * right_bytes)
-    if spec.accumulate:
-        reads += spec.n_out * spec.bi * spec.bj * cin_bytes
+    """Core HBM model of one bound program, as the depth>=2 kernel walks
+    it: per output tile, ``n_k`` steps for each real contribution of its
+    destination, each fetching that contribution's real left and right
+    terms (the count table the kernel reads); one write per output tile;
+    plus the incoming stack read for accumulating programs.
+
+    ``padded_grid_steps`` / ``padded_read_bytes`` are the padded grid
+    the depth-1 walk visits (every contribution slot, ``tmax`` tiles a
+    side), and ``skipped_fetch_share`` the share of that grid's tile
+    fetches the depth>=2 walk does not issue."""
+    counts = _program_tables(spec.kind, spec.levels, spec.variant,
+                             spec.gram, spec.trans_a, spec.trans_b)[5]
+    counts = counts.reshape(-1, spec.n_c + 1).astype(np.int64)
+    tiles = _dest_tiles(spec, counts.shape[0])
+    assert tiles.sum() == spec.n_out, (tiles.sum(), spec.n_out)
+    steps = int(tiles @ counts[:, 0]) * spec.n_k
+    n_left = int(tiles @ (counts[:, 1:] >> _COUNT_BITS).sum(1)) * spec.n_k
+    n_right = int(tiles @ (counts[:, 1:] & _COUNT_MASK).sum(1)) * spec.n_k
+    padded = spec.grid_steps
+    l_tile = spec.bi * spec.bc * left_bytes
+    r_tile = ((spec.bj * spec.bj) if spec.right_tri
+              else spec.bj * spec.bc) * right_bytes
+    stack_reads = (spec.n_out * spec.bi * spec.bj * cin_bytes
+                   if spec.accumulate else 0)
     writes = spec.n_out * spec.bi * spec.bj * out_bytes
-    # MXU work per grid step: one (bi, bc) x (bc, bj) leaf product (the
-    # VPU gather adds are second-order) — feeds the pipelined occupancy
-    # term in cost_model.pipelined_bytes_score
-    flops = 2 * grid * spec.bi * spec.bc * spec.bj
-    return {"grid_steps": grid, "read_bytes": reads, "write_bytes": writes,
-            "flops": flops}
+    # MXU work per step: one (bi, bc) x (bc, bj) leaf product (the VPU
+    # gather adds are second-order) — feeds the pipelined occupancy term
+    # in cost_model.pipelined_bytes_score
+    flops = 2 * steps * spec.bi * spec.bc * spec.bj
+    return {
+        "grid_steps": steps,
+        "read_bytes": n_left * l_tile + n_right * r_tile + stack_reads,
+        "write_bytes": writes, "flops": flops,
+        "padded_grid_steps": padded,
+        "padded_read_bytes": padded * spec.tmax * (l_tile + r_tile)
+        + stack_reads,
+        "skipped_fetch_share": 1 - (n_left + n_right)
+        / (2 * padded * spec.tmax),
+    }
 
 
 def ata_traffic_model(
@@ -1595,8 +1683,8 @@ def ata_bwd_traffic_model(
     ``S + S^t`` (add) — three dense N^2 buffers.  An
     ``hbm_intermediate_census`` of its compiled HLO lands near this
     (XLA fusion may materialize fewer; the packed entry's unpack scatter
-    adds more).  The fused read term honestly includes the
-    contribution-slot padding amplification, same as the forward model.
+    adds more).  The fused read term counts the real-entry walk of the
+    depth>=2 kernel, same as the forward model.
     """
     geo = _ata_geometry(m, n, levels, variant, bk, bn, gram=gram)
     M, N = geo["M"], geo["N"]

@@ -370,15 +370,25 @@ def test_acceptance_512_parity_and_hbm_ratio():
     assert ref_bytes >= 2 * fused_bytes and ref_bytes > 1_000_000, (
         ref_bytes, fused_bytes)
     # the analytic side must be a real model, not a constant: its write
-    # term is exactly the packed output, its read term covers the padded
+    # term is exactly the packed output, its read term covers the real
+    # contributions and terms the pipelined kernel fetches (one output
+    # tile per leaf destination here), its padded terms the whole
     # contribution sweep, and misaligned shapes surface the pad copy.
     t = 512 // 128
     n_tri = t * (t + 1) // 2
-    assert model["write_bytes"] == n_tri * 128 * 128 * 4
+    tile = 128 * 128 * 4
+    assert model["write_bytes"] == n_tri * tile
     plan = plan_ata(2, "strassen")
-    assert model["grid_steps"] == n_tri * plan.max_contributions * 1
-    assert model["read_bytes"] == (model["grid_steps"] * 2 * plan.max_terms
-                                   * 128 * 128 * 4)
+    contribs = [c for cs in plan.by_dest().values() for c in cs]
+    fetches = sum(len(c.left) + len(c.right) for c in contribs)
+    assert model["grid_steps"] == len(contribs) * 1
+    assert model["read_bytes"] == fetches * tile
+    assert model["padded_grid_steps"] == n_tri * plan.max_contributions * 1
+    assert model["padded_read_bytes"] == (
+        model["padded_grid_steps"] * 2 * plan.max_terms * tile)
+    assert model["skipped_fetch_share"] == pytest.approx(
+        1 - fetches / (model["padded_grid_steps"] * 2 * plan.max_terms))
+    assert 0 < model["skipped_fetch_share"] < 1
     misaligned = ata_traffic_model(257, 511, levels=2, bk=64, bn=64)
     assert misaligned["padded_shape"] == (512, 512)
     assert misaligned["intermediate_bytes"] == 512 * 512 * 4

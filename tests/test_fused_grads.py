@@ -347,12 +347,17 @@ def test_acceptance_bwd_traffic_4096():
                                    cotangent="packed")
     assert packed["intermediate_bytes"] == 0
     assert packed["intermediate_ratio_dense_over_fused"] is None
-    # the model is a real model: write term is exactly dA, grid covers
-    # the padded contribution sweep
+    # the model is a real model: write term is exactly dA, its steps are
+    # the real contributions of every output tile's destination, and its
+    # padded grid covers the whole contribution sweep
     assert model["write_bytes"] == 4096 * 4096 * 4
     from repro.core.schedule import plan_symm as _ps
     plan = _ps(model["levels"], "strassen")
     T = 4096 // 256
     q = T // plan.blocks
     grid = (4096 // 256) * T * plan.max_contributions * q
-    assert model["grid_steps"] == grid
+    assert model["padded_grid_steps"] == grid
+    tiles_per_dest = (4096 // 256) * T // plan.n_dests()
+    n_contribs = sum(len(cs) for cs in plan.by_dest().values())
+    assert model["grid_steps"] == tiles_per_dest * n_contribs * q
+    assert model["grid_steps"] < grid

@@ -396,14 +396,70 @@ def test_rank_k_streamed_grad_is_dense_free_capable():
 # IR-driven traffic models for the new kinds
 # ---------------------------------------------------------------------------
 
+@pytest.mark.parametrize("kind", PROGRAM_KINDS)
+@pytest.mark.parametrize("levels", [1, 2, 3])
+def test_count_table_holds_each_rows_real_prefix(kind, levels):
+    """The pipelined kernel walks only what the count table calls real:
+    per destination its contributions, per contribution its left and
+    right terms.  Real entries lead every table row, so the counts are
+    prefix lengths and every slot behind them is padding."""
+    from repro.kernels.strassen_fused import _program_tables
+    prog = compile_program(kind, levels, "strassen")
+    n_c, tmax = prog.max_contributions, prog.max_terms
+    sign, _, lsgn, _, rsgn, counts = _program_tables(kind, levels,
+                                                     "strassen")
+    sign = sign.reshape(-1, n_c)
+    lsgn, rsgn = lsgn.reshape(-1, n_c, tmax), rsgn.reshape(-1, n_c, tmax)
+    counts = counts.reshape(-1, n_c + 1)
+    assert counts.shape[0] == prog.n_dests()
+    walked = 0
+    for (di, dj), contribs in prog.by_dest().items():
+        ld = prog.dest_index(di, dj)
+        assert counts[ld, 0] == len(contribs)
+        assert not sign[ld, len(contribs):].any()
+        assert not counts[ld, 1 + len(contribs):].any()
+        for c, contrib in enumerate(contribs):
+            n_left, n_right = counts[ld, 1 + c] >> 16, counts[ld, 1 + c] \
+                & 0xFFFF
+            assert (n_left, n_right) == (len(contrib.left),
+                                         len(contrib.right))
+            assert sign[ld, c] != 0
+            assert lsgn[ld, c, :n_left].all() and rsgn[ld, c, :n_right].all()
+            assert not lsgn[ld, c, n_left:].any()
+            assert not rsgn[ld, c, n_right:].any()
+        walked += counts[ld, 0]
+    assert walked == counts[:, 0].sum() == len(prog.contributions())
+
+
+@pytest.mark.parametrize("kind", PROGRAM_KINDS)
+def test_table_bytes_count_every_lowered_word(kind):
+    """``_table_bytes`` (the SMEM guard of the fan-in clamp) counts the
+    count table too, and the deepest programs still fit SMEM."""
+    from repro.kernels.strassen_fused import (SMEM_TABLE_BYTES,
+                                              _program_tables, _table_bytes)
+    prog = compile_program(kind, 3, "strassen")
+    tables = _program_tables(kind, 3, "strassen")
+    assert _table_bytes(prog) == sum(t.nbytes for t in tables)
+    assert _table_bytes(prog) <= SMEM_TABLE_BYTES
+
+
 def test_aat_traffic_model_is_real():
     prog = compile_program("aat", 2, "strassen")
     t = aat_traffic_model(512, 512, levels=2, bm=128, bk=128)
     n_tri = 4 * 5 // 2
-    assert t["write_bytes"] == n_tri * 128 * 128 * 4
-    assert t["grid_steps"] == n_tri * prog.max_contributions * 1
-    assert t["read_bytes"] == (t["grid_steps"] * 2 * prog.max_terms
-                               * 128 * 128 * 4)
+    tile = 128 * 128 * 4
+    assert t["write_bytes"] == n_tri * tile
+    # one output tile per leaf destination: the pipelined kernel walks
+    # each destination's real contributions and fetches their real terms
+    contribs = [c for cs in prog.by_dest().values() for c in cs]
+    fetches = sum(len(c.left) + len(c.right) for c in contribs)
+    assert t["grid_steps"] == len(contribs) * 1
+    assert t["read_bytes"] == fetches * tile
+    assert t["padded_grid_steps"] == n_tri * prog.max_contributions * 1
+    assert t["padded_read_bytes"] == (t["padded_grid_steps"] * 2
+                                      * prog.max_terms * tile)
+    assert t["skipped_fetch_share"] == pytest.approx(
+        1 - fetches / (t["padded_grid_steps"] * 2 * prog.max_terms))
     assert t["intermediate_bytes"] == 0
     mis = aat_traffic_model(257, 511, levels=2, bm=64, bk=64)
     assert mis["padded_shape"] == (512, 512)

@@ -14,6 +14,8 @@ Three contracts:
   engine buckets quantized requests separately from native ones, and the
   candidate dedupe collapses identically-scored duplicates.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -40,37 +42,72 @@ def tmp_cache(tmp_path, monkeypatch):
 # pipeline_depth parity: depth>1 must be bit-exact vs depth=1, all kinds
 # --------------------------------------------------------------------------
 
-def _run_kind(kind, depth):
-    a = _rand(0, 96, 64)
+def _kind_call(kind, levels, gram="strassen"):
+    """(fn, args) of one fused call of ``kind`` at ``levels`` with 32-edge
+    tiles, shaped so that no level is clamped away: at levels >= 2 the
+    leaf programs pad their contribution and term slots."""
+    m, n = (96, 32 << levels) if levels < 3 else (160, 256)
+    a = _rand(0, m, n)
     if kind == "ata":
-        return ops.ata_fused(a, levels=1, bk=32, bn=32,
-                             pipeline_depth=depth)
+        return (lambda a, depth: ops.ata_fused(
+            a, levels=levels, gram=gram, bk=32, bn=32,
+            pipeline_depth=depth)), (a,)
     if kind == "aat":
-        return ops.aat_fused(a, levels=1, bm=32, bk=32,
-                             pipeline_depth=depth)
+        return (lambda a, depth: ops.aat_fused(
+            a, levels=levels, gram=gram, bm=32, bk=32,
+            pipeline_depth=depth)), (a,)
     if kind == "matmul":
-        b = _rand(1, 64, 96)
-        return ops.matmul_fused(a, b, levels=1, bm=32, bk=32, bn=32,
-                                pipeline_depth=depth)
+        b = _rand(1, n, m)
+        return (lambda a, b, depth: ops.matmul_fused(
+            a, b, levels=levels, bm=32, bk=32, bn=32,
+            pipeline_depth=depth)), (a, b)
     if kind == "symm":
-        s_packed = ops.ata_fused_packed(a, levels=1, bk=32, bn=32)
-        x = _rand(2, 48, 64)
-        return ops.symm_matmul(x, s_packed, levels=1, bm=32,
-                               pipeline_depth=depth)
+        s_packed = ops.ata_fused_packed(a, levels=levels, bk=32, bn=32)
+        x = _rand(2, 48, n)
+        return (lambda x, s, depth: ops.symm_matmul(
+            x, s, levels=levels, bm=32, pipeline_depth=depth)), (x, s_packed)
     assert kind == "rank_k"
+    t = n // 32
     stack = jnp.asarray(np.random.default_rng(3).standard_normal(
-        (3 * 32, 32)).astype(np.float32))   # t=2 tiles of edge 32
-    return ops.rank_k_update(stack, a, levels=1, bk=32, donate=False,
-                             pipeline_depth=depth)
+        (t * (t + 1) // 2 * 32, 32)).astype(np.float32))
+    return (lambda c, a, depth: ops.rank_k_update(
+        c, a, levels=levels, gram=gram, bk=32, donate=False,
+        pipeline_depth=depth)), (stack, a)
+
+
+@functools.lru_cache(maxsize=None)
+def _run_kind(kind, depth, levels, gram="strassen"):
+    """Run the call, asserting its kernel ran at the requested levels
+    (cached: the depth-1 baseline serves every depth it is compared
+    with)."""
+    fn, args = _kind_call(kind, levels, gram)
+    lowered = jax.jit(functools.partial(fn, depth=depth)).lower(*args)
+    scope = f"fused:{kind}:l{levels}:strassen:{gram}"
+    assert scope in lowered.as_text(debug_info=True), scope
+    return np.asarray(lowered.compile()(*args))
 
 
 @pytest.mark.parametrize("kind", ["ata", "aat", "matmul", "symm", "rank_k"])
 @pytest.mark.parametrize("depth", [2, 3])
-def test_pipeline_depth_bit_exact_parity(kind, depth):
-    base = np.asarray(_run_kind(kind, 1))
-    got = np.asarray(_run_kind(kind, depth))
+@pytest.mark.parametrize("levels", [1, 2, 3])
+def test_pipeline_depth_bit_exact_parity(kind, depth, levels):
+    """The pipelined kernel walks only the real contributions and terms;
+    the grid walk multiplies the padded slots by 0.  Both sum the real
+    terms in the same order, so the results agree bit for bit."""
+    base = _run_kind(kind, 1, levels)
+    got = _run_kind(kind, depth, levels)
     assert np.array_equal(base, got), (
-        f"{kind}: depth={depth} differs from depth=1 "
+        f"{kind} levels={levels}: depth={depth} differs from depth=1 "
+        f"(max abs {np.abs(base - got).max()})")
+
+
+@pytest.mark.parametrize("kind", ["ata", "aat", "rank_k"])
+def test_pipeline_depth_bit_exact_parity_dps(kind):
+    """The rational-coefficient gram algebra (+-1/2, +-1/4) at levels 2."""
+    base = _run_kind(kind, 1, 2, gram="dps")
+    got = _run_kind(kind, 2, 2, gram="dps")
+    assert np.array_equal(base, got), (
+        f"{kind} dps: depth=2 differs from depth=1 "
         f"(max abs {np.abs(base - got).max()})")
 
 
