@@ -2,7 +2,8 @@
 """Bring-up smoke of the fused Gram path on a TPU.
 
     python chip_smoke.py             # one chip: library, rows + gradient,
-                                     # streaming, the service
+                                     # streaming, Shampoo's statistics,
+                                     # the service
     python chip_smoke.py --chips 4   # distributed_gram on a four-chip mesh
 
 Every phase runs through the public entry points at the paper's sizes
@@ -46,10 +47,12 @@ TILE = 256
 REL_FRO_BOUND = 2.0 ** -7 * 2 ** 3
 
 # Sizes: the paper's §6.2 grams, a 4096 row gram and gradient, 65,536
-# streamed rows in four 256 MiB chunks, and a 1 GiB A on four chips.
+# streamed rows in four 256 MiB chunks, two of Shampoo's 8192-wide
+# statistics blocks, and a 1 GiB A on four chips.
 LIBRARY_N = (5000, 10000)
 ROWS_N = 4096
 STREAM_ROWS, STREAM_N, STREAM_CHUNKS = 16384, 4096, 4
+SHAMPOO_BLOCKS = [(8192, 4096), (4096, 8192)]
 DIST_SHAPE = (32768, 8192)
 
 _KERNEL_SCOPE = re.compile(r"fused:(\w+):l(\d+):(\w+):(\w+)(?::pd(\d+))?")
@@ -223,6 +226,45 @@ def phase_stream(seed):
         f"{timing(compile_s, call_s)} (last chunk)")
 
 
+def phase_shampoo(seed):
+    """Shampoo's statistics step at 8192-wide blocks: three update_stats
+    steps at beta2 0.999 of an 8192 x 4096 and a 4096 x 8192 fp32 block
+    (the scaled rows-side and columns-side accumulating kernels at levels
+    3), against (1 - beta2^3) G G^t and (1 - beta2^3) G^t G."""
+    import importlib
+    shampoo = importlib.import_module("repro.optim.shampoo")
+    shapes, beta2, steps = SHAMPOO_BLOCKS, 0.999, 3
+    grads = [normal(seed + i, s) for i, s in enumerate(shapes)]
+    stats = shampoo.init_stats(shapes)
+    b, a = jnp.float32(beta2), jnp.float32(1 - beta2)
+    t0 = time.perf_counter()
+    compiled = shampoo._stats_step.lower(
+        stats, grads, b, a, levels="auto", leaf=256, variant="strassen",
+        mode="auto", block=None, interpret=None).compile()
+    compile_s = time.perf_counter() - t0
+    kern = kernels_of(compiled, "update_stats")
+    if not {"rank_k:l3:pd2", "rank_k_rows:l3:pd2"} <= set(kern):
+        raise AssertionError(f"update_stats: kernels {kern}")
+    for _ in range(steps):
+        t0 = time.perf_counter()
+        stats = jax.block_until_ready(
+            shampoo.update_stats(stats, grads, beta2, levels="auto"))
+        call_s = time.perf_counter() - t0
+    dense = shampoo.dense_stats(stats, shapes)
+    scale = 1.0 - np.float32(beta2) ** steps
+    errs = []
+    for side, gram_of in (("l", "rows"), ("r", "cols")):
+        for g, got in zip(grads, dense[side]):
+            want = (jnp.dot(g, g.T, precision=jax.lax.Precision.HIGHEST)
+                    if gram_of == "rows" else
+                    jnp.dot(g.T, g, precision=jax.lax.Precision.HIGHEST))
+            errs.append(check(f"update_stats {side} {g.shape}",
+                              rel_fro(got, scale * want)))
+    log(f"  update_stats x{steps} of {shapes} at beta2 {beta2}: kernels "
+        f"{kern}; {'; '.join(errs)} vs (1 - beta2^{steps}) Gram HIGHEST; "
+        f"{timing(compile_s, call_s)} (last step)")
+
+
 # mixed shapes from 256 to 4096, both grams
 SERVICE_REQUESTS = [((256, 256), "cols"), ((512, 384), "cols"),
                     ((1000, 700), "rows"), ((2048, 1024), "cols"),
@@ -344,7 +386,8 @@ def main(argv=None):
 
     phases = ([("distributed", phase_distributed)] if args.chips == 4 else
               [("library", phase_library), ("rows_grad", phase_rows_and_grad),
-               ("stream", phase_stream), ("service", phase_service)])
+               ("stream", phase_stream), ("shampoo", phase_shampoo),
+               ("service", phase_service)])
     failed = []
     for name, fn in phases:
         log(f"[{name}]")

@@ -66,10 +66,13 @@ Kinds:
 ``symm``    D = X @ Sym           — Sym symmetric, stored as its lower
             triangle only; upper-triangle terms are mirrored onto the
             stored triangle with the per-term trans flag set.
-``rank_k``  C += A^t A            — the ``ata`` program with the output
-            accumulate flag: the executor seeds each output tile from
-            the incoming packed stack instead of zero, so streamed Gram
-            chunks never re-materialize C.
+``rank_k``  C <- beta C + alpha tril(A^t A)  — the ``ata`` program with
+            the output accumulate flag: the executor seeds each output
+            tile from the incoming packed stack (scaled by a run-time
+            ``beta``) instead of zero, so streamed Gram chunks never
+            re-materialize C.  ``gram_of="rows"`` compiles the ``aat``
+            recursion with the same flag: C <- beta C + alpha
+            tril(A A^t), the rows side.
 """
 from __future__ import annotations
 
@@ -634,6 +637,12 @@ class LeafProgram:
         return self.blocks_m
 
     @property
+    def gram_of(self) -> str:
+        """Which Gram a gram kind computes: ``"rows"`` (A A^t, the right
+        side read transposed) or ``"cols"`` (A^t A, the left side)."""
+        return "rows" if self.right_spec.transpose else "cols"
+
+    @property
     def out_blocks(self) -> Tuple[int, int]:
         """(rows, cols) of the output leaf grid."""
         if self.out_spec.packing == "tri":
@@ -709,17 +718,19 @@ class LeafProgram:
         operand.  Matches the ``cost_model`` closed forms evaluated with
         ``leaf=0`` at the padded shape (tests/test_properties.py).
         """
+        gram = self.kind in ("ata", "aat", "rank_k")
+        rows = gram and self.gram_of == "rows"
         total = 0
         for p in self.ops:
             if p.kind == "syrk":
-                if self.kind == "aat":
+                if rows:
                     total += nb * mb * (mb + 1) // 2
                 else:
                     total += mb * nb * (nb + 1) // 2
-            elif self.kind in ("ata", "rank_k"):
-                total += nb * mb * nb          # (nb, mb) @ (mb, nb)
-            elif self.kind == "aat":
+            elif rows:
                 total += mb * nb * mb          # (mb, nb) @ (nb, mb)
+            elif gram:
+                total += nb * mb * nb          # (nb, mb) @ (mb, nb)
             elif self.kind == "symm":
                 total += mb * nb * nb          # (mb, nb) @ (nb, nb)
             else:
@@ -877,7 +888,8 @@ def _compile_gram(levels: int, table, galg, *,
 def compile_program(kind: str, levels: int, variant: str = "strassen", *,
                     gram: str = "strassen",
                     trans_a: bool = False,
-                    trans_b: bool = False) -> LeafProgram:
+                    trans_b: bool = False,
+                    gram_of: str = "cols") -> LeafProgram:
     """Compile ``kind`` at ``levels`` against the registered tables.
 
     ``variant`` names the multiplication algebra (may be rectangular
@@ -887,6 +899,9 @@ def compile_program(kind: str, levels: int, variant: str = "strassen", *,
     ``trans_b`` apply to ``matmul`` only: the op list is identical
     (terms name stored blocks either way); only the operand specs
     change, and the executor folds the swap into its index maps.
+    ``gram_of`` applies to ``rank_k`` only: ``"cols"`` accumulates
+    A^t A (the ``ata`` recursion), ``"rows"`` accumulates A A^t (the
+    ``aat`` recursion).
     """
     if levels < 0:
         raise ValueError(f"levels must be >= 0, got {levels}")
@@ -895,6 +910,11 @@ def compile_program(kind: str, levels: int, variant: str = "strassen", *,
                          f"(want one of {PROGRAM_KINDS})")
     if (trans_a or trans_b) and kind != "matmul":
         raise ValueError(f"trans_a/trans_b only apply to matmul, not {kind!r}")
+    if gram_of not in ("cols", "rows"):
+        raise ValueError(f"gram_of must be 'cols' or 'rows', got {gram_of!r}")
+    if gram_of != "cols" and kind != "rank_k":
+        raise ValueError(f"gram_of only applies to rank_k ({kind!r} fixes "
+                         f"its own side)")
     if gram != "strassen" and kind not in ("ata", "aat", "rank_k"):
         raise ValueError(f"gram algebra selection only applies to gram "
                          f"kinds, not {kind!r}")
@@ -907,20 +927,20 @@ def compile_program(kind: str, levels: int, variant: str = "strassen", *,
                 f"gram kinds recurse over a square 2x2 split; algebra "
                 f"{variant!r} is <{dims[0]},{dims[1]},{dims[2]}>")
         galg = get_gram_algebra(gram)
-        ops = _compile_gram(levels, table, galg, rows=kind == "aat")
-        if kind == "aat":
+        rows = kind == "aat" or gram_of == "rows"
+        ops = _compile_gram(levels, table, galg, rows=rows)
+        out_spec = OutputSpec(packing="tri", accumulate=kind == "rank_k")
+        if rows:
             return LeafProgram(
                 kind, levels, variant, ops,
                 left_spec=OperandSpec(source=0),
                 right_spec=OperandSpec(source=0, transpose=True),
-                out_spec=OutputSpec(packing="tri"),
-                dims=dims, gram=gram)
+                out_spec=out_spec, dims=dims, gram=gram)
         return LeafProgram(
             kind, levels, variant, ops,
             left_spec=OperandSpec(source=0, transpose=True),
             right_spec=OperandSpec(source=0),
-            out_spec=OutputSpec(packing="tri", accumulate=kind == "rank_k"),
-            dims=dims, gram=gram)
+            out_spec=out_spec, dims=dims, gram=gram)
 
     if kind == "matmul":
         ops: List[LeafOp] = []
@@ -991,6 +1011,7 @@ def _gather_side(arr: np.ndarray, terms, grid, spec: OperandSpec,
 def interpret_program(prog: LeafProgram, a: np.ndarray,
                       b: Optional[np.ndarray] = None, *,
                       c0: Optional[np.ndarray] = None,
+                      beta: float = 1.0, alpha: float = 1.0,
                       diag_sym: bool = False) -> np.ndarray:
     """Execute a program densely in float64 numpy.
 
@@ -1000,8 +1021,8 @@ def interpret_program(prog: LeafProgram, a: np.ndarray,
     transpose).  For ``symm``, ``b`` is the dense (n, n) array whose
     strict upper triangle is provably never read (the packed-storage
     contract); ``diag_sym`` computes ``x @ (S + S^t)`` instead.  For
-    ``rank_k``, ``c0`` is the (n, n) initial C (lower triangle; defaults
-    to zero).
+    ``rank_k``, ``c0`` is the initial C (lower triangle; defaults to
+    zero) and the result is ``beta * c0 + alpha * tril(Gram)``.
 
     Returns: tril(C) for tri-packed outputs, dense C otherwise.
     """
@@ -1024,10 +1045,8 @@ def interpret_program(prog: LeafProgram, a: np.ndarray,
 
     # output geometry per kind
     m, n = af.shape
-    if prog.kind in ("ata", "rank_k"):
-        out_n = (n, n)
-    elif prog.kind == "aat":
-        out_n = (m, m)
+    if prog.kind in ("ata", "aat", "rank_k"):
+        out_n = (m, m) if prog.gram_of == "rows" else (n, n)
     elif prog.kind == "symm":
         out_n = (m, operands[1].shape[1])
     else:
@@ -1035,10 +1054,9 @@ def interpret_program(prog: LeafProgram, a: np.ndarray,
         out_n = ((la[1] if prog.left_spec.transpose else la[0]),
                  (lb[0] if prog.right_spec.transpose else lb[1]))
     out = np.zeros(out_n, np.float64)
-    if c0 is not None:
+    if c0 is not None or (beta, alpha) != (1.0, 1.0):
         assert prog.out_spec.accumulate, \
             f"{prog.kind} output does not accumulate"
-        out += np.tril(np.asarray(c0, np.float64))
     ogrid = prog.out_blocks
     mb, nb = out_n[0] // ogrid[0], out_n[1] // ogrid[1]
 
@@ -1047,10 +1065,12 @@ def interpret_program(prog: LeafProgram, a: np.ndarray,
                             prog.left_spec)
         right = _gather_side(operands[prog.right_spec.source], p.right, rgrid,
                              prog.right_spec, diag_sym=diag_sym)
-        prod = left @ right
+        prod = alpha * (left @ right)
         for di, dj, s, tr in p.dests:
             blk = prod.T if tr else prod
             out[di * mb:(di + 1) * mb, dj * nb:(dj + 1) * nb] += s * blk
+    if c0 is not None:
+        out += beta * np.tril(np.asarray(c0, np.float64))
     if prog.out_spec.packing == "tri":
         out = np.tril(out)
     return out

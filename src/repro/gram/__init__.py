@@ -21,7 +21,7 @@ from .autotune import (  # noqa: F401
 from . import verify  # noqa: F401
 from .engine import (  # noqa: F401
     BucketHealth, EngineShutdown, GramEngine, GramFuture, GramRequest,
-    GramServeError, Overloaded, TenantState, batched_gram,
+    GramServeError, Overloaded, TenantState,
 )
 from .stream import (  # noqa: F401
     GramStream, init as stream_init, update as stream_update,
@@ -41,7 +41,6 @@ __all__ = [
     "resolve_block_defaults",
     "GramEngine", "GramRequest", "GramFuture", "BucketHealth",
     "TenantState", "GramServeError", "Overloaded", "EngineShutdown",
-    "batched_gram",
     "GramStream", "stream_init", "stream_update", "stream_finalize",
     "GramStackStream", "stack_init", "stack_update", "stack_finalize",
     "sharded_init", "update_sharded",

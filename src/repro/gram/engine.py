@@ -103,7 +103,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core.ata import ata, ata_full, ata_levels_for
+from ..core.ata import ata, ata_levels_for
 from ..core.cost_model import gram_serve_work
 from ..core.distributed import (default_gram_axes, distributed_gram,
                                 feasible_schemes, scheme_fallback_chain,
@@ -118,8 +118,7 @@ from . import autotune as _autotune
 from . import verify as _verify
 
 __all__ = ["GramEngine", "GramRequest", "GramFuture", "BucketHealth",
-           "TenantState", "GramServeError", "Overloaded", "EngineShutdown",
-           "batched_gram"]
+           "TenantState", "GramServeError", "Overloaded", "EngineShutdown"]
 
 
 class GramServeError(RuntimeError):
@@ -136,24 +135,6 @@ class Overloaded(GramServeError):
 
 class EngineShutdown(GramServeError):
     """The engine was shut down while this request was still queued."""
-
-
-def batched_gram(blocks: jax.Array, *, levels: Union[int, str] = 1,
-                 leaf: int = 256, variant: str = "strassen",
-                 mode: str = "auto", block: Optional[int] = None,
-                 out_dtype=None,
-                 interpret: Optional[bool] = None) -> jax.Array:
-    """Full symmetric Gram of a (K, m, n) stack -> (K, n, n), vmapped
-    through the mode-dispatched ATA path (fused kernel on TPU).
-
-    The batched building block of the service layer; also the in-repo
-    consumer hook for ``optim/shampoo.py``'s per-block statistics.
-    """
-    if blocks.ndim != 3:
-        raise ValueError(f"batched_gram expects (K, m, n), got {blocks.shape}")
-    return jax.vmap(lambda b: ata_full(
-        b, levels=levels, leaf=leaf, variant=variant, mode=mode,
-        out_dtype=out_dtype, block=block, interpret=interpret))(blocks)
 
 
 class GramFuture:

@@ -157,10 +157,11 @@ def finalize(state: GramStream, *, symmetrize: bool = True,
 # ---------------------------------------------------------------------------
 # Rank-k streaming: the state IS the kernel's packed tile stack, and each
 # chunk folds in through the accumulating (rank_k) leaf program — the
-# kernel seeds its VMEM accumulator from the stack, so no per-chunk delta
-# stack, no unpack and no gather ever materializes (the PR-2 element-
-# packed ``update`` above computes a full n(n+1)/2 delta per chunk and
-# adds it; this path replaces that with ONE kernel per chunk).
+# kernel seeds its VMEM accumulator from the (optionally beta-scaled)
+# stack and writes over it, so no per-chunk delta stack, no unpack and no
+# gather ever materializes (the element-packed ``update`` above computes a
+# full n(n+1)/2 delta per chunk and adds it; this path replaces that with
+# ONE kernel per chunk, on either side of the chunk: ``gram_of``).
 # ---------------------------------------------------------------------------
 
 class GramStackStream(NamedTuple):
@@ -205,26 +206,41 @@ def stack_init(n: int, *, block: Optional[int] = None,
 def stack_update(state: GramStackStream, chunk: jax.Array, *,
                  levels: Union[int, str] = 2, leaf: int = 256,
                  variant: str = "strassen", block: Optional[int] = None,
+                 gram_of: str = "cols", beta=1.0, alpha=1.0,
                  interpret: Optional[bool] = None) -> GramStackStream:
-    """Fold one row chunk in: ``state.stack += packed(tril(chunk^t chunk))``
-    — one accumulating kernel, state donated, no intermediate delta.
+    """Fold one chunk in: ``state.stack <- beta * state.stack + alpha *
+    packed(tril(chunk^t chunk))`` — one accumulating kernel, state
+    donated, no intermediate delta.  The defaults give ``+=``.
 
-    ``chunk`` is (m_chunk, n) with n <= the stack's padded span.
-    ``block`` is the *contraction* tile (rows of the chunk; the output
-    tile edge is fixed by the stack).  ``levels`` clamps to depths the
-    stack layout divides, like the symm executor.
+    ``gram_of="cols"`` (default): ``chunk`` is (m_chunk, n) with n <= the
+    stack's padded span, its rows streamed in.  ``gram_of="rows"``: the
+    Gram is ``chunk chunk^t``, ``chunk`` is (n, k_chunk) with n <= the
+    span, its columns streamed in (Shampoo's left statistic of a
+    gradient block, with no transposed copy of the block).  ``beta`` and
+    ``alpha`` are run-time scalars: an exponential moving average takes
+    ``beta = b, alpha = 1 - b`` from one executable for every ``b``.
+    ``rows`` counts the vectors folded in, unscaled.
+
+    ``block`` is the *contraction* tile (the output tile edge is fixed
+    by the stack).  ``levels`` clamps to depths the stack layout
+    divides, like the symm executor.
     """
-    if chunk.ndim != 2 or chunk.shape[1] > state.n_padded:
+    if gram_of not in ("cols", "rows"):
+        raise ValueError(f"gram_of must be 'cols' or 'rows', got {gram_of!r}")
+    side = chunk.shape[0] if gram_of == "rows" else chunk.shape[-1]
+    if chunk.ndim != 2 or side > state.n_padded:
         raise ValueError(
             f"chunk shape {chunk.shape} does not fit stream "
-            f"n_padded={state.n_padded}")
+            f"n_padded={state.n_padded} ({gram_of} side)")
     from ..kernels.ops import rank_k_update
     m, n = chunk.shape
     lv = (min(ata_levels_for(m, n, leaf), AUTO_MAX_LEVELS)
           if levels == "auto" else levels)
     stack = rank_k_update(state.stack, chunk, levels=lv, variant=variant,
+                          gram_of=gram_of, beta=beta, alpha=alpha,
                           bk=block, interpret=interpret)
-    return GramStackStream(stack=stack, rows=state.rows + m)
+    return GramStackStream(stack=stack,
+                           rows=state.rows + (n if gram_of == "rows" else m))
 
 
 def stack_finalize(state: GramStackStream, n: Optional[int] = None, *,

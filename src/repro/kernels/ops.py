@@ -393,35 +393,39 @@ def _aat_fused_packed_jit(a, *, levels, variant, gram="strassen", bm, bk,
 
 
 def rank_k_update(c_stack, a, *, levels=2, variant="strassen",
-                  gram="strassen", bk=None, out_dtype=None, interpret=None,
-                  donate=True, pipeline_depth=None, operand_dtype=None,
-                  acc_dtype=None):
-    """``C += tril(a.T @ a)`` on a packed tile stack in ONE kernel — the
-    accumulating (rank-k) program.  The stack seeds the kernel's VMEM
-    accumulator, so a streamed Gram chunk materializes no delta stack
-    and no unpack/gather; with ``donate`` (default) the state buffer is
-    donated so XLA updates it in place at the jit boundary."""
+                  gram="strassen", gram_of="cols", beta=1.0, alpha=1.0,
+                  bk=None, out_dtype=None, interpret=None, donate=True,
+                  pipeline_depth=None, operand_dtype=None, acc_dtype=None):
+    """``C <- beta C + alpha tril(a.T @ a)`` (``gram_of="rows"``:
+    ``tril(a @ a.T)``) on a packed tile stack in ONE kernel — the
+    accumulating (rank-k) program.  The stack, scaled by ``beta``, seeds
+    the kernel's VMEM accumulator and the result is written over it, so
+    an update materializes no delta stack and no unpack/gather;
+    ``beta``/``alpha`` are run-time scalars (the defaults give ``C +=
+    tril(...)``).  With ``donate`` (default) the state buffer is donated
+    so XLA updates it in place at the jit boundary."""
     bs = _resolve_blocks("rank_k", a.shape[0], a.shape[1], a.dtype, bk=bk)
     fn = _rank_k_jit_donated if donate else _rank_k_jit
-    return fn(c_stack, a, levels=levels, variant=variant, gram=gram,
-              bk=bs["bk"], out_dtype=out_dtype, interpret=interpret,
-              pipeline_depth=pipeline_depth, operand_dtype=operand_dtype,
-              acc_dtype=acc_dtype)
+    return fn(c_stack, a, beta, alpha, levels=levels, variant=variant,
+              gram=gram, gram_of=gram_of, bk=bs["bk"], out_dtype=out_dtype,
+              interpret=interpret, pipeline_depth=pipeline_depth,
+              operand_dtype=operand_dtype, acc_dtype=acc_dtype)
 
 
-def _rank_k_impl(c_stack, a, *, levels, variant, gram="strassen", bk,
-                 out_dtype=None, interpret=None, pipeline_depth=None,
-                 operand_dtype=None, acc_dtype=None):
+def _rank_k_impl(c_stack, a, beta, alpha, *, levels, variant,
+                 gram="strassen", gram_of="cols", bk, out_dtype=None,
+                 interpret=None, pipeline_depth=None, operand_dtype=None,
+                 acc_dtype=None):
     from . import strassen_fused as _sf
     return _sf.fused_rank_k_update(
-        c_stack, a, levels=levels, variant=variant, gram=gram, bk=bk,
-        out_dtype=out_dtype,
+        c_stack, a, levels=levels, variant=variant, gram=gram,
+        gram_of=gram_of, beta=beta, alpha=alpha, bk=bk, out_dtype=out_dtype,
         interpret=_auto_interpret(interpret, site="ops.rank_k_update"),
         pipeline_depth=pipeline_depth, operand_dtype=operand_dtype,
         acc_dtype=acc_dtype)
 
 
-_rank_k_static = ("levels", "variant", "gram", "bk", "out_dtype",
+_rank_k_static = ("levels", "variant", "gram", "gram_of", "bk", "out_dtype",
                   "interpret", "pipeline_depth", "operand_dtype",
                   "acc_dtype")
 _rank_k_jit = jax.jit(_rank_k_impl, static_argnames=_rank_k_static)

@@ -30,9 +30,11 @@ old stacks could not express fall out of the same machinery:
 * ``aat`` — C = tril(A A^t), the Arrigoni-Massini 2021 row-gram
   recursion (:func:`fused_aat` / :func:`fused_aat_packed`, surfaced as
   ``ata(x, gram_of="rows")``): the transpose of A never exists in HBM.
-* ``rank_k`` — C += A^t A (:func:`fused_rank_k_update`): the running
-  packed stack seeds the accumulator, so streamed Gram chunks
-  (``gram/stream.py``) stop re-materializing a per-chunk delta.
+* ``rank_k`` — C <- beta C + alpha tril(A^t A), or of A A^t with
+  ``gram_of="rows"`` (:func:`fused_rank_k_update`): the running packed
+  stack, scaled by a run-time ``beta``, seeds the accumulator, so
+  streamed Gram chunks (``gram/stream.py``) and Shampoo's statistics
+  EMA (``optim/shampoo.py``) never re-materialize a per-update delta.
 
 Autodiff (DESIGN.md §11) is unchanged in spirit: custom VJPs route every
 backward through the same executor (symm schedule for the gram kinds,
@@ -306,15 +308,18 @@ def _symm_geometry(m: int, T: int, levels: int, variant: str, bm: int):
 
 
 def _rank_k_geometry(m: int, T: int, levels: int, variant: str, bk: int,
-                     gram: str = "strassen"):
-    """Geometry for C += A^t A against an existing packed (T-tile) stack:
-    the ata geometry with the column side pinned to the stack layout, so
-    levels clamp to divisors of T (like symm)."""
+                     gram: str = "strassen", gram_of: str = "cols"):
+    """Geometry for an accumulating Gram against an existing packed
+    (T-tile) stack: the ata (or, ``gram_of="rows"``, aat) geometry with
+    the output side pinned to the stack layout, so levels clamp to
+    divisors of T (like symm).  ``m`` is the contraction length: rows of
+    A for the columns side, columns of A for the rows side."""
     while levels > 0 and T % (1 << levels):
         levels -= 1
     levels = min(levels, ata_levels_for(m, T, 1))   # never exceed the grid
     levels = _fan_in_clamp("rank_k", levels, variant, gram)
-    plan = compile_program("rank_k", levels, variant, gram=gram)
+    plan = compile_program("rank_k", levels, variant, gram=gram,
+                           gram_of=gram_of)
     B = plan.blocks
     mb = _round_up(max(m, 1), B * bk) // B
     return {"plan": plan, "levels": levels, "M": B * mb, "mb": mb,
@@ -340,6 +345,7 @@ class _Spec:
     levels: int
     variant: str
     gram: str                   # gram-algebra entry (gram kinds)
+    gram_of: str                # rank_k: "cols" (A^t A) or "rows" (A A^t)
     trans_a: bool               # matmul-only operand-spec transposes
     trans_b: bool
     tmax: int
@@ -366,6 +372,13 @@ class _Spec:
     def grid_steps(self) -> int:
         return self.n_out * self.n_c * self.n_k
 
+    @property
+    def scope(self) -> str:
+        """The kernel's name in HLO metadata and the device trace; the
+        rows side of ``rank_k`` is ``rank_k_rows``."""
+        kind = self.kind + ("_rows" if self.gram_of == "rows" else "")
+        return f"fused:{kind}:l{self.levels}:{self.variant}:{self.gram}"
+
 
 def _bind(prog: LeafProgram, *, n_out, n_tj, q_i, q_j, n_k, bi, bj, bc,
           diag_sym=False, pipeline_depth=1,
@@ -374,6 +387,7 @@ def _bind(prog: LeafProgram, *, n_out, n_tj, q_i, q_j, n_k, bi, bj, bc,
     return _Spec(
         kind=prog.kind, levels=prog.levels, variant=prog.variant,
         gram=prog.gram,
+        gram_of=prog.gram_of if prog.kind == "rank_k" else "cols",
         trans_a=ls.transpose if prog.kind == "matmul" else False,
         trans_b=rs.transpose if prog.kind == "matmul" else False,
         tmax=prog.max_terms, n_c=prog.max_contributions, n_k=n_k,
@@ -406,7 +420,9 @@ def _bind(prog: LeafProgram, *, n_out, n_tj, q_i, q_j, n_k, bi, bj, bc,
 # operands; left per-term trans is asserted unused at lowering — the left
 # side's transposes are whole-operand OperandSpec flags, and transposed
 # gram destinations were normalized into side-swapped contributions at
-# the IR layer.
+# the IR layer.  Accumulating programs take one more float32 table,
+# ``(beta, alpha)``: run-time values, so one executable serves every
+# scale, and beta = alpha = 1 multiplies by exactly 1.
 # ---------------------------------------------------------------------------
 
 # packed term word: mirror << 30 | row << 15 | col
@@ -462,9 +478,11 @@ def _unpack(word):
 @functools.lru_cache(maxsize=None)
 def _program_tables(kind: str, levels: int, variant: str,
                     gram: str = "strassen",
-                    trans_a: bool = False, trans_b: bool = False):
+                    trans_a: bool = False, trans_b: bool = False,
+                    gram_of: str = "cols"):
     prog = compile_program(kind, levels, variant, gram=gram,
-                           trans_a=trans_a, trans_b=trans_b)
+                           trans_a=trans_a, trans_b=trans_b,
+                           gram_of=gram_of)
     n_dest, n_c, tmax = prog.n_dests(), prog.max_contributions, \
         prog.max_terms
     assert max(prog.blocks_m, prog.blocks_k, prog.blocks_n) <= _IDX_MASK
@@ -608,9 +626,30 @@ def _left_sum(tile, lsgn_ref, ld, c, spec, n_terms=None):
     return left.T if spec.left_trans else left
 
 
+def _seed(acc_ref, cin_ref, scale_ref):
+    """The accumulator's start: the incoming tile times ``beta`` for
+    accumulating programs, zero otherwise."""
+    if cin_ref is None:
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        return
+    wide = jnp.promote_types(acc_ref.dtype, jnp.float32)
+    acc_ref[...] = (cin_ref[...].astype(wide)
+                    * scale_ref[0].astype(wide)).astype(acc_ref.dtype)
+
+
+def _coef(sign_ref, scale_ref, ld, c, spec):
+    """A contribution's coefficient: its sign, times ``alpha`` for
+    accumulating programs."""
+    sgn = sign_ref[_slot(ld, c, spec)].astype(jnp.float32)
+    return sgn * scale_ref[1] if spec.accumulate else sgn
+
+
 def _leaf_kernel(sign_ref, lidx_ref, lsgn_ref, ridx_ref, rsgn_ref, *refs,
                  spec: _Spec):
     tmax = spec.tmax
+    scale_ref = None
+    if spec.accumulate:
+        scale_ref, refs = refs[0], refs[1:]
     l_refs = refs[:tmax]
     r_refs = refs[tmax:2 * tmax]
     cin_ref = refs[2 * tmax] if spec.accumulate else None
@@ -623,19 +662,16 @@ def _leaf_kernel(sign_ref, lidx_ref, lsgn_ref, ridx_ref, rsgn_ref, *refs,
 
     @pl.when((c == 0) & (k == 0))
     def _init():
-        if spec.accumulate:
-            # rank-k: the running packed stack seeds the accumulator —
-            # the incoming C is read once per tile, never re-materialized
-            acc_ref[...] = cin_ref[...].astype(acc_ref.dtype)
-        else:
-            acc_ref[...] = jnp.zeros_like(acc_ref)
+        # rank-k: the running packed stack seeds the accumulator — the
+        # incoming C is read once per tile, never re-materialized
+        _seed(acc_ref, cin_ref, scale_ref)
 
     @pl.when(sgn != 0)
     def _accumulate():
         left = _left_sum(lambda p: l_refs[p][...], lsgn_ref, ld, c, spec)
         right = _right_sum(lambda q: r_refs[q][...], ridx_ref, rsgn_ref,
                            ld, c, k, jq, spec)
-        contrib = sgn.astype(jnp.float32) * jnp.dot(
+        contrib = _coef(sign_ref, scale_ref, ld, c, spec) * jnp.dot(
             left, right, preferred_element_type=jnp.float32)
         acc_ref[...] += contrib.astype(acc_ref.dtype)
 
@@ -668,11 +704,15 @@ def _pipelined_kernel(sign_ref, lidx_ref, lsgn_ref, ridx_ref, rsgn_ref,
     the terms are summed in table order, so the accumulation order — and
     therefore the result, on finite operands — is bit-exact vs
     ``pipeline_depth=1``, whose padded slots only ever add +-0.  The
-    epilogue contract is unchanged: the accumulator is (c_in-)seeded
-    before the sweep and stored exactly once after it.
+    epilogue contract is unchanged: the accumulator is seeded (from
+    ``beta * c_in`` for accumulating programs) before the sweep and
+    stored exactly once after it.
     """
     depth, tmax = spec.pipeline_depth, spec.tmax
     n_k = spec.n_k
+    scale_ref = None
+    if spec.accumulate:
+        scale_ref, refs = refs[0], refs[1:]
     left_hbm, right_hbm = refs[0], refs[1]
     cin_ref = refs[2] if spec.accumulate else None
     o_ref = refs[3] if spec.accumulate else refs[2]
@@ -717,10 +757,7 @@ def _pipelined_kernel(sign_ref, lidx_ref, lsgn_ref, ridx_ref, rsgn_ref,
     def _wait(s):
         _each_real_copy(s, "wait")
 
-    if spec.accumulate:
-        acc_ref[...] = cin_ref[...].astype(acc_ref.dtype)
-    else:
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    _seed(acc_ref, cin_ref, scale_ref)
 
     for i in range(depth - 1):                     # pipeline warm-up
         pl.when(i < n_steps)(functools.partial(_start, i))
@@ -739,7 +776,7 @@ def _pipelined_kernel(sign_ref, lidx_ref, lsgn_ref, ridx_ref, rsgn_ref,
                          n_left)
         right = _right_sum(lambda q: r_bufs[slot, q], ridx_ref, rsgn_ref,
                            ld, c, k, jq, spec, n_right)
-        contrib = sign_ref[_slot(ld, c, spec)].astype(jnp.float32) \
+        contrib = _coef(sign_ref, scale_ref, ld, c, spec) \
             * jnp.dot(left, right, preferred_element_type=jnp.float32)
         acc_ref[...] += contrib.astype(acc_ref.dtype)
         return carry
@@ -825,9 +862,7 @@ def _pipelined_call(spec: _Spec, tables, operands, out_dtype, interpret):
             pltpu.VMEM((spec.bi, spec.bj), jnp.dtype(spec.acc_dtype)),
         ],
     )
-    with jax.named_scope(
-            f"fused:{spec.kind}:l{spec.levels}:{spec.variant}:{spec.gram}"
-            f":pd{depth}"):
+    with jax.named_scope(f"{spec.scope}:pd{depth}"):
         return pl.pallas_call(
             functools.partial(_pipelined_kernel, spec=spec,
                               l_shape=l_shape, r_shape=r_shape),
@@ -838,18 +873,33 @@ def _pipelined_call(spec: _Spec, tables, operands, out_dtype, interpret):
             # sequential sweep lives inside the kernel body.
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel",)),
+            input_output_aliases=_stack_alias(
+                operands[2] if spec.accumulate else None, out_dtype,
+                n_tab + len(operands)),
             interpret=interpret,
         )(*tables, *operands)
 
 
+def _stack_alias(c_in, out_dtype, n_args: int) -> dict:
+    """Accumulating programs write their result over the incoming stack
+    (the call's last argument) when it has the result's dtype: updated
+    in place where the caller donates it, copied first by XLA where it
+    does not."""
+    if c_in is None or jnp.dtype(c_in.dtype) != jnp.dtype(out_dtype):
+        return {}
+    return {n_args - 1: 0}
+
+
 def _execute(spec: _Spec, left: jax.Array, right: jax.Array,
-             out_dtype, interpret, c_in: Optional[jax.Array] = None):
+             out_dtype, interpret, c_in: Optional[jax.Array] = None,
+             scale: Optional[jax.Array] = None):
     """Run a bound program — the single ``pallas_call`` site.
 
     ``left``/``right`` are the padded operand arrays (the same array for
-    the one-input gram kinds); ``c_in`` the incoming packed stack for
-    accumulating programs.  Returns the raw output buffer: the packed
-    tri stack for tri-packed programs, the dense (padded) grid otherwise.
+    the one-input gram kinds); ``c_in`` the incoming packed stack and
+    ``scale`` the float32 ``(beta, alpha)`` of accumulating programs.
+    Returns the raw output buffer: the packed tri stack for tri-packed
+    programs, the dense (padded) grid otherwise.
 
     ``spec.pipeline_depth >= 2`` routes to the revolving-buffer DMA
     pipeline (one grid step per output tile, the (contribution, K) sweep
@@ -858,12 +908,15 @@ def _execute(spec: _Spec, left: jax.Array, right: jax.Array,
     ``acc_dtype``.
     """
     tables = _program_tables(spec.kind, spec.levels, spec.variant,
-                             spec.gram, spec.trans_a, spec.trans_b)
+                             spec.gram, spec.trans_a, spec.trans_b,
+                             spec.gram_of)
+    if spec.accumulate:
+        tables = tables + (scale,)
     if spec.pipeline_depth > 1:
         return _execute_pipelined(spec, tables, left, right, out_dtype,
                                   interpret, c_in)
     # the grid walk visits every padded slot, so it takes no count table
-    tables = tables[:5]
+    tables = tables[:5] + tables[6:]
     n_tab = len(tables)
 
     def left_map(p):
@@ -874,7 +927,7 @@ def _execute(spec: _Spec, left: jax.Array, right: jax.Array,
         return index_map
 
     def right_map(q):
-        def index_map(t, c, k, sign, lidx, lsgn, ridx, rsgn):
+        def index_map(t, c, k, sign, lidx, lsgn, ridx, rsgn, *tabs):
             gi, gj = _decode_out(t, spec)
             return _right_block(ridx, _dest_ld(gi, gj, spec), c, q, k,
                                 gj % spec.q_j, spec)
@@ -908,8 +961,7 @@ def _execute(spec: _Spec, left: jax.Array, right: jax.Array,
     # lands in the HLO metadata of the pallas_call, so profiler traces
     # and HLO censuses attribute kernel time/traffic to the schedule
     # that produced it (DESIGN.md §14)
-    with jax.named_scope(
-            f"fused:{spec.kind}:l{spec.levels}:{spec.variant}:{spec.gram}"):
+    with jax.named_scope(spec.scope):
         return pl.pallas_call(
             functools.partial(_leaf_kernel, spec=spec),
             grid_spec=grid_spec,
@@ -919,6 +971,8 @@ def _execute(spec: _Spec, left: jax.Array, right: jax.Array,
             # accumulator and must stay sequential per tile.
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+            input_output_aliases=_stack_alias(c_in, out_dtype,
+                                              n_tab + len(operands)),
             interpret=interpret,
         )(*tables, *operands)
 
@@ -1313,10 +1367,11 @@ _fused_aat_dense.defvjp(_fused_aat_dense_fwd, _fused_aat_dense_bwd)
 
 
 # ---------------------------------------------------------------------------
-# Fused rank-k update: C += A^t A against an existing packed stack — the
-# accumulating ata program.  The incoming stack seeds the VMEM
-# accumulator tile-wise, so a streamed Gram update is ONE kernel with no
-# per-chunk delta stack and no unpack/gather in HBM.
+# Fused rank-k update: C <- beta C + alpha tril(A^t A) (or of A A^t) against
+# an existing packed stack — the accumulating gram program.  The incoming
+# stack, scaled by beta, seeds the VMEM accumulator tile-wise and the result
+# is written over it, so a streamed Gram update or a statistics EMA step is
+# ONE kernel with no delta stack and no unpack/gather in HBM.
 # ---------------------------------------------------------------------------
 
 def fused_rank_k_update(
@@ -1326,6 +1381,9 @@ def fused_rank_k_update(
     levels: int = 2,
     variant: str = "strassen",
     gram: str = "strassen",
+    gram_of: str = "cols",
+    beta=1.0,
+    alpha=1.0,
     bk: int = 256,
     out_dtype=None,
     interpret=None,
@@ -1333,29 +1391,38 @@ def fused_rank_k_update(
     operand_dtype=None,
     acc_dtype=None,
 ) -> jax.Array:
-    """``C += tril(a.T @ a)`` on a packed lower-triangular tile stack.
+    """``C <- beta C + alpha tril(a.T @ a)`` on a packed lower-triangular
+    tile stack (``gram_of="rows"``: ``tril(a @ a.T)``).
 
     ``c_stack`` is a ``(T(T+1)/2 * bn, bn)`` stack (``fused_ata_packed``
     / ``kernels.syrk`` ordering — the tile edge is read off the stack's
-    trailing dim); ``a`` is an (m, n) chunk with ``n <= T * bn`` (columns
-    zero-padded to the stack span, exact for the Gram).  Returns the
-    updated stack, same shape/dtype discipline as the input.
+    trailing dim); ``a`` is an (m, n) operand whose Gram side spans at
+    most ``T * bn`` (n for the columns side, m for the rows side; zero-
+    padded to the stack span, exact for the Gram).  ``bk`` tiles the
+    contraction (the other side).  ``beta`` and ``alpha`` are run-time
+    scalars — one executable serves every value, and ``beta = alpha =
+    1`` (the default) is the plain accumulation ``C += tril(...)`` bit for
+    bit.  Returns the updated stack, written over the incoming one
+    (in place where the caller donates it).
 
     ``levels`` is clamped to depths dividing the (fixed) stack layout,
-    like :func:`fused_symm_matmul`.  Differentiable in both arguments:
-    the stack cotangent passes through packed, and ``dA`` runs the symm
-    program on the packed cotangent (DESIGN.md §11) — no dense n^2
-    buffer in either direction.
+    like :func:`fused_symm_matmul`.  Differentiable in the stack and in
+    ``a`` (the scalars get a zero cotangent): the stack cotangent passes
+    through packed (times beta), and ``dA`` runs the symm program on the
+    packed cotangent (DESIGN.md §11) — no dense n^2 buffer in either
+    direction.
 
     ``pipeline_depth``/``operand_dtype``/``acc_dtype`` as in
     :func:`fused_ata_packed`; ``operand_dtype`` quantizes only the
-    incoming chunk ``a`` — the running stack seeds the accumulator at
-    its own precision, so streamed state never degrades.
+    incoming ``a`` — the running stack seeds the accumulator at its own
+    precision, so streamed state never degrades.
     """
     interpret = _auto_interpret(interpret, site="fused_rank_k_update")
     depth = _resolve_pipeline_depth(pipeline_depth, interpret)
     op_dt = _resolve_operand_dtype(operand_dtype)
     acc_dt = _resolve_acc_dtype(acc_dtype)
+    if gram_of not in ("cols", "rows"):
+        raise ValueError(f"gram_of must be 'cols' or 'rows', got {gram_of!r}")
     if c_stack.ndim != 2 or a.ndim != 2:
         raise ValueError(f"bad ranks: stack {c_stack.shape} x {a.shape}")
     bn = c_stack.shape[1]
@@ -1366,70 +1433,88 @@ def fused_rank_k_update(
     T = (math.isqrt(8 * n_tri + 1) - 1) // 2
     if T * (T + 1) // 2 != n_tri:
         raise ValueError(f"stack of {n_tri} tiles is not triangular")
-    N = T * bn
-    if a.shape[1] > N:
-        raise ValueError(f"chunk has {a.shape[1]} cols but the stack "
-                         f"spans {N}")
+    side = a.shape[0] if gram_of == "rows" else a.shape[1]
+    if side > T * bn:
+        raise ValueError(f"a {a.shape} has a {gram_of} side of {side} but "
+                         f"the stack spans {T * bn}")
     out_dtype = (c_stack.dtype if out_dtype is None
                  else jnp.dtype(out_dtype))
-    return _fused_rank_k_core(c_stack, a, levels, variant, gram, bk, bn,
-                              out_dtype, jnp.dtype(c_stack.dtype),
-                              interpret, depth, op_dt, acc_dt)
+    scale = jnp.stack([jnp.asarray(beta, jnp.float32),
+                       jnp.asarray(alpha, jnp.float32)])
+    return _fused_rank_k_core(c_stack, a, scale, levels, variant, gram,
+                              gram_of, bk, bn, out_dtype,
+                              jnp.dtype(c_stack.dtype), interpret, depth,
+                              op_dt, acc_dt)
 
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(2, 3, 4, 5, 6, 7, 8, 9, 10, 11, 12))
-def _fused_rank_k_core(c_stack, a, levels, variant, gram, bk, bn, out_dtype,
-                       stack_dtype, interpret, pipeline_depth,
-                       operand_dtype, acc_dtype):
-    return _fused_rank_k_exec(c_stack, a, levels, variant, gram, bk, bn,
-                              out_dtype, interpret, pipeline_depth,
-                              operand_dtype, acc_dtype)
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11, 12, 13, 14))
+def _fused_rank_k_core(c_stack, a, scale, levels, variant, gram, gram_of,
+                       bk, bn, out_dtype, stack_dtype, interpret,
+                       pipeline_depth, operand_dtype, acc_dtype):
+    return _fused_rank_k_exec(c_stack, a, scale, levels, variant, gram,
+                              gram_of, bk, bn, out_dtype, interpret,
+                              pipeline_depth, operand_dtype, acc_dtype)
 
 
-def _fused_rank_k_exec(c_stack, a, levels, variant, gram, bk, bn, out_dtype,
-                       interpret, pipeline_depth=1, operand_dtype=None,
-                       acc_dtype="float32"):
+def _fused_rank_k_exec(c_stack, a, scale, levels, variant, gram, gram_of,
+                       bk, bn, out_dtype, interpret, pipeline_depth=1,
+                       operand_dtype=None, acc_dtype="float32"):
     n_tri = c_stack.shape[0] // bn
     T = (math.isqrt(8 * n_tri + 1) - 1) // 2
     N = T * bn
+    rows = gram_of == "rows"
     m, n = a.shape
-    geo = _rank_k_geometry(m, T, levels, variant, bk, gram=gram)
-    plan, M = geo["plan"], geo["M"]
-    if (M, N) != (m, n):
-        a = jnp.pad(a, ((0, M - m), (0, N - n)))
+    k_len = n if rows else m        # the contraction: the other side
+    geo = _rank_k_geometry(k_len, T, levels, variant, bk, gram=gram,
+                           gram_of=gram_of)
+    plan, K = geo["plan"], geo["M"]
+    shape = (N, K) if rows else (K, N)
+    if shape != (m, n):
+        a = jnp.pad(a, ((0, shape[0] - m), (0, shape[1] - n)))
     if operand_dtype is not None:
         a = a.astype(jnp.dtype(operand_dtype))
     spec = _bind(plan, n_out=geo["n_tri"], n_tj=0, q_i=geo["nbt"],
                  q_j=geo["nbt"], n_k=geo["n_k"], bi=bn, bj=bn, bc=bk,
                  pipeline_depth=pipeline_depth, acc_dtype=acc_dtype)
-    return _execute(spec, a, a, out_dtype, interpret, c_in=c_stack)
+    return _execute(spec, a, a, out_dtype, interpret, c_in=c_stack,
+                    scale=scale)
 
 
-def _fused_rank_k_fwd(c_stack, a, levels, variant, gram, bk, bn, out_dtype,
+def _fused_rank_k_fwd(c_stack, a, scale, levels, variant, gram, gram_of, bk,
+                      bn, out_dtype, stack_dtype, interpret, pipeline_depth,
+                      operand_dtype, acc_dtype):
+    out = _fused_rank_k_core(c_stack, a, scale, levels, variant, gram,
+                             gram_of, bk, bn, out_dtype, stack_dtype,
+                             interpret, pipeline_depth, operand_dtype,
+                             acc_dtype)
+    return out, (a, scale)
+
+
+def _fused_rank_k_bwd(levels, variant, gram, gram_of, bk, bn, out_dtype,
                       stack_dtype, interpret, pipeline_depth, operand_dtype,
-                      acc_dtype):
-    return (_fused_rank_k_core(c_stack, a, levels, variant, gram, bk, bn,
-                               out_dtype, stack_dtype, interpret,
-                               pipeline_depth, operand_dtype, acc_dtype), a)
-
-
-def _fused_rank_k_bwd(levels, variant, gram, bk, bn, out_dtype, stack_dtype,
-                      interpret, pipeline_depth, operand_dtype, acc_dtype,
-                      a, g):
-    # C_out = C_in + tril(A^t A): dC_in = g (packed pass-through, cast
-    # back to the stack primal's dtype); dA = A (S + S^t) with S the
-    # block-lower cotangent stack.
+                      acc_dtype, res, g):
+    # C_out = beta C_in + alpha P, P = tril(A^t A) (rows: tril(A A^t)) as
+    # the stack holds it: dC_in = beta g (packed pass-through, cast back
+    # to the stack primal's dtype); dA = alpha A (S + S^t) with S the
+    # block-lower cotangent stack (rows: alpha (S + S^t) A, the same
+    # symm program on A^t); the scalars are not differentiated.
+    a, scale = res
     acc = jnp.promote_types(a.dtype, jnp.float32)
-    n = a.shape[1]
+    rows = gram_of == "rows"
+    x = a.T if rows else a
+    n = x.shape[1]
     T = (math.isqrt(8 * (g.shape[0] // bn) + 1) - 1) // 2
-    lv = _rank_k_geometry(a.shape[0], T, levels, variant, bk,
-                          gram=gram)["levels"]
-    da = fused_symm_matmul(a, g, levels=lv, variant=variant, bm=bk,
+    lv = _rank_k_geometry(x.shape[0], T, levels, variant, bk, gram=gram,
+                          gram_of=gram_of)["levels"]
+    da = fused_symm_matmul(x, g, levels=lv, variant=variant, bm=bk,
                            diag_sym=True, out_dtype=acc,
                            interpret=interpret,
                            pipeline_depth=pipeline_depth)[:, :n]
-    return g.astype(stack_dtype), da.astype(a.dtype)
+    da = (scale[1].astype(acc) * da).astype(a.dtype)
+    dc = scale[0] * g.astype(jnp.float32)
+    return (dc.astype(stack_dtype), (da.T if rows else da),
+            jnp.zeros_like(scale))
 
 
 _fused_rank_k_core.defvjp(_fused_rank_k_fwd, _fused_rank_k_bwd)
@@ -1557,7 +1642,8 @@ def _traffic(spec: _Spec, *, left_bytes: int, right_bytes: int,
     side), and ``skipped_fetch_share`` the share of that grid's tile
     fetches the depth>=2 walk does not issue."""
     counts = _program_tables(spec.kind, spec.levels, spec.variant,
-                             spec.gram, spec.trans_a, spec.trans_b)[5]
+                             spec.gram, spec.trans_a, spec.trans_b,
+                             spec.gram_of)[5]
     counts = counts.reshape(-1, spec.n_c + 1).astype(np.int64)
     tiles = _dest_tiles(spec, counts.shape[0])
     assert tiles.sum() == spec.n_out, (tiles.sum(), spec.n_out)

@@ -1,40 +1,51 @@
-"""Blocked distributed Shampoo with ATA-powered gram statistics.
+"""Blocked distributed Shampoo whose statistics run on the packed rank-k path.
 
-This is the paper's technique integrated as a first-class training feature:
-the preconditioner statistics of every 2-D gradient block are exactly the
-paper's operation —
+The preconditioner statistics of every 2-D gradient block G are the
+paper's operation on each side of G, kept as exponential moving averages
+(Distributed Shampoo, Shi et al., arXiv:2309.06497):
 
-    L = G G^t = ATA(G^t),    R = G^t G = ATA(G)
+    L <- beta2 L + alpha G G^t,    R <- beta2 R + alpha G^t G
 
-— computed with the Strassen-based ATA recursion, i.e. at (2/7) n^{log2 7}
-multiplications instead of n^2(n+1)/2, and symmetric by construction (only
-the lower triangle is computed, then mirrored).  The block stack goes
-through the Gram service's batched path (``repro.gram.batched_gram``):
-one vmapped mode-dispatched ATA over all blocks — the fused Pallas
-schedule on TPU, the XLA reference recursion elsewhere — with
-``ata_mode=`` exposed to force either.
+with ``alpha = 1 - beta2``, or ``alpha = 1`` when ``beta2 = 1`` (a plain
+sum, Distributed Shampoo's own default).  Each statistic lives as the
+executor's packed lower-triangular tile stack (about n(n+1)/2 words) and
+each update is ONE accumulating kernel (``gram.stream.stack_update``:
+``gram_of="rows"`` for L, ``"cols"`` for R): the stack, scaled by
+``beta2`` at run time, seeds the kernel's accumulator and the result is
+written over it.  G is never transposed and no dense statistic exists;
+:func:`update_stats` runs the two updates of every block in one jitted,
+donated call.  The kernel runs the Strassen recursion to a capped depth
+(``ata_levels``; classical leaf products below it, so 0.852x the
+classical multiplications at levels 3), fused on TPU; ``ata_mode=
+"reference"`` (the default off-TPU) runs the XLA reference recursion
+into the same stacks instead.
 
 Structure (after Anil et al.'s distributed Shampoo):
   * large dims are partitioned into blocks of <= block_size; each sub-block
     is preconditioned independently (block-diagonal Shampoo);
   * leading dims beyond the trailing 2 (layer stacks, expert stacks) are
-    vmapped batch dims;
+    folded into the block list;
   * inverse-4th-roots via eigh, recomputed every ``precond_interval`` steps
     under lax.cond (kept OUTSIDE the block vmap so the skip branch really
-    skips);
+    skips) — the only place a statistic is unpacked to dense;
   * Adam grafting: the Shampoo direction is rescaled to the Adam update's
     norm; 1-D params (biases, norm scales) fall back to plain AdamW.
 """
 from __future__ import annotations
 
+import functools
 import math
-from functools import partial
-from typing import Optional
+from typing import Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 
-from ..gram.engine import batched_gram
+from ..core.ata import ata_full
+from ..core.strassen import resolve_mode
+from ..core.symmetry import pack_tril_blocks
+from ..gram import stream
+from ..obs import metrics as _metrics
+from ..obs import trace as _trace
 from .adamw import Optimizer, clip_by_global_norm
 
 
@@ -83,27 +94,151 @@ def _inv_4th_root(s, eps):
     return (u * (w ** -0.25)) @ u.T
 
 
+# ---------------------------------------------------------------------------
+# Statistics: packed L and R stacks, one accumulating kernel per side.
+# ---------------------------------------------------------------------------
+
+def _stat_tile(bs: int, block: Optional[int] = None) -> int:
+    """Tile edge of a ``bs``-wide statistic's stack: ``block``, else the
+    autotune cache's winner (256 untuned), never wider than ``bs``
+    rounded up to 8."""
+    if block is None:
+        from ..kernels.ops import _resolve_blocks
+        block = _resolve_blocks("rank_k", bs, bs, jnp.float32, bn=None)["bn"]
+    return min(block, -(-bs // 8) * 8)
+
+
+def init_stats(block_shapes: Sequence[Tuple[int, int]], *,
+               block: Optional[int] = None) -> dict:
+    """Zero statistics of gradient blocks of the given (rows, cols)
+    shapes: ``{"l": [rows x rows stack per block], "r": [cols x cols]}``,
+    each a :class:`~repro.gram.stream.GramStackStream` in fp32.
+
+    The stacks are committed to the default device, as a step's outputs
+    are, so the first :func:`update_stats` and every later one share one
+    executable."""
+    dev = jax.devices()[0]
+
+    def zeros(n):
+        return jax.device_put(stream.stack_init(n, block=_stat_tile(n, block)),
+                              dev)
+    return {"l": [zeros(r) for r, _ in block_shapes],
+            "r": [zeros(c) for _, c in block_shapes]}
+
+
+def _fold(state, g, gram_of, beta, alpha, *, levels, leaf, variant, mode,
+          block, interpret):
+    """``state <- beta state + alpha tril(Gram)`` of one gradient block."""
+    if resolve_mode(mode) == "fused":
+        return stream.stack_update(state, g, levels=levels, leaf=leaf,
+                                   variant=variant, block=block,
+                                   gram_of=gram_of, beta=beta, alpha=alpha,
+                                   interpret=interpret)
+    # the XLA reference recursion, packed into the same stack layout
+    dense = ata_full(g, gram_of=gram_of, levels=levels, leaf=leaf,
+                     variant=variant, mode="reference",
+                     out_dtype=state.stack.dtype)
+    pad = state.n_padded - dense.shape[0]
+    delta = pack_tril_blocks(jnp.pad(dense, ((0, pad), (0, pad))),
+                             state.block)
+    k = g.shape[1] if gram_of == "rows" else g.shape[0]
+    return stream.GramStackStream(stack=beta * state.stack + alpha * delta,
+                                  rows=state.rows + k)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,), static_argnames=(
+    "levels", "leaf", "variant", "mode", "block", "interpret"))
+def _stats_step(stats, grad_blocks, beta, alpha, *, levels, leaf, variant,
+                mode, block, interpret):
+    """Every block's L and R update, one after another, in one program;
+    ``beta`` and ``alpha`` are traced float32 scalars, so one executable
+    serves every ``beta2``."""
+    kw = dict(levels=levels, leaf=leaf, variant=variant, mode=mode,
+              block=block, interpret=interpret)
+    return {"l": [_fold(st, g, "rows", beta, alpha, **kw)
+                  for st, g in zip(stats["l"], grad_blocks)],
+            "r": [_fold(st, g, "cols", beta, alpha, **kw)
+                  for st, g in zip(stats["r"], grad_blocks)]}
+
+
+def update_stats(stats: dict, grad_blocks, beta2: float = 1.0, *,
+                 levels: Union[int, str] = "auto", leaf: int = 256,
+                 variant: str = "strassen", mode: str = "auto",
+                 block: Optional[int] = None,
+                 interpret: Optional[bool] = None) -> dict:
+    """One statistics step of Distributed Shampoo on packed stacks.
+
+    ``stats`` is :func:`init_stats`' structure (donated: every stack is
+    updated in place); ``grad_blocks`` the (rows, cols) gradient blocks,
+    in the same order.  Each block's ``L <- beta2 L + alpha G G^t`` and
+    ``R <- beta2 R + alpha G^t G`` run as one accumulating kernel each
+    (``alpha = 1 - beta2``, or 1 when ``beta2 = 1``), all in one jitted
+    call.  ``block`` is the kernels' contraction tile; ``mode`` as in
+    ``core.ata`` ("auto": the kernel on TPU, the XLA reference recursion
+    elsewhere; "fused" forces the kernel).  Outside a trace the
+    step is a live ``shampoo_stats`` span and counts its Grams in
+    ``shampoo_stat_grams_total{side}``.
+    """
+    grad_blocks = list(grad_blocks)
+    if len(grad_blocks) != len(stats["l"]):
+        raise ValueError(f"{len(grad_blocks)} gradient blocks for "
+                         f"{len(stats['l'])} statistics")
+    alpha = 1.0 if beta2 == 1.0 else 1.0 - beta2
+    step = functools.partial(_stats_step, levels=levels, leaf=leaf,
+                             variant=variant, mode=mode, block=block,
+                             interpret=interpret)
+    beta, alpha = jnp.float32(beta2), jnp.float32(alpha)
+    if any(isinstance(x, jax.core.Tracer) for x in
+           jax.tree.leaves((stats, grad_blocks))):
+        return step(stats, grad_blocks, beta, alpha)
+    k = len(grad_blocks)
+    with _trace.span("shampoo_stats", blocks=k, sides="l,r"):
+        out = step(stats, grad_blocks, beta, alpha)
+    grams = _metrics.counter("shampoo_stat_grams_total",
+                             "Shampoo statistic Gram updates dispatched")
+    grams.inc(k, side="l")
+    grams.inc(k, side="r")
+    return out
+
+
+def dense_stats(stats: dict, block_shapes) -> dict:
+    """The statistics as dense symmetric fp32 matrices (for the inverse
+    roots, and for checking): ``{"l": [(rows, rows)], "r": [...]}``."""
+    return {"l": [stream.stack_finalize(st, r)
+                  for st, (r, _) in zip(stats["l"], block_shapes)],
+            "r": [stream.stack_finalize(st, c)
+                  for st, (_, c) in zip(stats["r"], block_shapes)]}
+
+
+# ---------------------------------------------------------------------------
+# The optimizer
+# ---------------------------------------------------------------------------
+
 def shampoo(lr, *, block_size: int = 1024, stat_interval: int = 1,
             precond_interval: int = 20, beta2_stat: float = 1.0,
             b1=0.9, b2=0.95, eps=1e-8, matrix_eps=1e-6,
             weight_decay=0.1, grad_clip: Optional[float] = 1.0,
-            ata_levels: int = 1, ata_leaf: int = 128,
+            ata_levels: Union[int, str] = "auto", ata_leaf: int = 128,
             max_blocks: int = 64,
             ata_variant: str = "strassen",
             ata_mode: str = "auto",
             ata_block: Optional[int] = None) -> Optimizer:
     """ATA-powered blocked Shampoo with Adam grafting.
 
-    ``ata_mode`` ("auto" | "fused" | "reference") and ``ata_block`` are
-    threaded to the batched Gram path — "auto" runs the fused Pallas
-    schedule on TPU and the reference recursion elsewhere; ``ata_block=
-    None`` consults the gram autotune cache for the tile size.
+    ``beta2_stat`` is the statistics' EMA factor (1.0, the default, sums
+    them).  ``ata_mode`` ("auto" | "fused" | "reference") picks the
+    statistics' engine — "auto" runs the accumulating kernel on TPU and
+    the XLA reference recursion elsewhere; ``ata_block`` is the
+    statistics' tile edge (``None`` consults the gram autotune cache).
     """
     lr_fn = lr if callable(lr) else (lambda _: lr)
+    stat_kw = dict(levels=ata_levels, leaf=ata_leaf, variant=ata_variant,
+                   mode=ata_mode, block=ata_block)
 
-    gram = partial(batched_gram, levels=ata_levels, leaf=ata_leaf,
-                   variant=ata_variant, mode=ata_mode, block=ata_block,
-                   out_dtype=jnp.float32)
+    def _shapes(p, plan):
+        nbm, bsm, nbn, bsn = plan
+        k = math.prod(p.shape[:-2] or (1,)) * nbm * nbn
+        return [(bsm, bsn)] * k
 
     def init(params):
         f32 = lambda p: jnp.zeros(p.shape, jnp.float32)
@@ -111,14 +246,13 @@ def shampoo(lr, *, block_size: int = 1024, stat_interval: int = 1,
         def stats(p):
             plan = _plan(p.shape, block_size, max_blocks)
             if plan is None:
-                return {"l": jnp.zeros((0,)), "r": jnp.zeros((0,)),
-                        "pl": jnp.zeros((0,)), "pr": jnp.zeros((0,))}
-            nbm, bsm, nbn, bsn = plan
-            k = math.prod(p.shape[:-2] or (1,)) * nbm * nbn
+                return {"l": [], "r": [], "pl": jnp.zeros((0,)),
+                        "pr": jnp.zeros((0,))}
+            shapes = _shapes(p, plan)
+            k, (bsm, bsn) = len(shapes), shapes[0]
             eye = lambda bs: jnp.broadcast_to(jnp.eye(bs, dtype=jnp.float32),
                                               (k, bs, bs))
-            return {"l": jnp.zeros((k, bsm, bsm), jnp.float32),
-                    "r": jnp.zeros((k, bsn, bsn), jnp.float32),
+            return {**init_stats(shapes, block=ata_block),
                     "pl": eye(bsm), "pr": eye(bsn)}
 
         return {"m": jax.tree.map(f32, params),
@@ -154,23 +288,19 @@ def shampoo(lr, *, block_size: int = 1024, stat_interval: int = 1,
                 new_gr = gr
             else:
                 blk = _to_blocks(g, plan)              # (K, bsm, bsn)
-
-                def upd_stats(_):
-                    # THE paper's operation: block grams via the batched
-                    # Strassen-ATA service path (mode/out_dtype threaded)
-                    l_new = gram(jnp.swapaxes(blk, -1, -2))
-                    r_new = gram(blk)
-                    if beta2_stat >= 1.0:
-                        return gr["l"] + l_new, gr["r"] + r_new
-                    return (beta2_stat * gr["l"] + (1 - beta2_stat) * l_new,
-                            beta2_stat * gr["r"] + (1 - beta2_stat) * r_new)
-
-                sl, sr = jax.lax.cond(do_stat, upd_stats,
-                                      lambda _: (gr["l"], gr["r"]), None)
+                shapes = _shapes(p, plan)
+                stats = {"l": gr["l"], "r": gr["r"]}
+                stats = jax.lax.cond(
+                    do_stat,
+                    lambda st: update_stats(st, list(blk), beta2_stat,
+                                            **stat_kw),
+                    lambda st: st, stats)
 
                 def recompute(_):
-                    return (jax.vmap(lambda s: _inv_4th_root(s, matrix_eps))(sl),
-                            jax.vmap(lambda s: _inv_4th_root(s, matrix_eps))(sr))
+                    dense = dense_stats(stats, shapes)
+                    root = jax.vmap(lambda s: _inv_4th_root(s, matrix_eps))
+                    return (root(jnp.stack(dense["l"])),
+                            root(jnp.stack(dense["r"])))
 
                 pl, pr = jax.lax.cond(do_precond, recompute,
                                       lambda _: (gr["pl"], gr["pr"]), None)
@@ -182,7 +312,7 @@ def shampoo(lr, *, block_size: int = 1024, stat_interval: int = 1,
                 ratio = (jnp.linalg.norm(u_adam)
                          / jnp.maximum(jnp.linalg.norm(u_sh), 1e-16))
                 u = u_sh * ratio
-                new_gr = {"l": sl, "r": sr, "pl": pl, "pr": pr}
+                new_gr = {**stats, "pl": pl, "pr": pr}
             u = u + weight_decay * p.astype(jnp.float32)
             return -lr_t * u, new_gr
 

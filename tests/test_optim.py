@@ -91,8 +91,11 @@ def test_shampoo_blocks_large_dim():
     params = {"w": jnp.zeros((8, 6))}     # 2x2 blocks of (4, 3)... 4|8, 6->pad
     state = opt.init(params)
     gr = state["gram"]["w"]
-    assert gr["l"].shape == (2 * 2, 4, 4)
-    assert gr["r"].shape == (2 * 2, 4, 4) or gr["r"].shape == (4, 3, 3)
+    # 2x2 blocks of (4, 4): one packed L and one packed R stack a block
+    assert len(gr["l"]) == len(gr["r"]) == 2 * 2
+    for st in gr["l"] + gr["r"]:
+        assert st.stack.ndim == 2 and st.n_padded >= 4
+    assert gr["pl"].shape == gr["pr"].shape == (2 * 2, 4, 4)
 
 
 def test_shampoo_1d_falls_back_to_adam():

@@ -88,6 +88,23 @@ def test_rank_k_levels3_compiles(one_chip):
     assert "fused:rank_k:l3:" in text
 
 
+@pytest.mark.parametrize("gram_of,shape", [("rows", (8192, 4096)),
+                                           ("cols", (4096, 8192))])
+def test_scaled_rank_k_at_shampoo_width_compiles(one_chip, gram_of, shape):
+    """Shampoo's statistics update at its 8192-wide blocks: the scaled
+    accumulate on either side, written over its donated stack."""
+    t = 8192 // 256
+    text = _compile(
+        lambda c, a, b: ops.rank_k_update(c, a, levels=3, gram_of=gram_of,
+                                          beta=b, alpha=1 - b, bk=256,
+                                          interpret=False),
+        _f32(one_chip, t * (t + 1) // 2 * 256, 256), _f32(one_chip, *shape),
+        _f32(one_chip))
+    kind = "rank_k_rows" if gram_of == "rows" else "rank_k"
+    assert f"fused:{kind}:l3:" in text
+    assert "output_to_operand_aliasing" in text
+
+
 def test_matmul_levels3_compiles(one_chip):
     """Levels 3 is the deepest Strassen matmul the fan-in and SMEM table
     clamps keep."""
