@@ -229,8 +229,10 @@ def phase_stream(seed):
 def phase_shampoo(seed):
     """Shampoo's statistics step at 8192-wide blocks: three update_stats
     steps at beta2 0.999 of an 8192 x 4096 and a 4096 x 8192 fp32 block
-    (the scaled rows-side and columns-side accumulating kernels at levels
-    3), against (1 - beta2^3) G G^t and (1 - beta2^3) G^t G."""
+    (the scaled rows-side and columns-side accumulating kernels at an
+    explicit levels 3: ``"auto"`` picks classical blocking at these
+    shapes, so this phase keeps a Strassen program checked on the chip),
+    against (1 - beta2^3) G G^t and (1 - beta2^3) G^t G."""
     import importlib
     shampoo = importlib.import_module("repro.optim.shampoo")
     shapes, beta2, steps = SHAMPOO_BLOCKS, 0.999, 3
@@ -239,7 +241,7 @@ def phase_shampoo(seed):
     b, a = jnp.float32(beta2), jnp.float32(1 - beta2)
     t0 = time.perf_counter()
     compiled = shampoo._stats_step.lower(
-        stats, grads, b, a, levels="auto", leaf=256, variant="strassen",
+        stats, grads, b, a, levels=3, leaf=256, variant="strassen",
         mode="auto", block=None, interpret=None).compile()
     compile_s = time.perf_counter() - t0
     kern = kernels_of(compiled, "update_stats")
@@ -248,7 +250,7 @@ def phase_shampoo(seed):
     for _ in range(steps):
         t0 = time.perf_counter()
         stats = jax.block_until_ready(
-            shampoo.update_stats(stats, grads, beta2, levels="auto"))
+            shampoo.update_stats(stats, grads, beta2, levels=3))
         call_s = time.perf_counter() - t0
     dense = shampoo.dense_stats(stats, shapes)
     scale = 1.0 - np.float32(beta2) ** steps

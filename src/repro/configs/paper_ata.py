@@ -21,9 +21,10 @@ PAPER_COMM_FRACTION = (0.0014, 0.0046)  # §6.3.2 (P=6 .. P=250)
 class GramCell:
     """One distributed-gram dry-run cell: A (m, n) sharded on the mesh.
 
-    ``levels="auto"`` (the default) lets ``ata_levels_for`` /
-    ``strassen_levels_for`` pick the natural per-shard recursion depth
-    (capped at ``strassen.AUTO_MAX_LEVELS``) instead of a hard-coded 2.
+    ``levels="auto"`` (the default) resolves per shard as ``ata`` does:
+    the fused executor's cheapest depth by its traffic model, or the
+    reference recursion's natural depth (``ata_levels_for`` /
+    ``strassen_levels_for``, capped at ``strassen.AUTO_MAX_LEVELS``).
     """
     name: str
     m: int

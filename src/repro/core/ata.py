@@ -39,7 +39,7 @@ from .strassen import (
 )
 from .symmetry import symmetrize_from_lower
 
-__all__ = ["ata", "ata_full", "ata_levels_for"]
+__all__ = ["ata", "ata_full", "ata_levels_for", "resolve_levels"]
 
 
 def _default_base_syrk(a: jax.Array) -> jax.Array:
@@ -84,12 +84,15 @@ def ata(
         (``dA = (S + S^t) A`` — a symmetric-LEFT product the symm
         program does not yet express), so ``bwd=`` applies to the
         ``"cols"`` path only.
-      levels: recursion depth cap (0 => classical SYRK), or ``"auto"`` to
-        recurse until a dimension reaches ``leaf`` (capped at
-        ``AUTO_MAX_LEVELS`` — see strassen.py for the rationale).
+      levels: recursion depth cap (0 => classical SYRK), or ``"auto"``
+        (:func:`resolve_levels`): on the fused path the executor's
+        cheapest depth by its own traffic model
+        (``strassen_fused.auto_levels``); in reference mode the
+        recursion's natural depth, until a dimension reaches ``leaf``
+        (capped at ``AUTO_MAX_LEVELS``).
       leaf: stop recursing when m or n <= leaf (paper: 32; TPU: 256).
-        Reference mode only (the fused schedule unrolls exactly ``levels``);
-        also sets the ``levels="auto"`` depth for both modes.
+        Reference mode only (the fused schedule unrolls exactly ``levels``,
+        and its ``"auto"`` depth ignores ``leaf``).
       variant: Strassen variant for the off-diagonal C21 products
         (any registered algebra — "strassen" | "winograd" | "classical"
         by default; ``leaf_ir.registered_algebras()``).
@@ -134,12 +137,15 @@ def ata(
     if gram_of not in ("cols", "rows"):
         raise ValueError(f"gram_of must be 'cols' or 'rows', got "
                          f"{gram_of!r}")
-    m, n = a.shape
-    if levels == "auto":
-        levels = min(ata_levels_for(m, n, leaf), AUTO_MAX_LEVELS)
     out_dtype = (jnp.promote_types(a.dtype, jnp.float32)
                  if out_dtype is None else jnp.dtype(out_dtype))
     mode = resolve_mode(mode, base_syrk, base_matmul)
+    levels = resolve_levels(levels, a.shape, a.dtype, mode=mode,
+                            gram_of=gram_of, leaf=leaf, variant=variant,
+                            gram=gram, block=block, out_dtype=out_dtype,
+                            operand_dtype=operand_dtype,
+                            pipeline_depth=pipeline_depth,
+                            interpret=interpret)
     if mode != "fused" and operand_dtype is not None:
         # Reference oracle for quantized operands: quantize once, then
         # recurse in the promoted compute dtype (the fused kernel upcasts
@@ -216,6 +222,40 @@ def ata_full(a: jax.Array, **kw) -> jax.Array:
     lower = ata(a, **kw)
     with jax.named_scope("gram:mirror"):
         return symmetrize_from_lower(lower)
+
+
+def resolve_levels(levels: Union[int, str], shape, dtype, *, mode: str,
+                   gram_of: str = "cols", leaf: int = DEFAULT_LEAF,
+                   variant: str = "strassen", gram: str = "strassen",
+                   block: Optional[int] = None, out_dtype=None,
+                   operand_dtype=None, pipeline_depth: Optional[int] = None,
+                   interpret: Optional[bool] = None) -> int:
+    """The depth :func:`ata` runs for ``levels`` on an A of ``shape`` and
+    ``dtype`` under a resolved ``mode``.  An integer is returned as it
+    is.  ``"auto"`` on the fused path is the executor's cheapest depth by
+    its traffic model (``strassen_fused.auto_levels``), at the tiles,
+    dtypes and pipeline depth the call will run; in reference mode it is
+    the recursion's natural depth (:func:`ata_levels_for` at ``leaf``,
+    capped at ``AUTO_MAX_LEVELS``)."""
+    if levels != "auto":
+        return levels
+    m, n = shape
+    if mode != "fused":
+        return min(ata_levels_for(m, n, leaf), AUTO_MAX_LEVELS)
+    from ..kernels.ops import _resolve_blocks
+    from ..kernels.strassen_fused import auto_levels
+    if gram_of == "rows":
+        kind, tiles = "aat", _resolve_blocks("aat", m, n, dtype, bm=block,
+                                             bk=block)
+    else:
+        kind, tiles = "ata", _resolve_blocks("ata", m, n, dtype, bk=block,
+                                             bn=block)
+    return auto_levels(
+        kind, m, n, variant=variant, gram=gram, **tiles,
+        operand_dtype=dtype if operand_dtype is None else operand_dtype,
+        out_dtype=(jnp.promote_types(dtype, jnp.float32)
+                   if out_dtype is None else out_dtype),
+        pipeline_depth=pipeline_depth, interpret=interpret)
 
 
 def ata_levels_for(m: int, n: int, leaf: int = DEFAULT_LEAF) -> int:

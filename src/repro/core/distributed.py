@@ -81,7 +81,7 @@ SCHEME_LADDER = ("bfs25d", "ring", "reducescatter", "allreduce")
 # ---------------------------------------------------------------------------
 
 def gram_allreduce(a_local: jax.Array, row_axis: str, *,
-                   levels=2, leaf: int = 256,
+                   levels="auto", leaf: int = 256,
                    variant: str = "strassen", mode: str = "auto",
                    out_dtype=None,
                    interpret: Optional[bool] = None) -> jax.Array:
@@ -103,7 +103,7 @@ def gram_allreduce(a_local: jax.Array, row_axis: str, *,
 
 
 def gram_reducescatter(a_local: jax.Array, row_axis: str, *,
-                       levels=2, leaf: int = 256,
+                       levels="auto", leaf: int = 256,
                        variant: str = "strassen", mode: str = "auto",
                        out_dtype=None,
                        interpret: Optional[bool] = None) -> jax.Array:
@@ -118,7 +118,7 @@ def gram_reducescatter(a_local: jax.Array, row_axis: str, *,
 
 def gram_ring(a_local: jax.Array, col_axis: str,
               row_axis: Optional[str] = None, *,
-              levels=2, leaf: int = 256,
+              levels="auto", leaf: int = 256,
               variant: str = "strassen", mode: str = "auto",
               out_dtype=None,
               interpret: Optional[bool] = None) -> jax.Array:
@@ -183,7 +183,7 @@ def ring_stack_len(T: int) -> int:
 
 def gram_bfs25d(a_local: jax.Array, col_axis: str, rep_axis: str,
                 row_axis: Optional[str] = None, *,
-                levels=2, leaf: int = 256,
+                levels="auto", leaf: int = 256,
                 variant: str = "strassen", mode: str = "auto",
                 out_dtype=None, col_size: Optional[int] = None,
                 rep_size: Optional[int] = None,
@@ -393,7 +393,7 @@ def distributed_gram(a: jax.Array, mesh: Mesh, *,
                      row_axis: str = "data",
                      col_axis: Optional[str] = None,
                      rep_axis: Optional[str] = None,
-                     levels=2, leaf: int = 256,
+                     levels="auto", leaf: int = 256,
                      variant: str = "strassen", mode: str = "auto",
                      out_dtype=None,
                      interpret: Optional[bool] = None,
@@ -417,6 +417,10 @@ def distributed_gram(a: jax.Array, mesh: Mesh, *,
                          ``cost_model.rank_gram_schemes`` (bytes moved +
                          message count + per-device flops) and run the
                          cheapest.
+
+    ``levels`` is the depth of every shard's local kernels; ``"auto"``
+    (the default) resolves on each shard's own shape, as ``ata`` and
+    ``strassen_matmul`` resolve it.
     """
     if scheme == "auto":
         from . import cost_model

@@ -34,12 +34,15 @@ import jax.numpy as jnp
 DEFAULT_LEAF = 256
 DEFAULT_LEVELS = 2
 
-# Cap for levels="auto".  Each Strassen level saves 12.5% multiplications
-# but costs one more bit of accumulated error, larger operand-sum fan-in
-# (2^levels gathered tiles per operand in the fused kernel) and
-# exponentially larger schedules/jaxprs; past ~3 levels the recombination
-# overhead dominates on MXU-class hardware (paper §6 uses 1-2 parallel
-# levels for the same reason).
+# Deepest depth levels="auto" considers.  On the fused path "auto" is the
+# cheapest depth up to this one by the executor's own traffic model
+# (``kernels.strassen_fused.auto_levels``): the executor recomputes each
+# Strassen product once for every destination it feeds, so a deeper
+# program reads more tile bytes than it saves in products, and the model
+# picks classical blocking on today's walk.  In reference mode "auto" is
+# the recursion's natural depth (down to ``leaf``), capped here: each
+# level costs one more bit of accumulated error and exponentially larger
+# schedules/jaxprs (paper §6 uses 1-2 parallel levels for the same reason).
 AUTO_MAX_LEVELS = 3
 
 
@@ -115,10 +118,12 @@ def strassen_matmul(
         copy — this is how ``core.distributed``'s ``A_loc^t A_perm``
         ring block tasks run); the reference recursion materializes
         ``.T`` (the oracle).
-      levels: max recursion depth (0 => classical), or ``"auto"`` to
-        recurse until a dim hits ``leaf`` (capped at AUTO_MAX_LEVELS).
+      levels: max recursion depth (0 => classical), or ``"auto"``: on
+        the fused path the executor's cheapest depth by its traffic model
+        (``strassen_fused.auto_levels``), in reference mode recursing
+        until a dim hits ``leaf`` (capped at AUTO_MAX_LEVELS).
       leaf: stop recursing when min(m, k, n) <= leaf (reference mode; also
-        sets the "auto" depth).
+        sets the reference "auto" depth).
       variant: "strassen" (7 mults / 18 adds, as in the paper),
                "winograd" (7 mults / 15 adds, beyond-paper option) or
                "classical".
@@ -145,12 +150,21 @@ def strassen_matmul(
         raise ValueError(
             f"bad shapes for matmul: {a.shape} x {b.shape} "
             f"(trans_a={trans_a}, trans_b={trans_b})")
-    if levels == "auto":
-        levels = min(strassen_levels_for(m, k_a, n, leaf), AUTO_MAX_LEVELS)
     out_dtype = (jnp.promote_types(jnp.promote_types(a.dtype, b.dtype),
                                    jnp.float32)
                  if out_dtype is None else jnp.dtype(out_dtype))
     mode = resolve_mode(mode, base_matmul)
+    if levels == "auto" and mode == "fused":
+        from ..kernels.ops import _resolve_blocks
+        from ..kernels.strassen_fused import auto_levels
+        levels = auto_levels(
+            "matmul", m, n, k=k_a, variant=variant,
+            **_resolve_blocks("matmul", m, n, a.dtype, bm=block, bk=block,
+                              bn=block),
+            operand_dtype=jnp.promote_types(a.dtype, b.dtype),
+            out_dtype=out_dtype, interpret=interpret)
+    elif levels == "auto":
+        levels = min(strassen_levels_for(m, k_a, n, leaf), AUTO_MAX_LEVELS)
     if mode == "fused":
         from ..kernels.ops import matmul_fused
         return matmul_fused(a, b, levels=levels, variant=variant, bm=block,

@@ -103,12 +103,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core.ata import ata, ata_levels_for
+from ..core.ata import ata, resolve_levels
 from ..core.cost_model import gram_serve_work
 from ..core.distributed import (default_gram_axes, distributed_gram,
                                 feasible_schemes, scheme_fallback_chain,
                                 shrink_mesh)
-from ..core.strassen import AUTO_MAX_LEVELS, resolve_mode
+from ..core.strassen import resolve_mode
 from ..core.symmetry import symmetrize_from_lower
 from ..obs import metrics as _metrics
 from ..obs import trace as _trace
@@ -712,10 +712,7 @@ class GramEngine:
         if u is None:
             M, N, _dtype, gram_of = key[:4]
             cfg = self._bucket_config(key, 0)
-            levels = cfg["levels"]
-            if levels == "auto":
-                levels = min(ata_levels_for(M, N, cfg["leaf"]),
-                             AUTO_MAX_LEVELS)
+            levels = self._resolved_levels(key, cfg)
             try:
                 u = float(gram_serve_work(M, N, gram_of=gram_of,
                                           leaf=cfg["leaf"],
@@ -1236,6 +1233,18 @@ class GramEngine:
         self._executables[ekey] = compiled
         return compiled
 
+    def _resolved_levels(self, key, cfg) -> int:
+        """The depth the bucket's executable runs: ``ata``'s own
+        resolution of ``cfg["levels"]`` for one slot's operand."""
+        M, N, dtype, gram_of = key[:4]
+        return resolve_levels(
+            cfg["levels"], (M, N), jnp.dtype(dtype),
+            mode=resolve_mode(cfg["mode"]), gram_of=gram_of,
+            leaf=cfg["leaf"], variant=cfg["variant"], block=cfg["block"],
+            out_dtype=self.out_dtype, operand_dtype=cfg.get("operand_dtype"),
+            pipeline_depth=cfg.get("pipeline_depth"),
+            interpret=self.interpret)
+
     # -- cost-model drift ---------------------------------------------------
     def _drift_prediction(self, key, cfg) -> Optional[float]:
         """Model-predicted HBM bytes for one (bucket, config) — the
@@ -1249,10 +1258,7 @@ class GramEngine:
         M, N, dtype, gram_of = key[:4]
         pred: Optional[float] = None
         try:
-            levels = cfg["levels"]
-            if levels == "auto":
-                levels = min(ata_levels_for(M, N, cfg["leaf"]),
-                             AUTO_MAX_LEVELS)
+            levels = self._resolved_levels(key, cfg)
             blk = cfg["block"] or _autotune.DEFAULT_BLOCK
             cand = {"mode": resolve_mode(cfg["mode"]), "levels": int(levels),
                     "variant": cfg["variant"], "bm": blk, "bk": blk,

@@ -45,13 +45,13 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..core.ata import ata, ata_levels_for
+from ..core.ata import ata, resolve_levels
 from ..obs import metrics as _metrics
 from ..obs import trace as _trace
 from ..core.distributed import (assemble_ring_gram, gram_bfs25d,
                                 gram_reducescatter, gram_ring,
                                 ring_stack_len)
-from ..core.strassen import AUTO_MAX_LEVELS, resolve_mode
+from ..core.strassen import resolve_mode
 from ..core.symmetry import pack_tril, tril_vector_from_blocks, unpack_tril
 
 __all__ = ["GramStream", "init", "update", "finalize",
@@ -97,14 +97,15 @@ def _updater(levels, leaf, variant, mode, block, interpret):
             # the packed kernel's own packed-cotangent VJP), jax.grad of
             # a streamed update stays dense-free too (DESIGN.md §11).
             from ..kernels.ops import ata_fused_packed
-            m, n = chunk.shape
-            lv = (min(ata_levels_for(m, n, leaf), AUTO_MAX_LEVELS)
-                  if levels == "auto" else levels)
+            lv = resolve_levels(levels, chunk.shape, chunk.dtype,
+                                mode="fused", variant=variant, block=block,
+                                out_dtype=packed.dtype, interpret=interpret)
             stack = ata_fused_packed(chunk, levels=lv, variant=variant,
                                      bk=block, bn=block,
                                      out_dtype=packed.dtype,
                                      interpret=interpret)
-            delta = tril_vector_from_blocks(stack, stack.shape[1], n)
+            delta = tril_vector_from_blocks(stack, stack.shape[1],
+                                            chunk.shape[1])
         else:
             delta = pack_tril(ata(chunk, levels=levels, leaf=leaf,
                                   variant=variant, mode=mode,
@@ -204,7 +205,7 @@ def stack_init(n: int, *, block: Optional[int] = None,
 
 
 def stack_update(state: GramStackStream, chunk: jax.Array, *,
-                 levels: Union[int, str] = 2, leaf: int = 256,
+                 levels: Union[int, str] = 2,
                  variant: str = "strassen", block: Optional[int] = None,
                  gram_of: str = "cols", beta=1.0, alpha=1.0,
                  interpret: Optional[bool] = None) -> GramStackStream:
@@ -223,7 +224,8 @@ def stack_update(state: GramStackStream, chunk: jax.Array, *,
 
     ``block`` is the *contraction* tile (the output tile edge is fixed
     by the stack).  ``levels`` clamps to depths the stack layout
-    divides, like the symm executor.
+    divides, like the symm executor; ``"auto"`` is the executor's
+    cheapest depth by its traffic model (``strassen_fused.auto_levels``).
     """
     if gram_of not in ("cols", "rows"):
         raise ValueError(f"gram_of must be 'cols' or 'rows', got {gram_of!r}")
@@ -232,11 +234,18 @@ def stack_update(state: GramStackStream, chunk: jax.Array, *,
         raise ValueError(
             f"chunk shape {chunk.shape} does not fit stream "
             f"n_padded={state.n_padded} ({gram_of} side)")
-    from ..kernels.ops import rank_k_update
+    from ..kernels.ops import _resolve_blocks, rank_k_update
     m, n = chunk.shape
-    lv = (min(ata_levels_for(m, n, leaf), AUTO_MAX_LEVELS)
-          if levels == "auto" else levels)
-    stack = rank_k_update(state.stack, chunk, levels=lv, variant=variant,
+    if levels == "auto":
+        from ..kernels.strassen_fused import auto_levels
+        block = _resolve_blocks("rank_k", m, n, chunk.dtype, bk=block)["bk"]
+        levels = auto_levels("rank_k", n if gram_of == "rows" else m,
+                             state.n_padded, gram_of=gram_of,
+                             variant=variant, bk=block, bn=state.block,
+                             operand_dtype=chunk.dtype,
+                             out_dtype=state.stack.dtype,
+                             interpret=interpret)
+    stack = rank_k_update(state.stack, chunk, levels=levels, variant=variant,
                           gram_of=gram_of, beta=beta, alpha=alpha,
                           bk=block, interpret=interpret)
     return GramStackStream(stack=stack,

@@ -74,7 +74,8 @@ from .syrk import _tri_decode
 __all__ = ["fused_ata", "fused_ata_packed", "fused_aat", "fused_aat_packed",
            "fused_matmul", "fused_symm_matmul", "fused_rank_k_update",
            "ata_traffic_model", "aat_traffic_model", "ata_bwd_traffic_model",
-           "rank_k_traffic_model", "stochastic_round_bf16"]
+           "rank_k_traffic_model", "matmul_traffic_model", "auto_levels",
+           "stochastic_round_bf16"]
 
 
 def _round_up(x: int, mult: int) -> int:
@@ -176,25 +177,32 @@ def _warn_fan_in_clamp(kind: str, variant: str, gram: str, requested: int,
         stacklevel=3)
 
 
-def _fan_in_clamp(kind: str, levels: int, variant: str,
+def _fan_in_depth(kind: str, levels: int, variant: str,
                   gram: str = "strassen") -> int:
-    """Clamp ``levels`` until the program's operand fan-in fits VMEM and
-    its lowered tables fit SMEM, warning once per distinct clamp (the
-    shape-driven clamp above this is expected behaviour and stays
-    silent).  ``rank_k`` shares the ``ata`` program, ``symm`` warns under
-    its own name as before."""
+    """The deepest program at most ``levels`` deep whose operand fan-in
+    fits VMEM and whose lowered tables fit SMEM.  ``rank_k`` shares the
+    ``ata`` program."""
     prog_kind = "ata" if kind == "rank_k" else kind
     g = gram if prog_kind in ("ata", "aat") else "strassen"
-    requested = levels
     while levels > 0:
         prog = compile_program(prog_kind, levels, variant, gram=g)
         if prog.max_terms <= MAX_OPERAND_TERMS \
                 and _table_bytes(prog) <= SMEM_TABLE_BYTES:
             break
         levels -= 1
-    if levels < requested:
-        _warn_fan_in_clamp(kind, variant, g, requested, levels)
     return levels
+
+
+def _fan_in_clamp(kind: str, levels: int, variant: str,
+                  gram: str = "strassen") -> int:
+    """:func:`_fan_in_depth`, warning once per distinct clamp (the
+    shape-driven clamp above this is expected behaviour and stays
+    silent).  ``symm`` warns under its own name as before."""
+    clamped = _fan_in_depth(kind, levels, variant, gram)
+    if clamped < levels:
+        g = gram if kind in ("ata", "aat", "rank_k") else "strassen"
+        _warn_fan_in_clamp(kind, variant, g, levels, clamped)
+    return clamped
 
 
 # ---------------------------------------------------------------------------
@@ -325,6 +333,29 @@ def _rank_k_geometry(m: int, T: int, levels: int, variant: str, bk: int,
     return {"plan": plan, "levels": levels, "M": B * mb, "mb": mb,
             "n_k": mb // bk, "nbt": T // B,
             "n_tri": T * (T + 1) // 2}
+
+
+def _matmul_geometry(m: int, k: int, n: int, levels: int, variant: str,
+                     bm: int, bk: int, bn: int, trans_a: bool = False,
+                     trans_b: bool = False):
+    """Geometry for ``op(A) (m, k) @ op(B) (k, n)``: a generic per-axis
+    level clamp (== strassen_levels_for at (2,2,2)) stops splitting once
+    the smallest leaf axis reaches tile size, then the fan-in clamp."""
+    dm, dk, dn = leaf_ir.algebra_dims(variant)
+    leaf, lv = max(bm, bk, bn), 0
+    cm, ck, cn = m, k, n
+    while min(cm, ck, cn) > leaf:
+        cm, ck, cn = cm // dm, ck // dk, cn // dn
+        lv += 1
+    levels = _fan_in_clamp("matmul", min(levels, lv), variant)
+    plan = compile_program("matmul", levels, variant,
+                           trans_a=trans_a, trans_b=trans_b)
+    Bm, Bk, Bn = plan.blocks_m, plan.blocks_k, plan.blocks_n
+    mb = _round_up(max(m, 1), Bm * bm) // Bm
+    kb = _round_up(max(k, 1), Bk * bk) // Bk
+    nb = _round_up(max(n, 1), Bn * bn) // Bn
+    return {"plan": plan, "levels": levels, "mb": mb, "kb": kb, "nb": nb,
+            "M": Bm * mb, "K": Bk * kb, "N": Bn * nb}
 
 
 # ---------------------------------------------------------------------------
@@ -1717,16 +1748,18 @@ def aat_traffic_model(
 
 def rank_k_traffic_model(
     m: int, n: int, *, levels: int = 2, variant: str = "strassen",
-    gram: str = "strassen",
+    gram: str = "strassen", gram_of: str = "cols",
     bk: int = 256, bn: int = 256, state_bytes: int = 4, in_bytes: int = 4,
 ) -> dict:
     """HBM bytes of one ``fused_rank_k_update`` chunk vs the status-quo
     streamed update it replaces (ata kernel + delta stack + gather-add:
     the delta stack is written and re-read, and the state is read and
-    rewritten)."""
+    rewritten).  ``m`` is the contraction length and ``n`` the Gram
+    side, on either ``gram_of`` side."""
     T = _round_up(max(n, 1), bn) // bn
     # the stack layout fixes T; mirror the executor's divisibility clamp
-    geo = _rank_k_geometry(m, T, levels, variant, bk, gram=gram)
+    geo = _rank_k_geometry(m, T, levels, variant, bk, gram=gram,
+                           gram_of=gram_of)
     M, N = geo["M"], T * bn
     spec = _bind(geo["plan"], n_out=geo["n_tri"], n_tj=0, q_i=geo["nbt"],
                  q_j=geo["nbt"], n_k=geo["n_k"], bi=bn, bj=bn, bc=bk)
@@ -1802,6 +1835,113 @@ def ata_bwd_traffic_model(
     return t
 
 
+def matmul_traffic_model(
+    m: int, k: int, n: int, *, levels: int = 2, variant: str = "strassen",
+    bm: int = 256, bk: int = 256, bn: int = 256, in_bytes: int = 4,
+    out_bytes: int = 4,
+) -> dict:
+    """HBM bytes of ``fused_matmul`` for ``op(A) (m, k) @ op(B) (k, n)``
+    (the transposes change index maps, not fetch counts)."""
+    geo = _matmul_geometry(m, k, n, levels, variant, bm, bk, bn)
+    M, K, N = geo["M"], geo["K"], geo["N"]
+    spec = _bind(geo["plan"], n_out=(M // bm) * (N // bn), n_tj=N // bn,
+                 q_i=geo["mb"] // bm, q_j=geo["nb"] // bn,
+                 n_k=geo["kb"] // bk, bi=bm, bj=bn, bc=bk)
+    t = _traffic(spec, left_bytes=in_bytes, right_bytes=in_bytes,
+                 out_bytes=out_bytes)
+    t["intermediate_bytes"] = ((M * K if (M, K) != (m, k) else 0)
+                               + (K * N if (K, N) != (k, n) else 0)
+                               ) * in_bytes
+    t["padded_shape"] = (M, K, N)
+    return t
+
+
+# Two depths whose scores lie within this share of each other tie, and a
+# tie goes to the shallower program: fewer roundings, smaller tables.
+AUTO_TIE_SHARE = 0.01
+
+
+def auto_levels(kind: str, m: int, n: int, *, k: Optional[int] = None,
+                gram_of: str = "cols", variant: str = "strassen",
+                gram: str = "strassen", bm: int = 256, bk: int = 256,
+                bn: int = 256, operand_dtype=jnp.float32,
+                out_dtype=jnp.float32, pipeline_depth=None,
+                interpret=None) -> int:
+    """The Strassen depth ``levels="auto"`` runs on a fused call: the
+    cheapest of depths 0 to ``AUTO_MAX_LEVELS`` by the executor's own
+    traffic model, scored with ``cost_model.pipelined_bytes_score`` at
+    the pipeline depth the call will run.  Depths whose scores lie within
+    ``AUTO_TIE_SHARE`` of the best tie, and the shallowest of them wins.
+    Depths past the VMEM fan-in or SMEM table limits are not tried.
+
+    The choice rests on the shape, the dtypes and the tiles alone.  The
+    executor recomputes a Strassen product once for each destination it
+    feeds, so on today's walk a deeper program reads more tile bytes
+    than it saves in products, and the rule picks classical blocking; a
+    schedule that makes Strassen cheaper in bytes is picked by the same
+    rule.  The depth that ran is recorded in the kernel's scope name
+    (``fused:<kind>:l<levels>:...``, in the HLO metadata and the device
+    trace), so no counter repeats it.
+
+    Shapes by ``kind``: ``"ata"`` and ``"aat"`` take A's (m, n);
+    ``"rank_k"`` the contraction length ``m`` and the stack's Gram span
+    ``n`` (``gram_of`` names the side); ``"matmul"`` ``op(A) (m, k) @
+    op(B) (k, n)``.  Tiles as the kind's traffic model names them.
+    ``out_dtype`` is the output's (for ``rank_k``, the stack's) dtype.
+    """
+    from ..core.strassen import AUTO_MAX_LEVELS
+    if kind not in ("ata", "aat", "rank_k", "matmul"):
+        raise ValueError(f"no auto depth for kind {kind!r}")
+    if kind == "matmul" and k is None:
+        raise ValueError("matmul needs the contraction length k")
+    depth = _resolve_pipeline_depth(
+        pipeline_depth, _auto_interpret(interpret, site="auto_levels"))
+    tiles = {"ata": (bk, bn), "aat": (bm, bk), "rank_k": (bk, bn),
+             "matmul": (bm, bk, bn)}[kind]
+    return _auto_levels(kind, m, n, k, gram_of, variant, gram, tiles,
+                        jnp.dtype(operand_dtype).itemsize,
+                        jnp.dtype(out_dtype).itemsize, depth,
+                        AUTO_MAX_LEVELS)
+
+
+@functools.lru_cache(maxsize=None)
+def _auto_levels(kind, m, n, k, gram_of, variant, gram, tiles, in_bytes,
+                 out_bytes, depth, max_levels) -> int:
+    from ..core.cost_model import pipelined_bytes_score
+
+    def model(levels):
+        if kind == "ata":
+            return ata_traffic_model(m, n, levels=levels, variant=variant,
+                                     gram=gram, bk=tiles[0], bn=tiles[1],
+                                     in_bytes=in_bytes, out_bytes=out_bytes)
+        if kind == "aat":
+            return aat_traffic_model(m, n, levels=levels, variant=variant,
+                                     gram=gram, bm=tiles[0], bk=tiles[1],
+                                     in_bytes=in_bytes, out_bytes=out_bytes)
+        if kind == "rank_k":
+            return rank_k_traffic_model(m, n, levels=levels,
+                                        variant=variant, gram=gram,
+                                        gram_of=gram_of, bk=tiles[0],
+                                        bn=tiles[1], in_bytes=in_bytes,
+                                        state_bytes=out_bytes)
+        return matmul_traffic_model(m, k, n, levels=levels, variant=variant,
+                                    bm=tiles[0], bk=tiles[1], bn=tiles[2],
+                                    in_bytes=in_bytes, out_bytes=out_bytes)
+
+    scores = []
+    for levels in range(_fan_in_depth(kind, max_levels, variant, gram) + 1):
+        t = model(levels)
+        scores.append(pipelined_bytes_score(
+            t["read_bytes"] + t["intermediate_bytes"], t["write_bytes"],
+            t["flops"], pipeline_depth=depth, grid_steps=t["grid_steps"]))
+    best = min(scores)
+    return next(lv for lv, sc in enumerate(scores)
+                if sc <= best * (1 + AUTO_TIE_SHARE))
+
+
+leaf_ir.on_algebra_change(_auto_levels.cache_clear)
+
+
 # ---------------------------------------------------------------------------
 # Fused Strassen matmul: C = op(A) @ op(B), dense output.
 # ---------------------------------------------------------------------------
@@ -1868,23 +2008,10 @@ def _fused_matmul_exec(a, b, levels, variant, bm, bk, bn, out_dtype,
     """Executor binding for C = op(a) @ op(b)."""
     m, k_dim = a.shape[::-1] if trans_a else a.shape
     n, _ = b.shape if trans_b else b.shape[::-1]
-    # generic per-axis level clamp (== strassen_levels_for at (2,2,2)):
-    # stop splitting once the smallest leaf axis reaches tile size
-    dm, dk, dn = leaf_ir.algebra_dims(variant)
-    leaf, lv = max(bm, bk, bn), 0
-    cm, ck, cn = m, k_dim, n
-    while min(cm, ck, cn) > leaf:
-        cm, ck, cn = cm // dm, ck // dk, cn // dn
-        lv += 1
-    levels = min(levels, lv)
-    levels = _fan_in_clamp("matmul", levels, variant)
-    plan = compile_program("matmul", levels, variant,
+    geo = _matmul_geometry(m, k_dim, n, levels, variant, bm, bk, bn,
                            trans_a=trans_a, trans_b=trans_b)
-    Bm, Bk, Bn = plan.blocks_m, plan.blocks_k, plan.blocks_n
-    mb = _round_up(max(m, 1), Bm * bm) // Bm
-    kb = _round_up(max(k_dim, 1), Bk * bk) // Bk
-    nb = _round_up(max(n, 1), Bn * bn) // Bn
-    M, K, N = Bm * mb, Bk * kb, Bn * nb
+    plan, mb, kb, nb = geo["plan"], geo["mb"], geo["kb"], geo["nb"]
+    M, K, N = geo["M"], geo["K"], geo["N"]
     a_shape = (K, M) if trans_a else (M, K)
     b_shape = (N, K) if trans_b else (K, N)
     if a.shape != a_shape:

@@ -14,9 +14,9 @@ each update is ONE accumulating kernel (``gram.stream.stack_update``:
 ``beta2`` at run time, seeds the kernel's accumulator and the result is
 written over it.  G is never transposed and no dense statistic exists;
 :func:`update_stats` runs the two updates of every block in one jitted,
-donated call.  The kernel runs the Strassen recursion to a capped depth
-(``ata_levels``; classical leaf products below it, so 0.852x the
-classical multiplications at levels 3), fused on TPU; ``ata_mode=
+donated call.  The kernel runs the Strassen recursion to ``ata_levels``
+(``"auto"``: the executor's cheapest depth by its traffic model, which
+is classical blocking on today's walk), fused on TPU; ``ata_mode=
 "reference"`` (the default off-TPU) runs the XLA reference recursion
 into the same stacks instead.
 
@@ -130,7 +130,7 @@ def _fold(state, g, gram_of, beta, alpha, *, levels, leaf, variant, mode,
           block, interpret):
     """``state <- beta state + alpha tril(Gram)`` of one gradient block."""
     if resolve_mode(mode) == "fused":
-        return stream.stack_update(state, g, levels=levels, leaf=leaf,
+        return stream.stack_update(state, g, levels=levels,
                                    variant=variant, block=block,
                                    gram_of=gram_of, beta=beta, alpha=alpha,
                                    interpret=interpret)

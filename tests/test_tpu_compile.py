@@ -60,15 +60,26 @@ def _f32(sharding, *shape):
 
 @pytest.mark.parametrize("n,depth", [(5000, 1), (5000, 2), (10000, None)])
 def test_ata_full_auto_compiles(one_chip, n, depth):
-    """The README quickstart at the paper's sizes: levels="auto" is 3, and
-    the default depth of a compiled kernel is 2."""
-    text = _compile(lambda a: ata_full(a, levels="auto", mode="fused",
+    """The README quickstart at the paper's sizes, at the deepest Strassen
+    program ``levels="auto"`` may pick (3), and the default depth of a
+    compiled kernel is 2."""
+    text = _compile(lambda a: ata_full(a, levels=3, mode="fused",
                                        interpret=False,
                                        pipeline_depth=depth),
                     _f32(one_chip, n, n))
     scope = "fused:ata:l3:strassen:strassen"
     assert scope in text
     assert (scope + ":pd2" in text) == (depth != 1)
+
+
+@pytest.mark.parametrize("n", [5000, 10000])
+def test_ata_full_auto_picks_classical_blocking(one_chip, n):
+    """``levels="auto"`` at the paper's sizes: the executor's traffic
+    model picks depth 0, which compiles at the default pipeline depth."""
+    text = _compile(lambda a: ata_full(a, levels="auto", mode="fused",
+                                       interpret=False),
+                    _f32(one_chip, n, n))
+    assert "fused:ata:l0:strassen:strassen:pd2" in text
 
 
 def test_aat_levels3_compiles(one_chip):
@@ -130,8 +141,18 @@ def test_ata_dps_levels2_compiles(one_chip):
 
 
 def test_ata_fp8_operand_tiles_compile(one_chip):
-    text = _compile(lambda a: ata(a, levels="auto", mode="fused",
+    text = _compile(lambda a: ata(a, levels=3, mode="fused",
                                   interpret=False,
                                   operand_dtype=jnp.float8_e4m3fn),
                     _f32(one_chip, 5000, 5000))
     assert "fused:ata:l3:" in text
+
+
+def test_ata_fp8_operand_tiles_auto_compile(one_chip):
+    """fp8 operand tiles quarter the bytes of every depth alike, so
+    ``"auto"`` still picks classical blocking."""
+    text = _compile(lambda a: ata(a, levels="auto", mode="fused",
+                                  interpret=False,
+                                  operand_dtype=jnp.float8_e4m3fn),
+                    _f32(one_chip, 5000, 5000))
+    assert "fused:ata:l0:" in text
