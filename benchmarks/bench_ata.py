@@ -23,7 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import ata
-from repro.core.cost_model import ir_leaf_count, pipelined_bytes_score
+from repro.core.cost_model import ir_leaf_count
 from repro.core.leaf_ir import compile_program
 from repro.gram.verify import default_rtol, freivalds_gram
 from repro.kernels.strassen_fused import (aat_traffic_model,
@@ -41,7 +41,7 @@ LEVELS = 2
 # acceptance key (ISSUE 10).  dot/reference treatments compile natively
 # on every backend.
 _PALLAS_TREATMENTS = frozenset((
-    "fused", "fused_pd1", "fused_pd2", "fused_fp8", "aat_fused",
+    "fused", "fused_fp8", "aat_fused",
     "rank_k_fused", "rank_k_delta"))
 
 
@@ -82,12 +82,6 @@ def run(quick: bool = False):
                                    mode="reference"),
         "fused": lambda x: ops.ata_fused_packed(x, levels=LEVELS, bk=block,
                                                 bn=block),
-        # the pipelined hot loop (ISSUE 10): depth=1 is the unpipelined
-        # schedule, depth=2 double-buffers the tile DMAs; bit-exact pair
-        "fused_pd1": lambda x: ops.ata_fused_packed(
-            x, levels=LEVELS, bk=block, bn=block, pipeline_depth=1),
-        "fused_pd2": lambda x: ops.ata_fused_packed(
-            x, levels=LEVELS, bk=block, bn=block, pipeline_depth=2),
         # fp8 operand tiles, fp32 accumulation — halves(+) the DMA read
         # term; parity is gated by the Freivalds probe below, not here
         "fused_fp8": lambda x: ops.ata_fused_packed(
@@ -132,8 +126,7 @@ def run(quick: bool = False):
             "census_total_bytes": census["total_bytes"],
             "census_by_opcode": census["by_opcode"],
         }
-        if name in ("fused", "fused_pd1", "fused_pd2", "fused_fp8",
-                    "aat_fused", "rank_k_fused"):
+        if name in ("fused", "fused_fp8", "aat_fused", "rank_k_fused"):
             if name.startswith("fused"):
                 in_b = 1 if name == "fused_fp8" else 4
                 model = ata_traffic_model(n, n, levels=LEVELS, bk=block,
@@ -221,32 +214,6 @@ def run(quick: bool = False):
     print(f"[ata] dps leaf count below strassen-gram at levels={LEVELS}: "
           f"{dps_below}")
 
-    # -- pipelining acceptance (ISSUE 10) --------------------------------
-    # On TPU the pd1/pd2 rows are real compiled wall clocks and the gate
-    # is wall-based: depth-2 must be no worse than 1.05x depth-1.  Off-TPU
-    # the rows are interpret-mode emulation — the emulator serializes the
-    # DMA bookkeeping the real pipeline overlaps, so an emulated wall gate
-    # would always fail for the wrong reason.  There the gate falls back
-    # to the roofline model (pipelined_bytes_score) on the same traffic,
-    # and pipeline_acceptance_basis records which basis produced the bit.
-    pd1, pd2 = by["fused_pd1"], by["fused_pd2"]
-    if not pd1["interpret"] and not pd2["interpret"]:
-        basis = "compiled_wall"
-        pipe_ok = pd2["wall_s"] <= 1.05 * pd1["wall_s"]
-    else:
-        basis = "model_score"
-        s1 = pipelined_bytes_score(
-            pd1["hbm_read_bytes"], pd1["hbm_write_bytes"],
-            pd1["model_flops"], pipeline_depth=1,
-            grid_steps=pd1["model_grid_steps"])
-        s2 = pipelined_bytes_score(
-            pd2["hbm_read_bytes"], pd2["hbm_write_bytes"],
-            pd2["model_flops"], pipeline_depth=2,
-            grid_steps=pd2["model_grid_steps"])
-        pipe_ok = s2 <= 1.05 * s1
-    print(f"[ata] pipeline acceptance ({basis}): depth-2 no worse than "
-          f"1.05x depth-1: {pipe_ok}")
-
     # fp8 operand serve parity: the quantized Gram must still satisfy the
     # Freivalds identity at the precision-scaled tolerance — this is the
     # end-to-end check that quantize-after-pad + fp32 accumulation did
@@ -290,8 +257,6 @@ def run(quick: bool = False):
                                          for r in variant_rows),
         "backend": backend,
         "compiled_wall_rows": compiled_wall_rows,
-        "pipeline_acceptance_basis": basis,
-        "acceptance_pipeline_no_worse": bool(pipe_ok),
         "fp8_freivalds_rel_err": fp8_err,
         "fp8_freivalds_rtol": default_rtol("float8_e4m3fn"),
         "acceptance_fp8_freivalds": bool(fp8_ok),

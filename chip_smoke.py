@@ -55,7 +55,7 @@ STREAM_ROWS, STREAM_N, STREAM_CHUNKS = 16384, 4096, 4
 SHAMPOO_BLOCKS = [(8192, 4096), (4096, 8192)]
 DIST_SHAPE = (32768, 8192)
 
-_KERNEL_SCOPE = re.compile(r"fused:(\w+):l(\d+):(\w+):(\w+)(?::pd(\d+))?")
+_KERNEL_SCOPE = re.compile(r"fused:(\w+):l(\d+):(\w+):(\w+):pd(\d+)")
 _cache_events = {"hits": 0, "misses": 0}
 
 
@@ -78,7 +78,7 @@ def kernels_of(compiled, label):
     if "tpu_custom_call" not in text:
         raise AssertionError(f"{label}: no tpu_custom_call in the compiled "
                              "program (the kernel did not run compiled)")
-    return sorted({f"{k}:l{lv}:pd{pd or 1}" for k, lv, _, _, pd
+    return sorted({f"{k}:l{lv}:pd{pd}" for k, lv, _, _, pd
                    in _KERNEL_SCOPE.findall(text)})
 
 

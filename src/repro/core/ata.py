@@ -63,9 +63,7 @@ def ata(
     out_dtype=None,
     block: Optional[int] = None,
     interpret: Optional[bool] = None,
-    pipeline_depth: Optional[int] = None,
     operand_dtype=None,
-    acc_dtype=None,
     sr_seed: Optional[int] = None,
 ) -> jax.Array:
     """Lower triangle of ``a.T @ a`` via the paper's ATA recursion.
@@ -118,14 +116,9 @@ def ata(
         (256 when untuned).
       interpret: Pallas interpret-mode override for the fused path
         (default: interpret off-TPU).
-      pipeline_depth: revolving-buffer DMA pipeline depth for the fused
-        path (DESIGN.md §16).  ``None`` = backend default (2 compiled,
-        1 interpret); 1 reproduces the unpipelined grid walk bit-exactly.
       operand_dtype: quantize operand tiles to this dtype (fp8 e4m3/e5m2,
         bf16, ...) before the kernel; accumulation stays >=fp32.  Fused
         path only; ``None`` keeps the native operand dtype.
-      acc_dtype: VMEM accumulator storage dtype on the fused path
-        (default fp32).
       sr_seed: when set (with bf16 ``out_dtype``), apply deterministic
         stochastic rounding to the fused Gram output under this seed.
 
@@ -143,9 +136,7 @@ def ata(
     levels = resolve_levels(levels, a.shape, a.dtype, mode=mode,
                             gram_of=gram_of, leaf=leaf, variant=variant,
                             gram=gram, block=block, out_dtype=out_dtype,
-                            operand_dtype=operand_dtype,
-                            pipeline_depth=pipeline_depth,
-                            interpret=interpret)
+                            operand_dtype=operand_dtype)
     if mode != "fused" and operand_dtype is not None:
         # Reference oracle for quantized operands: quantize once, then
         # recurse in the promoted compute dtype (the fused kernel upcasts
@@ -158,9 +149,7 @@ def ata(
             return aat_fused(a, levels=levels, variant=variant, gram=gram,
                              bm=block, bk=block, out_dtype=out_dtype,
                              interpret=interpret,
-                             pipeline_depth=pipeline_depth,
-                             operand_dtype=operand_dtype,
-                             acc_dtype=acc_dtype, sr_seed=sr_seed)
+                             operand_dtype=operand_dtype, sr_seed=sr_seed)
         # reference oracle: AAT(A) = ATA(A^t) — the 2021 paper's identity
         syrk = base_syrk or _default_base_syrk
         out = _ata_rec(a.T, levels, leaf, variant, syrk, base_matmul)
@@ -170,9 +159,7 @@ def ata(
         return ata_fused(a, levels=levels, variant=variant, gram=gram,
                          bk=block, bn=block, out_dtype=out_dtype,
                          interpret=interpret, bwd=bwd,
-                         pipeline_depth=pipeline_depth,
-                         operand_dtype=operand_dtype, acc_dtype=acc_dtype,
-                         sr_seed=sr_seed)
+                         operand_dtype=operand_dtype, sr_seed=sr_seed)
     syrk = base_syrk or _default_base_syrk
     out = _ata_rec(a, levels, leaf, variant, syrk, base_matmul)
     return out.astype(out_dtype)
@@ -228,15 +215,14 @@ def resolve_levels(levels: Union[int, str], shape, dtype, *, mode: str,
                    gram_of: str = "cols", leaf: int = DEFAULT_LEAF,
                    variant: str = "strassen", gram: str = "strassen",
                    block: Optional[int] = None, out_dtype=None,
-                   operand_dtype=None, pipeline_depth: Optional[int] = None,
-                   interpret: Optional[bool] = None) -> int:
+                   operand_dtype=None) -> int:
     """The depth :func:`ata` runs for ``levels`` on an A of ``shape`` and
     ``dtype`` under a resolved ``mode``.  An integer is returned as it
     is.  ``"auto"`` on the fused path is the executor's cheapest depth by
-    its traffic model (``strassen_fused.auto_levels``), at the tiles,
-    dtypes and pipeline depth the call will run; in reference mode it is
-    the recursion's natural depth (:func:`ata_levels_for` at ``leaf``,
-    capped at ``AUTO_MAX_LEVELS``)."""
+    its traffic model (``strassen_fused.auto_levels``), at the tiles and
+    dtypes the call will run; in reference mode it is the recursion's
+    natural depth (:func:`ata_levels_for` at ``leaf``, capped at
+    ``AUTO_MAX_LEVELS``)."""
     if levels != "auto":
         return levels
     m, n = shape
@@ -254,8 +240,7 @@ def resolve_levels(levels: Union[int, str], shape, dtype, *, mode: str,
         kind, m, n, variant=variant, gram=gram, **tiles,
         operand_dtype=dtype if operand_dtype is None else operand_dtype,
         out_dtype=(jnp.promote_types(dtype, jnp.float32)
-                   if out_dtype is None else out_dtype),
-        pipeline_depth=pipeline_depth, interpret=interpret)
+                   if out_dtype is None else out_dtype))
 
 
 def ata_levels_for(m: int, n: int, leaf: int = DEFAULT_LEAF) -> int:

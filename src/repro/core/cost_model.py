@@ -526,26 +526,20 @@ ICI_LATENCY = 1e-6                # per collective round (s), order of mag
 
 
 def pipelined_bytes_score(read_bytes: float, write_bytes: float,
-                          flops: float, *, pipeline_depth: int = 1,
-                          grid_steps: int = 1,
+                          flops: float, *, grid_steps: int = 1,
                           flop_rate: float = V5E.peak_flops,
                           hbm_bw: float = V5E.hbm_bw) -> float:
     """Roofline score (HBM-byte-equivalents) of a bound leaf program under
-    DMA pipelining (DESIGN.md §16).
+    the executor's double-buffered DMA walk (DESIGN.md §16).
 
-    Unpipelined (depth <= 1), each grid step serializes its operand DMA
-    against its MXU work, so the cost is the SUM of the memory and
-    compute terms.  With revolving buffers (depth >= 2) the next step's
-    copies stream while the current step computes, so steady state pays
-    the MAX of the two, plus one non-overlapped pipeline fill amortized
-    over ``grid_steps``.  Compute is expressed in byte-equivalents
-    (``flops * hbm_bw / flop_rate``) so the score stays comparable with
-    the raw ``read_bytes + write_bytes`` ranking autotune used before
-    this term existed."""
+    The next step's copies stream while the current step computes, so
+    steady state pays the MAX of the memory and compute terms, plus one
+    non-overlapped pipeline fill amortized over ``grid_steps``.  Compute
+    is expressed in byte-equivalents (``flops * hbm_bw / flop_rate``) so
+    the score stays comparable with the raw ``read_bytes + write_bytes``
+    ranking autotune used before this term existed."""
     mem = float(read_bytes) + float(write_bytes)
     cmp_eq = float(flops) * hbm_bw / flop_rate
-    if pipeline_depth <= 1:
-        return mem + cmp_eq
     fill = min(mem, cmp_eq) / max(int(grid_steps), 1)
     return max(mem, cmp_eq) + fill
 

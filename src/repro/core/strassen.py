@@ -162,7 +162,7 @@ def strassen_matmul(
             **_resolve_blocks("matmul", m, n, a.dtype, bm=block, bk=block,
                               bn=block),
             operand_dtype=jnp.promote_types(a.dtype, b.dtype),
-            out_dtype=out_dtype, interpret=interpret)
+            out_dtype=out_dtype)
     elif levels == "auto":
         levels = min(strassen_levels_for(m, k_a, n, leaf), AUTO_MAX_LEVELS)
     if mode == "fused":

@@ -138,9 +138,9 @@ def _variant_axis(kind: str) -> list:
 def candidate_space(M: int, N: int, *, backend: Optional[str] = None,
                     blocks=(128, 256, 512), levels=(0, 1, 2),
                     modes=("fused", "reference"), kind: str = "ata",
-                    pipeline_depths=(1, 2), operand_dtypes=(None,)):
-    """Enumerate (mode, levels, variant, gram, bm/bk/bn, pipeline_depth,
-    operand_dtype) candidates for an (M, N) bucket.
+                    operand_dtypes=(None,)):
+    """Enumerate (mode, levels, variant, gram, bm/bk/bn, operand_dtype)
+    candidates for an (M, N) bucket.
 
     The variant/gram axes come from the live leaf-IR registries
     (``_variant_axis``), so registering a new algebra automatically puts
@@ -150,9 +150,9 @@ def candidate_space(M: int, N: int, *, backend: Optional[str] = None,
     the smallest candidate).  The grid only varies the knobs ``kind``
     actually uses — ``aat`` ties bm=bk and ignores bn, and at levels=0
     every (variant, gram) compiles the identical classical program, so
-    only one candidate is emitted there.  ``pipeline_depths`` /
-    ``operand_dtypes`` (DESIGN.md §16) are fused-kernel knobs: the
-    reference recursion pins depth 1 / native operands.
+    only one candidate is emitted there.  ``operand_dtypes`` (DESIGN.md
+    §16) is a fused-kernel knob: the reference recursion pins native
+    operands.
     """
     usable = [b for b in blocks if b <= max(M, N)] or [min(blocks)]
     axis = _variant_axis(kind)
@@ -160,28 +160,25 @@ def candidate_space(M: int, N: int, *, backend: Optional[str] = None,
     for mode in modes:
         for lv in levels:
             if mode == "reference":
-                # blocking/pipelining are fused-kernel knobs; the
-                # reference recursion leaves tiling to XLA — one
-                # candidate per level.
+                # blocking is a fused-kernel knob; the reference
+                # recursion leaves tiling to XLA — one candidate per
+                # level.
                 out.append({"mode": "reference", "levels": lv,
                             "variant": "strassen", "gram": "strassen",
                             "bm": min(usable), "bk": min(usable),
-                            "bn": min(usable), "pipeline_depth": 1,
-                            "operand_dtype": None})
+                            "bn": min(usable), "operand_dtype": None})
                 continue
             pairs = axis if lv > 0 else [("strassen", "strassen")]
             for variant, gram in pairs:
                 for bk in usable:
                     bns = [bk] if kind == "aat" else usable
                     for bn in bns:
-                        for pd in pipeline_depths:
-                            for od in operand_dtypes:
-                                out.append({
-                                    "mode": "fused", "levels": lv,
-                                    "variant": variant, "gram": gram,
-                                    "bm": bk, "bk": bk, "bn": bn,
-                                    "pipeline_depth": int(pd),
-                                    "operand_dtype": od})
+                        for od in operand_dtypes:
+                            out.append({
+                                "mode": "fused", "levels": lv,
+                                "variant": variant, "gram": gram,
+                                "bm": bk, "bk": bk, "bn": bn,
+                                "operand_dtype": od})
     return dedupe_candidates(out, kind=kind)
 
 
@@ -193,7 +190,7 @@ def dedupe_candidates(cands, kind: str = "ata"):
     that filled the measured top-K with re-timings of one config),
     levels=0 compiles the same classical program for every (variant,
     gram) pair, and reference candidates ignore blocking and the fused
-    perf knobs entirely.  Keyed on the knobs ``kind`` actually uses,
+    precision knob entirely.  Keyed on the knobs ``kind`` actually uses,
     first occurrence wins (order — and therefore model ranking — is
     preserved)."""
     seen, out = set(), []
@@ -206,9 +203,7 @@ def dedupe_candidates(cands, kind: str = "ata"):
         else:
             blocks = ((c["bm"], c["bk"]) if kind == "aat"
                       else (c["bk"], c["bn"]))
-            sig = ("fused", lv, alg, blocks,
-                   int(c.get("pipeline_depth") or 1),
-                   c.get("operand_dtype"))
+            sig = ("fused", lv, alg, blocks, c.get("operand_dtype"))
         if sig in seen:
             continue
         seen.add(sig)
@@ -219,31 +214,26 @@ def dedupe_candidates(cands, kind: str = "ata"):
 def _pipelined_side_score(side: dict, cand: dict, in_bytes: int) -> float:
     """Score one traffic-model side dict for a candidate.
 
-    Legacy byte-sum (read + write + intermediate) unless the candidate
-    carries the §16 perf knobs AND they deviate from the unpipelined
-    native-operand baseline — entries tuned before those axes existed
-    (and the depth-1/native candidates) keep their historical scores
-    bit-for-bit.  ``operand_dtype`` rescales operand read traffic by the
-    quantized itemsize; ``pipeline_depth`` >= 2 swaps the byte sum for
-    ``cost_model.pipelined_bytes_score`` (max(mem, compute) + amortized
-    fill instead of their sum)."""
+    Legacy byte-sum (read + write + intermediate) for a candidate that
+    carries no ``operand_dtype`` — entries tuned before that axis
+    existed keep their historical scores bit-for-bit.  Otherwise
+    ``operand_dtype`` rescales operand read traffic by the quantized
+    itemsize and the score is ``cost_model.pipelined_bytes_score``
+    (max(mem, compute) + amortized fill), what the kernel's
+    double-buffered walk pays.  The buffer-depth key older versions
+    persisted is ignored."""
     reads = float(side["read_bytes"])
     writes = float(side["write_bytes"])
     inter = float(side.get("intermediate_bytes", 0))
-    if "pipeline_depth" not in cand and "operand_dtype" not in cand:
+    if "operand_dtype" not in cand:
         return reads + writes + inter           # legacy candidate
-    od = cand.get("operand_dtype")
-    pd = int(cand.get("pipeline_depth") or 1)
+    od = cand["operand_dtype"]
     if od is not None:
         reads *= jnp.dtype(od).itemsize / float(in_bytes)
     from ..core.cost_model import pipelined_bytes_score
-    # Depth 1 and depth >= 2 are both scored on the roofline (sum vs
-    # max + fill) so the depth axis is an apples-to-apples contest; the
-    # legacy byte-sum above has no compute term and would misrank
-    # compute-bound shapes against pipelined candidates.
     return pipelined_bytes_score(
         reads + inter, writes, float(side.get("flops", 0)),
-        pipeline_depth=pd, grid_steps=int(side.get("grid_steps", 1)))
+        grid_steps=int(side.get("grid_steps", 1)))
 
 
 def model_score(m: int, n: int, cand: dict, *, in_bytes: int = 4,
@@ -262,10 +252,9 @@ def model_score(m: int, n: int, cand: dict, *, in_bytes: int = 4,
     ranks fused candidates only — reference candidates compete through
     ``measure=True`` wall clock (see :func:`autotune`).
 
-    Candidates carrying the §16 perf knobs (``pipeline_depth`` >= 2 or a
-    quantized ``operand_dtype``) are scored with the pipelined roofline
-    term (``_pipelined_side_score``); legacy candidates keep the
-    historical byte sum.
+    Candidates carrying the §16 ``operand_dtype`` knob are scored with
+    the pipelined roofline term (``_pipelined_side_score``); legacy
+    candidates keep the historical byte sum.
     """
     if kind == "ata_bwd":
         from ..kernels.strassen_fused import ata_bwd_traffic_model
@@ -458,10 +447,8 @@ def _build_runner(M: int, N: int, dtype, cand: dict, interpret,
     from ..core.ata import ata
 
     galg = cand.get("gram", "strassen")
-    # §16 perf knobs — fused-kernel only; the reference recursion takes
-    # pipeline_depth=None (a no-op there) and quantizes via ata()'s
+    # §16 precision knob — the reference recursion quantizes via ata()'s
     # operand_dtype oracle path.
-    pdepth = cand.get("pipeline_depth")
     odtype = cand.get("operand_dtype")
     if kind == "aat":
         def fn(a):
@@ -469,7 +456,7 @@ def _build_runner(M: int, N: int, dtype, cand: dict, interpret,
                        variant=cand["variant"], gram=galg,
                        mode=cand["mode"], block=cand["bk"],
                        out_dtype=jnp.float32, interpret=interpret,
-                       pipeline_depth=pdepth, operand_dtype=odtype)
+                       operand_dtype=odtype)
         return jax.jit(fn)
 
     if kind == "rank_k":
@@ -485,8 +472,7 @@ def _build_runner(M: int, N: int, dtype, cand: dict, interpret,
                 return rank_k_update(stack, a, levels=cand["levels"],
                                      variant=cand["variant"], gram=galg,
                                      bk=cand["bk"], interpret=interpret,
-                                     donate=False, pipeline_depth=pdepth,
-                                     operand_dtype=odtype)
+                                     donate=False, operand_dtype=odtype)
             return jax.jit(fn)
 
         from . import stream as _stream
@@ -507,15 +493,14 @@ def _build_runner(M: int, N: int, dtype, cand: dict, interpret,
             return jax.grad(lambda x: ata(
                 x, levels=cand["levels"], variant=cand["variant"],
                 gram=galg, mode="fused", bwd=bwd, block=cand["bk"],
-                out_dtype=jnp.float32, interpret=interpret,
-                pipeline_depth=pdepth).sum())(a)
+                out_dtype=jnp.float32, interpret=interpret).sum())(a)
         return jax.jit(fn)
 
     def fn(a):
         return ata(a, levels=cand["levels"], variant=cand["variant"],
                    gram=galg, mode=cand["mode"], block=cand["bk"],
                    out_dtype=jnp.float32, interpret=interpret,
-                   pipeline_depth=pdepth, operand_dtype=odtype)
+                   operand_dtype=odtype)
     return jax.jit(fn)
 
 
@@ -533,7 +518,7 @@ def autotune(m: int, n: int, *, dtype: str = "float32", kind: str = "ata",
              backend: Optional[str] = None, measure: bool = False,
              top_k: int = 3, blocks=(128, 256, 512), levels=(0, 1, 2),
              modes=("fused", "reference"), min_side: int = 32,
-             pipeline_depths=(1, 2), operand_dtypes=(None,),
+             operand_dtypes=(None,),
              cache_path: Optional[os.PathLike] = None,
              interpret: Optional[bool] = None,
              refresh: bool = False) -> dict:
@@ -563,7 +548,6 @@ def autotune(m: int, n: int, *, dtype: str = "float32", kind: str = "ata",
     in_bytes = jnp.dtype(dtype).itemsize
     cands = candidate_space(M, N, backend=backend, blocks=blocks,
                             levels=levels, modes=modes, kind=kind,
-                            pipeline_depths=pipeline_depths,
                             operand_dtypes=operand_dtypes)
     score = lambda c: model_score(M, N, c, in_bytes=in_bytes,  # noqa: E731
                                   kind=kind)

@@ -371,7 +371,6 @@ class GramEngine:
                  tenant_quota: Optional[int] = None,
                  tenant_max_inflight: Optional[int] = None,
                  ring_depth: Optional[int] = None,
-                 pipeline_depth: Optional[int] = None,
                  operand_dtype=None):
         self.slots = slots
         self.levels, self.leaf, self.variant = levels, leaf, variant
@@ -380,13 +379,10 @@ class GramEngine:
         self.min_bucket = min_bucket
         self.use_autotune_cache = use_autotune_cache
         self.interpret = interpret
-        # §16 perf/precision knobs: pipeline_depth None defers to the
-        # measured autotune winner (then the kernel's backend default);
-        # operand_dtype quantizes every served operand tile (fp8/bf16,
-        # fp32 accumulation) and becomes part of the bucket key so
-        # quantized and native traffic never share an executable,
-        # a guard tolerance, or a drift history.
-        self.pipeline_depth = pipeline_depth
+        # §16 precision knob: operand_dtype quantizes every served
+        # operand tile (fp8/bf16, fp32 accumulation) and becomes part of
+        # the bucket key so quantized and native traffic never share an
+        # executable, a guard tolerance, or a drift history.
         self.operand_dtype = (None if operand_dtype is None
                               else jnp.dtype(operand_dtype).name)
         # distributed routing: buckets of >= dist_threshold elements go to
@@ -866,18 +862,14 @@ class GramEngine:
         winner (quarantine), 2 forces the XLA reference recursion, 3 adds
         ``levels=0`` (classical — no fast-variant arithmetic at all).
 
-        The §16 perf knobs ride the same policy: ``pipeline_depth`` is
-        adopted only from *measured* fused winners (it is a wall-clock
-        claim — a model-only entry must not pick the pipelined kernel on
-        a backend where it was never timed), and ``operand_dtype`` is
-        never adopted from the cache at all — quantization changes the
-        served numerics, so it flows exclusively from the caller (engine
-        kwarg / per-request override) via the bucket key.
+        The §16 ``operand_dtype`` knob is never adopted from the cache
+        at all — quantization changes the served numerics, so it flows
+        exclusively from the caller (engine kwarg / per-request
+        override) via the bucket key.
         """
         M, N, dtype, gram_of = key[:4]
         cfg = {"mode": self.mode, "levels": self.levels, "leaf": self.leaf,
                "variant": self.variant, "block": self.block,
-               "pipeline_depth": self.pipeline_depth,
                "operand_dtype": self._bucket_operand(key)}
         if self.use_autotune_cache and rung == 0:
             try:
@@ -893,9 +885,6 @@ class GramEngine:
                         cfg["mode"] = hit["mode"]
                     if cfg["levels"] == "auto":
                         cfg["levels"] = hit["levels"]
-                    if cfg["pipeline_depth"] is None \
-                            and hit.get("mode") == "fused":
-                        cfg["pipeline_depth"] = hit.get("pipeline_depth")
                 if cfg["block"] is None and hit.get("mode") == "fused":
                     cfg["block"] = hit.get("bk")
         if rung >= 2:
@@ -1171,8 +1160,7 @@ class GramEngine:
     @staticmethod
     def _cfg_fingerprint(cfg) -> tuple:
         return (cfg["mode"], str(cfg["levels"]), cfg["leaf"],
-                cfg["variant"], cfg["block"],
-                cfg.get("pipeline_depth"), cfg.get("operand_dtype"))
+                cfg["variant"], cfg["block"], cfg.get("operand_dtype"))
 
     def _local_executable(self, key, cfg):
         M, N, dtype, gram_of = key[:4]
@@ -1189,7 +1177,6 @@ class GramEngine:
                        leaf=cfg["leaf"], variant=cfg["variant"],
                        mode=cfg["mode"], out_dtype=self.out_dtype,
                        block=cfg["block"], interpret=self.interpret,
-                       pipeline_depth=cfg.get("pipeline_depth"),
                        operand_dtype=cfg.get("operand_dtype"))
         spec = jax.ShapeDtypeStruct((self.slots, M, N), jnp.dtype(dtype))
         with _trace.span("compile", bucket=self._blabel(key), path="local",
@@ -1241,9 +1228,7 @@ class GramEngine:
             cfg["levels"], (M, N), jnp.dtype(dtype),
             mode=resolve_mode(cfg["mode"]), gram_of=gram_of,
             leaf=cfg["leaf"], variant=cfg["variant"], block=cfg["block"],
-            out_dtype=self.out_dtype, operand_dtype=cfg.get("operand_dtype"),
-            pipeline_depth=cfg.get("pipeline_depth"),
-            interpret=self.interpret)
+            out_dtype=self.out_dtype, operand_dtype=cfg.get("operand_dtype"))
 
     # -- cost-model drift ---------------------------------------------------
     def _drift_prediction(self, key, cfg) -> Optional[float]:

@@ -99,7 +99,7 @@ def _updater(levels, leaf, variant, mode, block, interpret):
             from ..kernels.ops import ata_fused_packed
             lv = resolve_levels(levels, chunk.shape, chunk.dtype,
                                 mode="fused", variant=variant, block=block,
-                                out_dtype=packed.dtype, interpret=interpret)
+                                out_dtype=packed.dtype)
             stack = ata_fused_packed(chunk, levels=lv, variant=variant,
                                      bk=block, bn=block,
                                      out_dtype=packed.dtype,
@@ -243,8 +243,7 @@ def stack_update(state: GramStackStream, chunk: jax.Array, *,
                              state.n_padded, gram_of=gram_of,
                              variant=variant, bk=block, bn=state.block,
                              operand_dtype=chunk.dtype,
-                             out_dtype=state.stack.dtype,
-                             interpret=interpret)
+                             out_dtype=state.stack.dtype)
     stack = rank_k_update(state.stack, chunk, levels=levels, variant=variant,
                           gram_of=gram_of, beta=beta, alpha=alpha,
                           bk=block, interpret=interpret)

@@ -181,8 +181,7 @@ def pallas_base_syrk(bk=None, bn=None, interpret=None):
 
 def ata_fused(a, *, levels=2, variant="strassen", gram="strassen", bk=None,
               bn=None, out_dtype=None, interpret=None, bwd="fused",
-              pipeline_depth=None, operand_dtype=None, acc_dtype=None,
-              sr_seed=None):
+              operand_dtype=None, sr_seed=None):
     """Dense ``tril(a.T @ a)`` via the fused leaf-task schedule.
     ``bk``/``bn`` default to the autotune-cache winner for this shape
     bucket (256 when untuned).  ``gram`` picks the registered symmetric
@@ -191,74 +190,65 @@ def ata_fused(a, *, levels=2, variant="strassen", gram="strassen", bk=None,
     (packed-cotangent symm schedule, the default) or ``"dense"`` (the
     classical dense-dot baseline).
 
-    Perf/precision knobs (DESIGN.md §16): ``pipeline_depth`` (revolving
-    DMA buffers, None = backend default), ``operand_dtype`` (fp8/bf16
-    operand tiles, fp32 accumulation), ``acc_dtype`` (VMEM accumulator
-    storage) and ``sr_seed`` (stochastic-rounded bf16 output)."""
+    Precision knobs (DESIGN.md §16): ``operand_dtype`` (fp8/bf16 operand
+    tiles, fp32 accumulation) and ``sr_seed`` (stochastic-rounded bf16
+    output)."""
     bs = _resolve_blocks("ata", a.shape[0], a.shape[1], a.dtype, bk=bk, bn=bn)
     return _ata_fused_jit(a, levels=levels, variant=variant, gram=gram,
                           bk=bs["bk"], bn=bs["bn"], out_dtype=out_dtype,
                           interpret=interpret, bwd=bwd,
-                          pipeline_depth=pipeline_depth,
-                          operand_dtype=operand_dtype, acc_dtype=acc_dtype,
-                          sr_seed=sr_seed)
+                          operand_dtype=operand_dtype, sr_seed=sr_seed)
 
 
 @functools.partial(jax.jit, static_argnames=(
     "levels", "variant", "gram", "bk", "bn", "out_dtype", "interpret",
-    "bwd", "pipeline_depth", "operand_dtype", "acc_dtype", "sr_seed"))
+    "bwd", "operand_dtype", "sr_seed"))
 def _ata_fused_jit(a, *, levels, variant, gram="strassen", bk, bn,
                    out_dtype=None, interpret=None, bwd="fused",
-                   pipeline_depth=None, operand_dtype=None, acc_dtype=None,
-                   sr_seed=None):
+                   operand_dtype=None, sr_seed=None):
     from . import strassen_fused as _sf
     return _sf.fused_ata(a, levels=levels, variant=variant, gram=gram,
                          bk=bk, bn=bn, out_dtype=out_dtype,
                          interpret=_auto_interpret(interpret,
                                                    site="ops.ata_fused"),
-                         bwd=bwd, pipeline_depth=pipeline_depth,
-                         operand_dtype=operand_dtype, acc_dtype=acc_dtype,
+                         bwd=bwd, operand_dtype=operand_dtype,
                          sr_seed=sr_seed)
 
 
 def ata_fused_packed(a, *, levels=2, variant="strassen", gram="strassen",
                      bk=None, bn=None, out_dtype=None, interpret=None,
-                     bwd="fused", pipeline_depth=None, operand_dtype=None,
-                     acc_dtype=None, sr_seed=None):
+                     bwd="fused", operand_dtype=None, sr_seed=None):
     """Packed lower-tri block stack of ``a.T @ a`` via the fused schedule
     (upper-triangular blocks are never computed or written).
     Differentiable: the custom VJP consumes the *packed* cotangent
     directly (``bwd="fused"``) — no dense n^2 buffer in the backward.
-    Same perf/precision knobs as :func:`ata_fused`."""
+    Same precision knobs as :func:`ata_fused`."""
     bs = _resolve_blocks("ata", a.shape[0], a.shape[1], a.dtype, bk=bk, bn=bn)
     return _ata_fused_packed_jit(a, levels=levels, variant=variant,
                                  gram=gram, bk=bs["bk"], bn=bs["bn"],
                                  out_dtype=out_dtype, interpret=interpret,
-                                 bwd=bwd, pipeline_depth=pipeline_depth,
-                                 operand_dtype=operand_dtype,
-                                 acc_dtype=acc_dtype, sr_seed=sr_seed)
+                                 bwd=bwd, operand_dtype=operand_dtype,
+                                 sr_seed=sr_seed)
 
 
 @functools.partial(jax.jit, static_argnames=(
     "levels", "variant", "gram", "bk", "bn", "out_dtype", "interpret",
-    "bwd", "pipeline_depth", "operand_dtype", "acc_dtype", "sr_seed"))
+    "bwd", "operand_dtype", "sr_seed"))
 def _ata_fused_packed_jit(a, *, levels, variant, gram="strassen", bk, bn,
                           out_dtype=None, interpret=None, bwd="fused",
-                          pipeline_depth=None, operand_dtype=None,
-                          acc_dtype=None, sr_seed=None):
+                          operand_dtype=None, sr_seed=None):
     from . import strassen_fused as _sf
     packed, _ = _sf.fused_ata_packed(
         a, levels=levels, variant=variant, gram=gram, bk=bk, bn=bn,
         out_dtype=out_dtype,
         interpret=_auto_interpret(interpret, site="ops.ata_fused_packed"),
-        bwd=bwd, pipeline_depth=pipeline_depth, operand_dtype=operand_dtype,
-        acc_dtype=acc_dtype, sr_seed=sr_seed)
+        bwd=bwd, operand_dtype=operand_dtype, sr_seed=sr_seed)
     return packed
 
 
 def symm_matmul(x, s_packed, *, levels=2, variant="strassen", bm=None,
                 diag_sym=False, out_dtype=None, interpret=None,
-                pipeline_depth=None, operand_dtype=None, acc_dtype=None):
+                operand_dtype=None):
     """``x @ Sym`` where Sym is given only as its packed lower-triangular
     tile stack (``syrk_packed`` / ``ata_fused_packed`` layout; the tile
     edge is read off the stack) — the symm-schedule kernel that powers
@@ -268,32 +258,28 @@ def symm_matmul(x, s_packed, *, levels=2, variant="strassen", bm=None,
     return _symm_matmul_jit(x, s_packed, levels=levels, variant=variant,
                             bm=bs["bm"], diag_sym=diag_sym,
                             out_dtype=out_dtype, interpret=interpret,
-                            pipeline_depth=pipeline_depth,
-                            operand_dtype=operand_dtype, acc_dtype=acc_dtype)
+                            operand_dtype=operand_dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
     "levels", "variant", "bm", "diag_sym", "out_dtype", "interpret",
-    "pipeline_depth", "operand_dtype", "acc_dtype"))
+    "operand_dtype"))
 def _symm_matmul_jit(x, s_packed, *, levels, variant, bm, diag_sym,
-                     out_dtype=None, interpret=None, pipeline_depth=None,
-                     operand_dtype=None, acc_dtype=None):
+                     out_dtype=None, interpret=None, operand_dtype=None):
     from . import strassen_fused as _sf
     return _sf.fused_symm_matmul(
         x, s_packed, levels=levels, variant=variant, bm=bm,
         diag_sym=diag_sym, out_dtype=out_dtype,
         interpret=_auto_interpret(interpret, site="ops.symm_matmul"),
-        pipeline_depth=pipeline_depth, operand_dtype=operand_dtype,
-        acc_dtype=acc_dtype)
+        operand_dtype=operand_dtype)
 
 
 def matmul_fused(a, b, *, levels=2, variant="strassen", bm=None, bk=None,
                  bn=None, trans_a=False, trans_b=False, out_dtype=None,
-                 interpret=None, bwd="fused", pipeline_depth=None,
-                 operand_dtype=None, acc_dtype=None):
+                 interpret=None, bwd="fused", operand_dtype=None):
     """``op(a) @ op(b)`` via the fused Strassen program kernel;
-    ``trans_a``/``trans_b`` transpose an operand *through the index
-    maps* — no transposed HBM copy (the distributed ring/2.5D block
+    ``trans_a``/``trans_b`` transpose an operand *through the tile
+    addresses* — no transposed HBM copy (the distributed ring/2.5D block
     tasks route here).  ``bwd="fused"`` (default) runs both VJP products
     through the same program with the transposes likewise folded."""
     m = a.shape[1] if trans_a else a.shape[0]
@@ -303,33 +289,27 @@ def matmul_fused(a, b, *, levels=2, variant="strassen", bm=None, bk=None,
                              bm=bs["bm"], bk=bs["bk"], bn=bs["bn"],
                              trans_a=trans_a, trans_b=trans_b,
                              out_dtype=out_dtype, interpret=interpret,
-                             bwd=bwd, pipeline_depth=pipeline_depth,
-                             operand_dtype=operand_dtype,
-                             acc_dtype=acc_dtype)
+                             bwd=bwd, operand_dtype=operand_dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
     "levels", "variant", "bm", "bk", "bn", "trans_a", "trans_b",
-    "out_dtype", "interpret", "bwd", "pipeline_depth", "operand_dtype",
-    "acc_dtype"))
+    "out_dtype", "interpret", "bwd", "operand_dtype"))
 def _matmul_fused_jit(a, b, *, levels, variant, bm, bk, bn, trans_a=False,
                       trans_b=False, out_dtype=None, interpret=None,
-                      bwd="fused", pipeline_depth=None, operand_dtype=None,
-                      acc_dtype=None):
+                      bwd="fused", operand_dtype=None):
     from . import strassen_fused as _sf
     return _sf.fused_matmul(a, b, levels=levels, variant=variant, bm=bm,
                             bk=bk, bn=bn, trans_a=trans_a, trans_b=trans_b,
                             out_dtype=out_dtype,
                             interpret=_auto_interpret(
                                 interpret, site="ops.matmul_fused"),
-                            bwd=bwd, pipeline_depth=pipeline_depth,
-                            operand_dtype=operand_dtype,
-                            acc_dtype=acc_dtype)
+                            bwd=bwd, operand_dtype=operand_dtype)
 
 
 def aat_fused(a, *, levels=2, variant="strassen", gram="strassen", bm=None,
-              bk=None, out_dtype=None, interpret=None, pipeline_depth=None,
-              operand_dtype=None, acc_dtype=None, sr_seed=None):
+              bk=None, out_dtype=None, interpret=None, operand_dtype=None,
+              sr_seed=None):
     """Dense ``tril(a @ a.T)`` — the Arrigoni-Massini row gram
     (``ata(x, gram_of="rows")``) via the same leaf-program executor; the
     transpose of ``a`` never exists in HBM."""
@@ -337,32 +317,27 @@ def aat_fused(a, *, levels=2, variant="strassen", gram="strassen", bm=None,
                          bm=bm, bk=bk)
     return _aat_fused_jit(a, levels=levels, variant=variant, gram=gram,
                           bm=bs["bm"], bk=bs["bk"], out_dtype=out_dtype,
-                          interpret=interpret,
-                          pipeline_depth=pipeline_depth,
-                          operand_dtype=operand_dtype, acc_dtype=acc_dtype,
+                          interpret=interpret, operand_dtype=operand_dtype,
                           sr_seed=sr_seed)
 
 
 @functools.partial(jax.jit, static_argnames=(
     "levels", "variant", "gram", "bm", "bk", "out_dtype", "interpret",
-    "pipeline_depth", "operand_dtype", "acc_dtype", "sr_seed"))
+    "operand_dtype", "sr_seed"))
 def _aat_fused_jit(a, *, levels, variant, gram="strassen", bm, bk,
-                   out_dtype=None, interpret=None, pipeline_depth=None,
-                   operand_dtype=None, acc_dtype=None, sr_seed=None):
+                   out_dtype=None, interpret=None, operand_dtype=None,
+                   sr_seed=None):
     from . import strassen_fused as _sf
     return _sf.fused_aat(a, levels=levels, variant=variant, gram=gram,
                          bm=bm, bk=bk, out_dtype=out_dtype,
                          interpret=_auto_interpret(interpret,
                                                    site="ops.aat_fused"),
-                         pipeline_depth=pipeline_depth,
-                         operand_dtype=operand_dtype, acc_dtype=acc_dtype,
-                         sr_seed=sr_seed)
+                         operand_dtype=operand_dtype, sr_seed=sr_seed)
 
 
 def aat_fused_packed(a, *, levels=2, variant="strassen", gram="strassen",
                      bm=None, bk=None, out_dtype=None, interpret=None,
-                     pipeline_depth=None, operand_dtype=None,
-                     acc_dtype=None, sr_seed=None):
+                     operand_dtype=None, sr_seed=None):
     """Packed lower-tri block stack of ``a @ a.T`` (row-gram dual of
     :func:`ata_fused_packed`)."""
     bs = _resolve_blocks("aat", a.shape[0], a.shape[1], a.dtype,
@@ -370,32 +345,29 @@ def aat_fused_packed(a, *, levels=2, variant="strassen", gram="strassen",
     return _aat_fused_packed_jit(a, levels=levels, variant=variant,
                                  gram=gram, bm=bs["bm"], bk=bs["bk"],
                                  out_dtype=out_dtype, interpret=interpret,
-                                 pipeline_depth=pipeline_depth,
                                  operand_dtype=operand_dtype,
-                                 acc_dtype=acc_dtype, sr_seed=sr_seed)
+                                 sr_seed=sr_seed)
 
 
 @functools.partial(jax.jit, static_argnames=(
     "levels", "variant", "gram", "bm", "bk", "out_dtype", "interpret",
-    "pipeline_depth", "operand_dtype", "acc_dtype", "sr_seed"))
+    "operand_dtype", "sr_seed"))
 def _aat_fused_packed_jit(a, *, levels, variant, gram="strassen", bm, bk,
-                          out_dtype=None, interpret=None,
-                          pipeline_depth=None, operand_dtype=None,
-                          acc_dtype=None, sr_seed=None):
+                          out_dtype=None, interpret=None, operand_dtype=None,
+                          sr_seed=None):
     from . import strassen_fused as _sf
     packed, _ = _sf.fused_aat_packed(
         a, levels=levels, variant=variant, gram=gram, bm=bm, bk=bk,
         out_dtype=out_dtype,
         interpret=_auto_interpret(interpret, site="ops.aat_fused_packed"),
-        pipeline_depth=pipeline_depth, operand_dtype=operand_dtype,
-        acc_dtype=acc_dtype, sr_seed=sr_seed)
+        operand_dtype=operand_dtype, sr_seed=sr_seed)
     return packed
 
 
 def rank_k_update(c_stack, a, *, levels=2, variant="strassen",
                   gram="strassen", gram_of="cols", beta=1.0, alpha=1.0,
                   bk=None, out_dtype=None, interpret=None, donate=True,
-                  pipeline_depth=None, operand_dtype=None, acc_dtype=None):
+                  operand_dtype=None):
     """``C <- beta C + alpha tril(a.T @ a)`` (``gram_of="rows"``:
     ``tril(a @ a.T)``) on a packed tile stack in ONE kernel — the
     accumulating (rank-k) program.  The stack, scaled by ``beta``, seeds
@@ -408,26 +380,22 @@ def rank_k_update(c_stack, a, *, levels=2, variant="strassen",
     fn = _rank_k_jit_donated if donate else _rank_k_jit
     return fn(c_stack, a, beta, alpha, levels=levels, variant=variant,
               gram=gram, gram_of=gram_of, bk=bs["bk"], out_dtype=out_dtype,
-              interpret=interpret, pipeline_depth=pipeline_depth,
-              operand_dtype=operand_dtype, acc_dtype=acc_dtype)
+              interpret=interpret, operand_dtype=operand_dtype)
 
 
 def _rank_k_impl(c_stack, a, beta, alpha, *, levels, variant,
                  gram="strassen", gram_of="cols", bk, out_dtype=None,
-                 interpret=None, pipeline_depth=None, operand_dtype=None,
-                 acc_dtype=None):
+                 interpret=None, operand_dtype=None):
     from . import strassen_fused as _sf
     return _sf.fused_rank_k_update(
         c_stack, a, levels=levels, variant=variant, gram=gram,
         gram_of=gram_of, beta=beta, alpha=alpha, bk=bk, out_dtype=out_dtype,
         interpret=_auto_interpret(interpret, site="ops.rank_k_update"),
-        pipeline_depth=pipeline_depth, operand_dtype=operand_dtype,
-        acc_dtype=acc_dtype)
+        operand_dtype=operand_dtype)
 
 
 _rank_k_static = ("levels", "variant", "gram", "gram_of", "bk", "out_dtype",
-                  "interpret", "pipeline_depth", "operand_dtype",
-                  "acc_dtype")
+                  "interpret", "operand_dtype")
 _rank_k_jit = jax.jit(_rank_k_impl, static_argnames=_rank_k_static)
 _rank_k_jit_donated = jax.jit(_rank_k_impl, static_argnames=_rank_k_static,
                               donate_argnums=(0,))

@@ -7,20 +7,22 @@ and which side transposes in VMEM.  This module rewrites them as a
 single executor driven by the :mod:`repro.core.leaf_ir` IR: a
 ``LeafProgram`` (kind x levels x algebra table) is bound to tile sizes
 (:class:`_Spec`), lowered to int32 scalar-prefetch tables, and executed
-by ONE scalar-prefetch ``pallas_call`` whose grid enumerates
-``(output tile, contribution slot, K block)``.  Per grid step the kernel
+by ONE scalar-prefetch ``pallas_call`` whose grid enumerates the output
+tiles.  Per output tile the kernel walks the (real contribution, K
+block) sweep of its destination, and per step it
 
-  1. gathers up to ``max_terms`` stored tiles per side straight from HBM
-     (the prefetched tables drive the BlockSpec index maps — pad /
-     concatenate / transpose copies of the reference recursions become
-     index arithmetic),
+  1. copies the contribution's real stored tiles per side straight from
+     HBM into double-buffered VMEM (the prefetched tables give the tile
+     addresses — pad / concatenate / transpose copies of the reference
+     recursions become index arithmetic; the next step's copies stream
+     in while this step computes),
   2. forms the +-1-signed operand sums tile-wise in VMEM, applying the
      per-term tri-mirror transposes (packed symm operand) and the
      whole-side transposes (ATA's left, AAT's right, trans_a/trans_b),
   3. runs the leaf product on the MXU into an fp32 VMEM accumulator that
-     lives across the whole (contribution, K) sweep of one output tile
-     — seeded from the incoming packed stack for accumulating (rank-k)
-     programs instead of zero,
+     lives across the whole sweep of one output tile — seeded from the
+     incoming packed stack for accumulating (rank-k) programs instead of
+     zero,
   4. writes each output tile to HBM exactly once — packed
      lower-triangular stack for gram kinds, dense grid otherwise.
 
@@ -43,7 +45,7 @@ keeping the dense-dot baselines selectable for benchmarking.
 
 The analytic HBM traffic model is likewise IR-driven: :func:`_traffic`
 scores a bound :class:`_Spec` (reads = the real tile fetches of the
-depth>=2 walk, with the padded grid beside them; writes = one store per
+walk, with the padded table grid beside them; writes = one store per
 output tile), and
 the per-kind models (``ata_traffic_model`` etc.) are thin geometry
 wrappers over it — the model shares the executor's binding code, so it
@@ -82,17 +84,16 @@ def _round_up(x: int, mult: int) -> int:
     return -(-x // mult) * mult
 
 
-# VMEM guard: the kernel gathers 2 * max_terms input tiles per grid step
-# (double-buffered by the pipeline).  Each Strassen level doubles the
+# VMEM guard: the kernel gathers 2 * max_terms input tiles per step
+# (double-buffered by the walk).  Each Strassen level doubles the
 # operand fan-in (Winograd can quadruple it), so deep programs are clamped
 # to keep the working set well under per-core VMEM: 2*8 tiles of 256x256
 # fp32 = 4 MB single-buffered.
 MAX_OPERAND_TERMS = 8
 
-# Revolving-buffer depth cap: each extra slot holds another 2*max_terms
-# operand tiles in VMEM, so depth 4 at 256x256 fp32 tiles is already
-# 16 MB of ring — past the point of diminishing overlap returns.
-MAX_PIPELINE_DEPTH = 4
+# Revolving VMEM buffer slots of the walk: one step's tile copies stream
+# in while the previous step computes.
+_BUFFER_SLOTS = 2
 
 # Operand-tile storage dtypes the executor will quantize to.  fp8 tiles
 # halve (vs bf16) / quarter (vs fp32) the DMA traffic per term while the
@@ -120,35 +121,6 @@ def _resolve_operand_dtype(operand_dtype):
             f"operand_dtype={name!r} is not a supported operand-tile "
             f"storage dtype; pick one of {_SUPPORTED_OPERAND_DTYPES}")
     return name
-
-
-def _resolve_acc_dtype(acc_dtype):
-    name = "float32" if acc_dtype is None else jnp.dtype(acc_dtype).name
-    if name not in ("float32", "bfloat16", "float64"):
-        raise ValueError(f"acc_dtype={name!r}: the VMEM accumulator must "
-                         "be float32 (default), bfloat16 or float64")
-    return name
-
-
-def _resolve_pipeline_depth(pipeline_depth, interpret) -> int:
-    """Resolve the ``pipeline_depth`` knob.
-
-    ``None`` picks the backend default: 2 (double buffering — prefetch
-    the next contribution's operand tiles while the current MXU work
-    runs) for compiled kernels, 1 in interpret mode, where the emulator
-    runs DMAs synchronously and revolving buffers only add bookkeeping.
-    Explicit values are always honored (parity tests force 2/3 under
-    interpret).
-    """
-    if pipeline_depth is None:
-        return 1 if interpret else 2
-    depth = int(pipeline_depth)
-    if not 1 <= depth <= MAX_PIPELINE_DEPTH:
-        raise ValueError(
-            f"pipeline_depth must be in [1, {MAX_PIPELINE_DEPTH}], got "
-            f"{pipeline_depth} (each slot rings 2*{MAX_OPERAND_TERMS} "
-            "operand tiles in VMEM)")
-    return depth
 
 
 def _resolve_sr_seed(sr_seed, out_dtype):
@@ -364,13 +336,14 @@ def _matmul_geometry(m: int, k: int, n: int, levels: int, variant: str,
 
 @dataclass(frozen=True)
 class _Spec:
-    """Static binding of a LeafProgram to tiles and a flattened grid.
+    """Static binding of a LeafProgram to tiles and output tiles.
 
-    Grid is uniformly ``(n_out, n_c, n_k)``: output tiles (tri-decoded
-    for packed outputs, row-major ``divmod(t, n_tj)`` for dense), the
-    padded contribution sweep, and the K sweep.  ``q_i``/``q_j`` are
-    output tiles per leaf block along each output dim; ``bi``/``bj`` the
-    output tile edges; ``bc`` the contraction tile edge.
+    The grid is ``(n_out,)``: output tiles (tri-decoded for packed
+    outputs, row-major ``divmod(t, n_tj)`` for dense).  ``n_c`` is the
+    program tables' contribution slots per destination and ``n_k`` the
+    K sweep.  ``q_i``/``q_j`` are output tiles per leaf block along each
+    output dim; ``bi``/``bj`` the output tile edges; ``bc`` the
+    contraction tile edge.
     """
     kind: str
     levels: int
@@ -396,24 +369,20 @@ class _Spec:
     right_tri: bool
     diag_sym: bool
     accumulate: bool
-    pipeline_depth: int = 1     # revolving VMEM buffer slots (1 = grid walk)
-    acc_dtype: str = "float32"  # VMEM accumulator storage dtype (name)
-
-    @property
-    def grid_steps(self) -> int:
-        return self.n_out * self.n_c * self.n_k
 
     @property
     def scope(self) -> str:
         """The kernel's name in HLO metadata and the device trace; the
         rows side of ``rank_k`` is ``rank_k_rows``."""
         kind = self.kind + ("_rows" if self.gram_of == "rows" else "")
-        return f"fused:{kind}:l{self.levels}:{self.variant}:{self.gram}"
+        # ":pd2" keeps the names the device traces have recorded since
+        # the walk was double-buffered
+        return (f"fused:{kind}:l{self.levels}:{self.variant}:{self.gram}"
+                f":pd{_BUFFER_SLOTS}")
 
 
 def _bind(prog: LeafProgram, *, n_out, n_tj, q_i, q_j, n_k, bi, bj, bc,
-          diag_sym=False, pipeline_depth=1,
-          acc_dtype="float32") -> _Spec:
+          diag_sym=False) -> _Spec:
     ls, rs, os_ = prog.left_spec, prog.right_spec, prog.out_spec
     return _Spec(
         kind=prog.kind, levels=prog.levels, variant=prog.variant,
@@ -428,8 +397,7 @@ def _bind(prog: LeafProgram, *, n_out, n_tj, q_i, q_j, n_k, bi, bj, bc,
         out_tri=os_.packing == "tri",
         left_trans=ls.transpose, right_trans=rs.transpose,
         right_tri=rs.layout == "tri",
-        diag_sym=diag_sym, accumulate=os_.accumulate,
-        pipeline_depth=pipeline_depth, acc_dtype=acc_dtype)
+        diag_sym=diag_sym, accumulate=os_.accumulate)
 
 
 # ---------------------------------------------------------------------------
@@ -442,16 +410,14 @@ def _bind(prog: LeafProgram, *, n_out, n_tj, q_i, q_j, n_k, bi, bj, bc,
 # float32 table holds its coefficient (rational gram-algebra
 # coefficients like dps's +-1/2, +-1/4 must survive lowering).  Real
 # entries come first in every row; the padding behind them carries
-# coefficient 0 and indexes block (0, 0).  The depth-1 grid walk fetches
-# those padded slots through its BlockSpecs and multiplies them by 0; the
-# depth>=2 kernel reads the count table and never touches them: per
-# destination ``_dest_count`` words hold its real contributions, and per
-# (destination, contribution) one ``n_left << 16 | n_right`` word its
-# real terms.  Per-term mirrors only ever occur on tri-stored right
-# operands; left per-term trans is asserted unused at lowering — the left
-# side's transposes are whole-operand OperandSpec flags, and transposed
-# gram destinations were normalized into side-swapped contributions at
-# the IR layer.  Accumulating programs take one more float32 table,
+# coefficient 0 and indexes block (0, 0).  The kernel reads the count
+# table and never touches them: per destination ``_dest_count`` words
+# hold its real contributions, and per (destination, contribution) one
+# ``n_left << 16 | n_right`` word its real terms.  Per-term mirrors only
+# ever occur on tri-stored right operands; left per-term trans is
+# asserted unused at lowering — the left side's transposes are
+# whole-operand OperandSpec flags, and transposed gram destinations were
+# normalized into side-swapped contributions at the IR layer.  Accumulating programs take one more float32 table,
 # ``(beta, alpha)``: run-time values, so one executable serves every
 # scale, and beta = alpha = 1 multiplies by exactly 1.
 # ---------------------------------------------------------------------------
@@ -580,8 +546,8 @@ def _tri_term_coords(ridx_ref, ld, c, qt, spec, k, jq):
 
 
 def _left_block(lidx_ref, ld, c, p, k, gi, spec):
-    """Block index of left term ``p`` — shared by the depth-1 index maps
-    and the depth>=2 in-kernel DMAs."""
+    """Block index of left term ``p`` of (destination, contribution) at
+    K step ``k``: the address of its in-kernel copy."""
     row, col, _ = _unpack(lidx_ref[_term(ld, c, p, spec)])
     if spec.left_trans:
         # stored leaf is (contraction, out_i)
@@ -593,7 +559,7 @@ def _right_block(ridx_ref, ld, c, q, k, jq, spec):
     """Block index of right term ``q`` (see :func:`_left_block`)."""
     if spec.right_tri:
         gr, gc, _ = _tri_term_coords(ridx_ref, ld, c, q, spec, k, jq)
-        # the mirror, folded into the index map: always fetch the stored
+        # the mirror, folded into the address: always fetch the stored
         # lower-triangle tile
         fr = jnp.maximum(gr, gc)
         fc = jnp.minimum(gr, gc)
@@ -605,24 +571,19 @@ def _right_block(ridx_ref, ld, c, q, k, jq, spec):
     return row * spec.n_k + k, col * spec.q_j + jq
 
 
-def _signed_sum(term, tmax, n_terms=None):
-    """``term(0) + term(1) + ...`` in term order.
+def _signed_sum(term, tmax, n_terms):
+    """``term(0) + ... + term(n_terms - 1)`` in term order.
 
-    ``n_terms=None`` sums all ``tmax`` slots (the grid walk, whose padded
-    slots hold coefficient 0); otherwise only the first ``n_terms``, a
-    run-time count, so a padded slot — whose buffer holds stale data —
-    is neither read nor added.  The real terms are summed in the same
-    order either way, so both forms agree bit for bit on finite data."""
+    ``n_terms`` is a run-time count, so a padded slot — whose buffer
+    holds stale data — is neither read nor added."""
     def upto(n):
         return lambda: functools.reduce(
             operator.add, [term(p) for p in range(n)])
-    if n_terms is None:
-        return upto(tmax)()
     return jax.lax.switch(n_terms - 1,
                           [upto(n) for n in range(1, tmax + 1)])
 
 
-def _right_sum(tile, ridx_ref, rsgn_ref, ld, c, k, jq, spec, n_terms=None):
+def _right_sum(tile, ridx_ref, rsgn_ref, ld, c, k, jq, spec, n_terms):
     """Signed sum of the right operand's gathered tiles (``tile(q)`` reads
     term ``q``), in fp32 in VMEM (never in HBM), with the tri-stored
     mirrors and the whole-side transpose applied."""
@@ -630,9 +591,9 @@ def _right_sum(tile, ridx_ref, rsgn_ref, ld, c, k, jq, spec, n_terms=None):
         t_ = tile(qt).astype(jnp.float32)
         if spec.right_tri:
             gr, gc, t = _tri_term_coords(ridx_ref, ld, c, qt, spec, k, jq)
-            # the index map fetched the stored (max, min) tile;
-            # transpose in VMEM whenever the conceptual read was above
-            # the diagonal or the term itself was mirrored
+            # the copy fetched the stored (max, min) tile; transpose in
+            # VMEM whenever the conceptual read was above the diagonal
+            # or the term itself was mirrored
             t_ = jnp.where((t != 0) | (gr < gc), t_.T, t_)
             if spec.diag_sym:
                 # the S + S^t operand: diagonal tiles double
@@ -645,7 +606,7 @@ def _right_sum(tile, ridx_ref, rsgn_ref, ld, c, k, jq, spec, n_terms=None):
     return right
 
 
-def _left_sum(tile, lsgn_ref, ld, c, spec, n_terms=None):
+def _left_sum(tile, lsgn_ref, ld, c, spec, n_terms):
     """Signed sum of the left operand's gathered tiles (see
     :func:`_right_sum`)."""
     left = _signed_sum(
@@ -657,17 +618,6 @@ def _left_sum(tile, lsgn_ref, ld, c, spec, n_terms=None):
     return left.T if spec.left_trans else left
 
 
-def _seed(acc_ref, cin_ref, scale_ref):
-    """The accumulator's start: the incoming tile times ``beta`` for
-    accumulating programs, zero otherwise."""
-    if cin_ref is None:
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        return
-    wide = jnp.promote_types(acc_ref.dtype, jnp.float32)
-    acc_ref[...] = (cin_ref[...].astype(wide)
-                    * scale_ref[0].astype(wide)).astype(acc_ref.dtype)
-
-
 def _coef(sign_ref, scale_ref, ld, c, spec):
     """A contribution's coefficient: its sign, times ``alpha`` for
     accumulating programs."""
@@ -675,46 +625,10 @@ def _coef(sign_ref, scale_ref, ld, c, spec):
     return sgn * scale_ref[1] if spec.accumulate else sgn
 
 
-def _leaf_kernel(sign_ref, lidx_ref, lsgn_ref, ridx_ref, rsgn_ref, *refs,
-                 spec: _Spec):
-    tmax = spec.tmax
-    scale_ref = None
-    if spec.accumulate:
-        scale_ref, refs = refs[0], refs[1:]
-    l_refs = refs[:tmax]
-    r_refs = refs[tmax:2 * tmax]
-    cin_ref = refs[2 * tmax] if spec.accumulate else None
-    o_ref, acc_ref = refs[-2], refs[-1]
-    t, c, k = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    gi, gj = _decode_out(t, spec)
-    ld = _dest_ld(gi, gj, spec)
-    jq = gj % spec.q_j
-    sgn = sign_ref[_slot(ld, c, spec)]
-
-    @pl.when((c == 0) & (k == 0))
-    def _init():
-        # rank-k: the running packed stack seeds the accumulator — the
-        # incoming C is read once per tile, never re-materialized
-        _seed(acc_ref, cin_ref, scale_ref)
-
-    @pl.when(sgn != 0)
-    def _accumulate():
-        left = _left_sum(lambda p: l_refs[p][...], lsgn_ref, ld, c, spec)
-        right = _right_sum(lambda q: r_refs[q][...], ridx_ref, rsgn_ref,
-                           ld, c, k, jq, spec)
-        contrib = _coef(sign_ref, scale_ref, ld, c, spec) * jnp.dot(
-            left, right, preferred_element_type=jnp.float32)
-        acc_ref[...] += contrib.astype(acc_ref.dtype)
-
-    @pl.when((c == spec.n_c - 1) & (k == spec.n_k - 1))
-    def _store():
-        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
-
-
-def _pipelined_kernel(sign_ref, lidx_ref, lsgn_ref, ridx_ref, rsgn_ref,
-                      cnt_ref, *refs, spec: _Spec, l_shape, r_shape):
-    """Depth>=2 executor body: one grid step per output tile; the
-    (contribution, K) sweep runs in-kernel behind a revolving-buffer
+def _walk_kernel(sign_ref, lidx_ref, lsgn_ref, ridx_ref, rsgn_ref, cnt_ref,
+                 *refs, spec: _Spec, l_shape, r_shape):
+    """The executor body: one grid step per output tile; the
+    (contribution, K) sweep runs in-kernel behind a double-buffered
     manual-DMA pipeline (DESIGN.md §16).
 
     Real-entry walk: the tile's destination has ``n_real`` real
@@ -726,21 +640,16 @@ def _pipelined_kernel(sign_ref, lidx_ref, lsgn_ref, ridx_ref, rsgn_ref,
     ``q < n_right``, read from the same count word): a wait without its
     start would hang the core.
 
-    Slot protocol: step ``s`` computes out of slot ``s % depth`` while
-    the copies for step ``s + depth - 1`` stream into slot
-    ``(s + depth - 1) % depth`` — the slot whose compute retired at step
-    ``s - 1`` (the sweep is sequential per tile), so a buffer is never
-    overwritten while in use.  The flattened step order
-    ``s = c * n_k + k`` reproduces the depth-1 grid walk (k fastest) and
-    the terms are summed in table order, so the accumulation order — and
-    therefore the result, on finite operands — is bit-exact vs
-    ``pipeline_depth=1``, whose padded slots only ever add +-0.  The
-    epilogue contract is unchanged: the accumulator is seeded (from
-    ``beta * c_in`` for accumulating programs) before the sweep and
-    stored exactly once after it.
+    Slot protocol: step ``s`` computes out of slot ``s % 2`` while the
+    copies for step ``s + 1`` stream into the other slot — the slot
+    whose compute retired at step ``s - 1`` (the sweep is sequential per
+    tile), so a buffer is never overwritten while in use.  The step
+    order is ``s = c * n_k + k`` (K fastest) and the terms are summed in
+    table order.  The accumulator is fp32, seeded (from ``beta * c_in``
+    for accumulating programs) before the sweep and stored exactly once
+    after it.
     """
-    depth, tmax = spec.pipeline_depth, spec.tmax
-    n_k = spec.n_k
+    slots, n_k = _BUFFER_SLOTS, spec.n_k
     scale_ref = None
     if spec.accumulate:
         scale_ref, refs = refs[0], refs[1:]
@@ -757,7 +666,7 @@ def _pipelined_kernel(sign_ref, lidx_ref, lsgn_ref, ridx_ref, rsgn_ref,
 
     def _copy(s, side, p):
         """Async copy of term ``p`` of one side of step ``s``."""
-        slot = s % depth
+        slot = s % slots
         c, k = s // n_k, s % n_k
         if side == 0:
             br, bc_ = _left_block(lidx_ref, ld, c, p, k, gi, spec)
@@ -777,7 +686,7 @@ def _pipelined_kernel(sign_ref, lidx_ref, lsgn_ref, ridx_ref, rsgn_ref,
         through."""
         counts = _term_counts(cnt_ref, ld, s // n_k, spec)
         for side in (0, 1):
-            for p in range(tmax):
+            for p in range(spec.tmax):
                 @pl.when(p < counts[side])
                 def _():
                     getattr(_copy(s, side, p), op)()
@@ -788,17 +697,22 @@ def _pipelined_kernel(sign_ref, lidx_ref, lsgn_ref, ridx_ref, rsgn_ref,
     def _wait(s):
         _each_real_copy(s, "wait")
 
-    _seed(acc_ref, cin_ref, scale_ref)
+    # rank-k: the running packed stack seeds the accumulator — the
+    # incoming C is read once per tile, never re-materialized
+    if cin_ref is None:
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+    else:
+        acc_ref[...] = cin_ref[...].astype(jnp.float32) * scale_ref[0]
 
-    for i in range(depth - 1):                     # pipeline warm-up
+    for i in range(slots - 1):                     # pipeline warm-up
         pl.when(i < n_steps)(functools.partial(_start, i))
 
     def body(s, carry):
-        slot = s % depth
+        slot = s % slots
 
-        @pl.when(s + depth - 1 < n_steps)
+        @pl.when(s + slots - 1 < n_steps)
         def _prefetch():
-            _start(s + depth - 1)
+            _start(s + slots - 1)
 
         _wait(s)
         c, k = s // n_k, s % n_k
@@ -809,7 +723,7 @@ def _pipelined_kernel(sign_ref, lidx_ref, lsgn_ref, ridx_ref, rsgn_ref,
                            ld, c, k, jq, spec, n_right)
         contrib = _coef(sign_ref, scale_ref, ld, c, spec) \
             * jnp.dot(left, right, preferred_element_type=jnp.float32)
-        acc_ref[...] += contrib.astype(acc_ref.dtype)
+        acc_ref[...] += contrib
         return carry
 
     jax.lax.fori_loop(0, n_steps, body, 0)
@@ -836,81 +750,6 @@ def _out_shape_struct(spec: _Spec, out_dtype):
         out_dtype)
 
 
-def _execute_pipelined(spec: _Spec, tables, left, right, out_dtype,
-                       interpret, c_in):
-    """Depth>=2 ``pallas_call`` site: grid = output tiles only; the
-    operands stay in HBM/ANY and the kernel streams their tiles through
-    revolving VMEM buffers with manual async copies (DMA semaphores),
-    overlapping the next step's fetch with the current MXU work.
-
-    Pallas cannot batch a kernel whose operands stay in HBM, so under
-    ``vmap`` (the engine's slot batch) the batch elements run the kernel
-    one after another in a ``lax.map``."""
-    operands = (left, right) if c_in is None else (left, right, c_in)
-
-    @jax.custom_batching.custom_vmap
-    def run(*ops):
-        return _pipelined_call(spec, tables, ops, out_dtype, interpret)
-
-    @run.def_vmap
-    def _run_batched(axis_size, in_batched, *ops):
-        def one(batched):
-            it = iter(batched)
-            return run(*[next(it) if b else x
-                         for x, b in zip(ops, in_batched)])
-        return jax.lax.map(one, [x for x, b in zip(ops, in_batched)
-                                 if b]), True
-
-    return run(*operands)
-
-
-def _pipelined_call(spec: _Spec, tables, operands, out_dtype, interpret):
-    left, right = operands[:2]
-    n_tab = len(tables)
-    depth, tmax = spec.pipeline_depth, spec.tmax
-    l_shape, r_shape = _operand_shapes(spec)
-
-    def out_map(t, *tabs):
-        if spec.out_tri:
-            return (t, 0)
-        return (t // spec.n_tj, t % spec.n_tj)
-
-    in_specs = [pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(memory_space=pl.ANY)]
-    if spec.accumulate:
-        in_specs.append(pl.BlockSpec((spec.bi, spec.bj), out_map))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=n_tab,
-        grid=(spec.n_out,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((spec.bi, spec.bj), out_map),
-        scratch_shapes=[
-            pltpu.VMEM((depth, tmax) + l_shape, left.dtype),
-            pltpu.VMEM((depth, tmax) + r_shape, right.dtype),
-            pltpu.SemaphoreType.DMA((depth, tmax)),
-            pltpu.SemaphoreType.DMA((depth, tmax)),
-            pltpu.VMEM((spec.bi, spec.bj), jnp.dtype(spec.acc_dtype)),
-        ],
-    )
-    with jax.named_scope(f"{spec.scope}:pd{depth}"):
-        return pl.pallas_call(
-            functools.partial(_pipelined_kernel, spec=spec,
-                              l_shape=l_shape, r_shape=r_shape),
-            grid_spec=grid_spec,
-            out_shape=_out_shape_struct(spec, out_dtype),
-            # only the output-tile axis remains a grid axis and its
-            # tiles are independent -> megacore partitions freely; the
-            # sequential sweep lives inside the kernel body.
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel",)),
-            input_output_aliases=_stack_alias(
-                operands[2] if spec.accumulate else None, out_dtype,
-                n_tab + len(operands)),
-            interpret=interpret,
-        )(*tables, *operands)
-
-
 def _stack_alias(c_in, out_dtype, n_args: int) -> dict:
     """Accumulating programs write their result over the incoming stack
     (the call's last argument) when it has the result's dtype: updated
@@ -924,7 +763,7 @@ def _stack_alias(c_in, out_dtype, n_args: int) -> dict:
 def _execute(spec: _Spec, left: jax.Array, right: jax.Array,
              out_dtype, interpret, c_in: Optional[jax.Array] = None,
              scale: Optional[jax.Array] = None):
-    """Run a bound program — the single ``pallas_call`` site.
+    """Run a bound program.
 
     ``left``/``right`` are the padded operand arrays (the same array for
     the one-input gram kinds); ``c_in`` the incoming packed stack and
@@ -932,61 +771,70 @@ def _execute(spec: _Spec, left: jax.Array, right: jax.Array,
     Returns the raw output buffer: the packed tri stack for tri-packed
     programs, the dense (padded) grid otherwise.
 
-    ``spec.pipeline_depth >= 2`` routes to the revolving-buffer DMA
-    pipeline (one grid step per output tile, the (contribution, K) sweep
-    in-kernel); depth 1 keeps the classic 3-axis grid walk.  Both paths
-    accumulate in the same order, so they are bit-exact for a fixed
-    ``acc_dtype``.
+    Pallas cannot batch a kernel whose operands stay in HBM, so under
+    ``vmap`` (the engine's slot batch) the batch elements run the kernel
+    one after another in a ``lax.map``.  The tables are arguments of the
+    batched function, not constants it closes over: the run-time scale
+    may be a traced value.
     """
     tables = _program_tables(spec.kind, spec.levels, spec.variant,
                              spec.gram, spec.trans_a, spec.trans_b,
                              spec.gram_of)
     if spec.accumulate:
         tables = tables + (scale,)
-    if spec.pipeline_depth > 1:
-        return _execute_pipelined(spec, tables, left, right, out_dtype,
-                                  interpret, c_in)
-    # the grid walk visits every padded slot, so it takes no count table
-    tables = tables[:5] + tables[6:]
     n_tab = len(tables)
+    operands = (left, right) if c_in is None else (left, right, c_in)
 
-    def left_map(p):
-        def index_map(t, c, k, sign, lidx, *tabs):
-            gi, gj = _decode_out(t, spec)
-            return _left_block(lidx, _dest_ld(gi, gj, spec), c, p, k, gi,
-                               spec)
-        return index_map
+    @jax.custom_batching.custom_vmap
+    def run(*args):
+        return _walk_call(spec, args[:n_tab], args[n_tab:], out_dtype,
+                          interpret)
 
-    def right_map(q):
-        def index_map(t, c, k, sign, lidx, lsgn, ridx, rsgn, *tabs):
-            gi, gj = _decode_out(t, spec)
-            return _right_block(ridx, _dest_ld(gi, gj, spec), c, q, k,
-                                gj % spec.q_j, spec)
-        return index_map
+    @run.def_vmap
+    def _run_batched(axis_size, in_batched, *args):
+        def one(batched):
+            it = iter(batched)
+            return run(*[next(it) if b else x
+                         for x, b in zip(args, in_batched)])
+        return jax.lax.map(one, [x for x, b in zip(args, in_batched)
+                                 if b]), True
 
-    def out_map(t, c, k, *tabs):
+    return run(*tables, *operands)
+
+
+def _walk_call(spec: _Spec, tables, operands, out_dtype, interpret):
+    """The single ``pallas_call`` site: grid = output tiles only; the
+    operands stay in HBM/ANY and the kernel streams their tiles through
+    double-buffered VMEM with manual async copies (DMA semaphores),
+    overlapping the next step's fetch with the current MXU work."""
+    left, right = operands[:2]
+    n_tab = len(tables)
+    slots, tmax = _BUFFER_SLOTS, spec.tmax
+    l_shape, r_shape = _operand_shapes(spec)
+
+    def out_map(t, *tabs):
         if spec.out_tri:
             return (t, 0)
         return (t // spec.n_tj, t % spec.n_tj)
 
-    l_shape, r_shape = _operand_shapes(spec)
-
-    in_specs = [pl.BlockSpec(l_shape, left_map(p)) for p in range(spec.tmax)]
-    in_specs += [pl.BlockSpec(r_shape, right_map(q))
-                 for q in range(spec.tmax)]
-    operands = [left] * spec.tmax + [right] * spec.tmax
+    in_specs = [pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY)]
     if spec.accumulate:
         # the incoming stack: same tile walk as the output
         in_specs.append(pl.BlockSpec((spec.bi, spec.bj), out_map))
-        operands.append(c_in)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=n_tab,
-        grid=(spec.n_out, spec.n_c, spec.n_k),
+        grid=(spec.n_out,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((spec.bi, spec.bj), out_map),
-        scratch_shapes=[pltpu.VMEM((spec.bi, spec.bj),
-                                   jnp.dtype(spec.acc_dtype))],
+        scratch_shapes=[
+            pltpu.VMEM((slots, tmax) + l_shape, left.dtype),
+            pltpu.VMEM((slots, tmax) + r_shape, right.dtype),
+            pltpu.SemaphoreType.DMA((slots, tmax)),
+            pltpu.SemaphoreType.DMA((slots, tmax)),
+            pltpu.VMEM((spec.bi, spec.bj), jnp.float32),
+        ],
     )
     # named_scope: the bound program's identity (kind/levels/variant)
     # lands in the HLO metadata of the pallas_call, so profiler traces
@@ -994,16 +842,18 @@ def _execute(spec: _Spec, left: jax.Array, right: jax.Array,
     # that produced it (DESIGN.md §14)
     with jax.named_scope(spec.scope):
         return pl.pallas_call(
-            functools.partial(_leaf_kernel, spec=spec),
+            functools.partial(_walk_kernel, spec=spec,
+                              l_shape=l_shape, r_shape=r_shape),
             grid_spec=grid_spec,
             out_shape=_out_shape_struct(spec, out_dtype),
-            # output tiles (t) are independent -> megacore partitions
-            # them; the (contribution, K) sweep carries the VMEM
-            # accumulator and must stay sequential per tile.
+            # only the output-tile axis is a grid axis and its tiles are
+            # independent -> megacore partitions freely; the sequential
+            # sweep lives inside the kernel body.
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary", "arbitrary")),
-            input_output_aliases=_stack_alias(c_in, out_dtype,
-                                              n_tab + len(operands)),
+                dimension_semantics=("parallel",)),
+            input_output_aliases=_stack_alias(
+                operands[2] if spec.accumulate else None, out_dtype,
+                n_tab + len(operands)),
             interpret=interpret,
         )(*tables, *operands)
 
@@ -1023,9 +873,7 @@ def fused_ata_packed(
     out_dtype=None,
     interpret=None,
     bwd: str = "fused",
-    pipeline_depth=None,
     operand_dtype=None,
-    acc_dtype=None,
     sr_seed=None,
 ):
     """Packed lower-triangular block stack of ``tril(a.T @ a)`` via the
@@ -1051,17 +899,13 @@ def fused_ata_packed(
     classical dense-dot baseline (unpack + ``A @ (S + S^t)``) for
     benchmarking.
 
-    Perf/precision knobs (DESIGN.md §16): ``pipeline_depth`` revolving
-    DMA buffer slots (None = backend default: 2 compiled, 1 interpret);
-    ``operand_dtype`` quantizes the stored operand tiles (fp8/bf16)
-    while accumulation stays in ``acc_dtype`` (fp32 default);
+    Precision knobs (DESIGN.md §16): ``operand_dtype`` quantizes the
+    stored operand tiles (fp8/bf16) while accumulation stays in fp32;
     ``sr_seed`` stochastically rounds a bf16 output (deterministic per
     seed, unbiased in expectation).
     """
     interpret = _auto_interpret(interpret, site="fused_ata_packed")
-    depth = _resolve_pipeline_depth(pipeline_depth, interpret)
     op_dt = _resolve_operand_dtype(operand_dtype)
-    acc_dt = _resolve_acc_dtype(acc_dtype)
     m, n = a.shape
     geo = _ata_geometry(m, n, levels, variant, bk, bn, gram=gram)
     out_dtype = (jnp.promote_types(a.dtype, jnp.float32)
@@ -1069,35 +913,29 @@ def fused_ata_packed(
     sr = _resolve_sr_seed(sr_seed, out_dtype)
     core_out = jnp.dtype(jnp.float32) if sr is not None else out_dtype
     packed = _fused_ata_packed_core(a, levels, variant, gram, bk, bn,
-                                    core_out, interpret, bwd, depth,
-                                    op_dt, acc_dt)
+                                    core_out, interpret, bwd, op_dt)
     if sr is not None:
         packed = stochastic_round_bf16(packed, jax.random.PRNGKey(sr))
     return packed, geo["N"]
 
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11))
+                   nondiff_argnums=(1, 2, 3, 4, 5, 6, 7, 8, 9))
 def _fused_ata_packed_core(a, levels, variant, gram, bk, bn, out_dtype,
-                           interpret, bwd, pipeline_depth, operand_dtype,
-                           acc_dtype):
+                           interpret, bwd, operand_dtype):
     return _fused_ata_packed_exec(a, levels, variant, gram, bk, bn,
-                                  out_dtype, interpret, pipeline_depth,
-                                  operand_dtype, acc_dtype)[0]
+                                  out_dtype, interpret, operand_dtype)[0]
 
 
 def _fused_ata_packed_fwd(a, levels, variant, gram, bk, bn, out_dtype,
-                          interpret, bwd, pipeline_depth, operand_dtype,
-                          acc_dtype):
+                          interpret, bwd, operand_dtype):
     return (_fused_ata_packed_core(a, levels, variant, gram, bk, bn,
                                    out_dtype, interpret, bwd,
-                                   pipeline_depth, operand_dtype,
-                                   acc_dtype), a)
+                                   operand_dtype), a)
 
 
 def _fused_ata_packed_bwd(levels, variant, gram, bk, bn, out_dtype,
-                          interpret, bwd, pipeline_depth, operand_dtype,
-                          acc_dtype, a, gp):
+                          interpret, bwd, operand_dtype, a, gp):
     # vdot(gp, packed(A)) has S = block-lower cotangent (diagonal tiles
     # full — the forward computes them full), so dA = A (S + S^t): the
     # packed stack *is* S and feeds the symm executor directly.
@@ -1112,8 +950,7 @@ def _fused_ata_packed_bwd(levels, variant, gram, bk, bn, out_dtype,
     else:
         da = fused_symm_matmul(a, gp, levels=levels, variant=variant,
                                bm=bk, diag_sym=True, out_dtype=acc,
-                               interpret=interpret,
-                               pipeline_depth=pipeline_depth)[:, :n]
+                               interpret=interpret)[:, :n]
     return (da.astype(a.dtype),)
 
 
@@ -1129,9 +966,7 @@ def _fused_ata_packed_exec(
     bn: int,
     out_dtype,
     interpret,
-    pipeline_depth: int = 1,
     operand_dtype=None,
-    acc_dtype: str = "float32",
 ):
     """Forward executor (no autodiff surface — see the custom VJP above)."""
     m, n = a.shape
@@ -1148,8 +983,7 @@ def _fused_ata_packed_exec(
         # the low precision; every compute upcasts tile-wise to fp32
         a = a.astype(jnp.dtype(operand_dtype))
     spec = _bind(plan, n_out=geo["n_tri"], n_tj=0, q_i=geo["nbt"],
-                 q_j=geo["nbt"], n_k=geo["n_k"], bi=bn, bj=bn, bc=bk,
-                 pipeline_depth=pipeline_depth, acc_dtype=acc_dtype)
+                 q_j=geo["nbt"], n_k=geo["n_k"], bi=bn, bj=bn, bc=bk)
     return _execute(spec, a, a, out_dtype, interpret), N
 
 
@@ -1164,9 +998,7 @@ def fused_ata(
     out_dtype=None,
     interpret=None,
     bwd: str = "fused",
-    pipeline_depth=None,
     operand_dtype=None,
-    acc_dtype=None,
     sr_seed=None,
 ) -> jax.Array:
     """Dense ``tril(a.T @ a)`` at the original size via the fused pipeline.
@@ -1179,32 +1011,30 @@ def fused_ata(
     the product runs the same leaf-program pipeline as the forward.
     ``bwd="dense"`` keeps the classical ``jnp.dot(a, s + s.T)`` baseline.
 
-    Accepts the same perf/precision knobs as :func:`fused_ata_packed`:
-    ``pipeline_depth``, ``operand_dtype``, ``acc_dtype``, ``sr_seed``.
+    Accepts the same precision knobs as :func:`fused_ata_packed`:
+    ``operand_dtype`` and ``sr_seed``.
     """
     interpret = _auto_interpret(interpret, site="fused_ata")
-    depth = _resolve_pipeline_depth(pipeline_depth, interpret)
     op_dt = _resolve_operand_dtype(operand_dtype)
-    acc_dt = _resolve_acc_dtype(acc_dtype)
     out_dtype = (jnp.promote_types(a.dtype, jnp.float32)
                  if out_dtype is None else jnp.dtype(out_dtype))
     sr = _resolve_sr_seed(sr_seed, out_dtype)
     core_out = jnp.dtype(jnp.float32) if sr is not None else out_dtype
     out = _fused_ata_dense(a, levels, variant, gram, bk, bn, core_out,
-                           interpret, bwd, depth, op_dt, acc_dt)
+                           interpret, bwd, op_dt)
     if sr is not None:
         out = stochastic_round_bf16(out, jax.random.PRNGKey(sr))
     return out
 
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11))
+                   nondiff_argnums=(1, 2, 3, 4, 5, 6, 7, 8, 9))
 def _fused_ata_dense(a, levels, variant, gram, bk, bn, out_dtype, interpret,
-                     bwd, pipeline_depth, operand_dtype, acc_dtype):
+                     bwd, operand_dtype):
     n = a.shape[1]
     packed, n_pad = _fused_ata_packed_exec(
         a, levels, variant, gram, bk, bn, out_dtype, interpret,
-        pipeline_depth, operand_dtype, acc_dtype)
+        operand_dtype)
     with jax.named_scope("gram:unpack"):
         dense = unpack_tril_blocks(packed, n_pad, bn, symmetrize=False)
         # diagonal blocks are computed full — drop their upper halves
@@ -1212,11 +1042,9 @@ def _fused_ata_dense(a, levels, variant, gram, bk, bn, out_dtype, interpret,
 
 
 def _fused_ata_dense_fwd(a, levels, variant, gram, bk, bn, out_dtype,
-                         interpret, bwd, pipeline_depth, operand_dtype,
-                         acc_dtype):
+                         interpret, bwd, operand_dtype):
     return (_fused_ata_dense(a, levels, variant, gram, bk, bn, out_dtype,
-                             interpret, bwd, pipeline_depth, operand_dtype,
-                             acc_dtype), a)
+                             interpret, bwd, operand_dtype), a)
 
 
 def _pack_cotangent(g: jax.Array, n: int, n_pad: int, bn: int) -> jax.Array:
@@ -1244,8 +1072,7 @@ def _pack_cotangent(g: jax.Array, n: int, n_pad: int, bn: int) -> jax.Array:
 
 
 def _fused_ata_dense_bwd(levels, variant, gram, bk, bn, out_dtype, interpret,
-                         bwd, pipeline_depth, operand_dtype, acc_dtype,
-                         a, g):
+                         bwd, operand_dtype, a, g):
     # C = tril(A^t A) => dL/dA = A (S + S^t), S = tril(dL/dC); the factor
     # 2 on the diagonal of S + S^t is exactly the quadratic term's.
     acc = jnp.promote_types(a.dtype, jnp.float32)
@@ -1258,8 +1085,7 @@ def _fused_ata_dense_bwd(levels, variant, gram, bk, bn, out_dtype, interpret,
         sp = _pack_cotangent(g.astype(acc), n, geo["N"], bn)
         da = fused_symm_matmul(a, sp, levels=geo["levels"], variant=variant,
                                bm=bk, diag_sym=True, out_dtype=acc,
-                               interpret=interpret,
-                               pipeline_depth=pipeline_depth)[:, :n]
+                               interpret=interpret)[:, :n]
     return (da.astype(a.dtype),)
 
 
@@ -1269,8 +1095,8 @@ _fused_ata_dense.defvjp(_fused_ata_dense_fwd, _fused_ata_dense_bwd)
 # ---------------------------------------------------------------------------
 # Fused AAT: C = tril(A A^t) — the Arrigoni-Massini (2021) row-gram
 # recursion, compiled from the same IR.  The transpose of A never exists
-# in HBM: the right side reads the SAME stored A tiles mirrored through
-# the index maps and flips the gathered sum in VMEM.
+# in HBM: the right side reads the SAME stored A tiles at mirrored
+# addresses and flips the gathered sum in VMEM.
 # ---------------------------------------------------------------------------
 
 def fused_aat_packed(
@@ -1283,9 +1109,7 @@ def fused_aat_packed(
     bk: int = 256,
     out_dtype=None,
     interpret=None,
-    pipeline_depth=None,
     operand_dtype=None,
-    acc_dtype=None,
     sr_seed=None,
 ):
     """Packed lower-triangular block stack of ``tril(a @ a.T)``.
@@ -1295,12 +1119,10 @@ def fused_aat_packed(
     exact: zero columns add nothing to A A^t, zero rows add zero
     rows/columns to C that the dense wrapper slices away.
 
-    Accepts the same perf/precision knobs as :func:`fused_ata_packed`.
+    Accepts the same precision knobs as :func:`fused_ata_packed`.
     """
     interpret = _auto_interpret(interpret, site="fused_aat_packed")
-    depth = _resolve_pipeline_depth(pipeline_depth, interpret)
     op_dt = _resolve_operand_dtype(operand_dtype)
-    acc_dt = _resolve_acc_dtype(acc_dtype)
     m, n = a.shape
     geo = _aat_geometry(m, n, levels, variant, bm, bk, gram=gram)
     plan = geo["plan"]
@@ -1314,8 +1136,7 @@ def fused_aat_packed(
     if op_dt is not None:
         a = a.astype(jnp.dtype(op_dt))
     spec = _bind(plan, n_out=geo["n_tri"], n_tj=0, q_i=geo["nbt"],
-                 q_j=geo["nbt"], n_k=geo["n_k"], bi=bm, bj=bm, bc=bk,
-                 pipeline_depth=depth, acc_dtype=acc_dt)
+                 q_j=geo["nbt"], n_k=geo["n_k"], bi=bm, bj=bm, bc=bk)
     packed = _execute(spec, a, a, core_out, interpret)
     if sr is not None:
         packed = stochastic_round_bf16(packed, jax.random.PRNGKey(sr))
@@ -1332,9 +1153,7 @@ def fused_aat(
     bk: int = 256,
     out_dtype=None,
     interpret=None,
-    pipeline_depth=None,
     operand_dtype=None,
-    acc_dtype=None,
     sr_seed=None,
 ) -> jax.Array:
     """Dense ``tril(a @ a.T)`` at the original size via the fused
@@ -1347,46 +1166,40 @@ def fused_aat(
     Accepts the same perf/precision knobs as :func:`fused_ata_packed`.
     """
     interpret = _auto_interpret(interpret, site="fused_aat")
-    depth = _resolve_pipeline_depth(pipeline_depth, interpret)
     op_dt = _resolve_operand_dtype(operand_dtype)
-    acc_dt = _resolve_acc_dtype(acc_dtype)
     out_dtype = (jnp.promote_types(a.dtype, jnp.float32)
                  if out_dtype is None else jnp.dtype(out_dtype))
     sr = _resolve_sr_seed(sr_seed, out_dtype)
     core_out = jnp.dtype(jnp.float32) if sr is not None else out_dtype
     out = _fused_aat_dense(a, levels, variant, gram, bm, bk, core_out,
-                           interpret, depth, op_dt, acc_dt)
+                           interpret, op_dt)
     if sr is not None:
         out = stochastic_round_bf16(out, jax.random.PRNGKey(sr))
     return out
 
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(1, 2, 3, 4, 5, 6, 7, 8, 9, 10))
+                   nondiff_argnums=(1, 2, 3, 4, 5, 6, 7, 8))
 def _fused_aat_dense(a, levels, variant, gram, bm, bk, out_dtype, interpret,
-                     pipeline_depth, operand_dtype, acc_dtype):
+                     operand_dtype):
     m = a.shape[0]
     packed, m_pad = fused_aat_packed(a, levels=levels, variant=variant,
                                      gram=gram, bm=bm, bk=bk,
                                      out_dtype=out_dtype,
                                      interpret=interpret,
-                                     pipeline_depth=pipeline_depth,
-                                     operand_dtype=operand_dtype,
-                                     acc_dtype=acc_dtype)
+                                     operand_dtype=operand_dtype)
     dense = unpack_tril_blocks(packed, m_pad, bm, symmetrize=False)
     return jnp.tril(dense)[:m, :m]
 
 
 def _fused_aat_dense_fwd(a, levels, variant, gram, bm, bk, out_dtype,
-                         interpret, pipeline_depth, operand_dtype,
-                         acc_dtype):
+                         interpret, operand_dtype):
     return (_fused_aat_dense(a, levels, variant, gram, bm, bk, out_dtype,
-                             interpret, pipeline_depth, operand_dtype,
-                             acc_dtype), a)
+                             interpret, operand_dtype), a)
 
 
 def _fused_aat_dense_bwd(levels, variant, gram, bm, bk, out_dtype, interpret,
-                         pipeline_depth, operand_dtype, acc_dtype, a, g):
+                         operand_dtype, a, g):
     # C = tril(A A^t) => dA = (S + S^t) A, S = tril(g)
     acc = jnp.promote_types(a.dtype, jnp.float32)
     s = jnp.tril(g).astype(acc)
@@ -1418,9 +1231,7 @@ def fused_rank_k_update(
     bk: int = 256,
     out_dtype=None,
     interpret=None,
-    pipeline_depth=None,
     operand_dtype=None,
-    acc_dtype=None,
 ) -> jax.Array:
     """``C <- beta C + alpha tril(a.T @ a)`` on a packed lower-triangular
     tile stack (``gram_of="rows"``: ``tril(a @ a.T)``).
@@ -1443,15 +1254,12 @@ def fused_rank_k_update(
     packed cotangent (DESIGN.md §11) — no dense n^2 buffer in either
     direction.
 
-    ``pipeline_depth``/``operand_dtype``/``acc_dtype`` as in
-    :func:`fused_ata_packed`; ``operand_dtype`` quantizes only the
+    ``operand_dtype`` as in :func:`fused_ata_packed`; it quantizes only the
     incoming ``a`` — the running stack seeds the accumulator at its own
     precision, so streamed state never degrades.
     """
     interpret = _auto_interpret(interpret, site="fused_rank_k_update")
-    depth = _resolve_pipeline_depth(pipeline_depth, interpret)
     op_dt = _resolve_operand_dtype(operand_dtype)
-    acc_dt = _resolve_acc_dtype(acc_dtype)
     if gram_of not in ("cols", "rows"):
         raise ValueError(f"gram_of must be 'cols' or 'rows', got {gram_of!r}")
     if c_stack.ndim != 2 or a.ndim != 2:
@@ -1474,23 +1282,21 @@ def fused_rank_k_update(
                        jnp.asarray(alpha, jnp.float32)])
     return _fused_rank_k_core(c_stack, a, scale, levels, variant, gram,
                               gram_of, bk, bn, out_dtype,
-                              jnp.dtype(c_stack.dtype), interpret, depth,
-                              op_dt, acc_dt)
+                              jnp.dtype(c_stack.dtype), interpret, op_dt)
 
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11, 12, 13, 14))
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11, 12))
 def _fused_rank_k_core(c_stack, a, scale, levels, variant, gram, gram_of,
                        bk, bn, out_dtype, stack_dtype, interpret,
-                       pipeline_depth, operand_dtype, acc_dtype):
+                       operand_dtype):
     return _fused_rank_k_exec(c_stack, a, scale, levels, variant, gram,
                               gram_of, bk, bn, out_dtype, interpret,
-                              pipeline_depth, operand_dtype, acc_dtype)
+                              operand_dtype)
 
 
 def _fused_rank_k_exec(c_stack, a, scale, levels, variant, gram, gram_of,
-                       bk, bn, out_dtype, interpret, pipeline_depth=1,
-                       operand_dtype=None, acc_dtype="float32"):
+                       bk, bn, out_dtype, interpret, operand_dtype=None):
     n_tri = c_stack.shape[0] // bn
     T = (math.isqrt(8 * n_tri + 1) - 1) // 2
     N = T * bn
@@ -1506,25 +1312,21 @@ def _fused_rank_k_exec(c_stack, a, scale, levels, variant, gram, gram_of,
     if operand_dtype is not None:
         a = a.astype(jnp.dtype(operand_dtype))
     spec = _bind(plan, n_out=geo["n_tri"], n_tj=0, q_i=geo["nbt"],
-                 q_j=geo["nbt"], n_k=geo["n_k"], bi=bn, bj=bn, bc=bk,
-                 pipeline_depth=pipeline_depth, acc_dtype=acc_dtype)
+                 q_j=geo["nbt"], n_k=geo["n_k"], bi=bn, bj=bn, bc=bk)
     return _execute(spec, a, a, out_dtype, interpret, c_in=c_stack,
                     scale=scale)
 
 
 def _fused_rank_k_fwd(c_stack, a, scale, levels, variant, gram, gram_of, bk,
-                      bn, out_dtype, stack_dtype, interpret, pipeline_depth,
-                      operand_dtype, acc_dtype):
+                      bn, out_dtype, stack_dtype, interpret, operand_dtype):
     out = _fused_rank_k_core(c_stack, a, scale, levels, variant, gram,
                              gram_of, bk, bn, out_dtype, stack_dtype,
-                             interpret, pipeline_depth, operand_dtype,
-                             acc_dtype)
+                             interpret, operand_dtype)
     return out, (a, scale)
 
 
 def _fused_rank_k_bwd(levels, variant, gram, gram_of, bk, bn, out_dtype,
-                      stack_dtype, interpret, pipeline_depth, operand_dtype,
-                      acc_dtype, res, g):
+                      stack_dtype, interpret, operand_dtype, res, g):
     # C_out = beta C_in + alpha P, P = tril(A^t A) (rows: tril(A A^t)) as
     # the stack holds it: dC_in = beta g (packed pass-through, cast back
     # to the stack primal's dtype); dA = alpha A (S + S^t) with S the
@@ -1540,8 +1342,7 @@ def _fused_rank_k_bwd(levels, variant, gram, gram_of, bk, bn, out_dtype,
                           gram_of=gram_of)["levels"]
     da = fused_symm_matmul(x, g, levels=lv, variant=variant, bm=bk,
                            diag_sym=True, out_dtype=acc,
-                           interpret=interpret,
-                           pipeline_depth=pipeline_depth)[:, :n]
+                           interpret=interpret)[:, :n]
     da = (scale[1].astype(acc) * da).astype(a.dtype)
     dc = scale[0] * g.astype(jnp.float32)
     return (dc.astype(stack_dtype), (da.T if rows else da),
@@ -1568,9 +1369,7 @@ def fused_symm_matmul(
     diag_sym: bool = False,
     out_dtype=None,
     interpret=None,
-    pipeline_depth=None,
     operand_dtype=None,
-    acc_dtype=None,
 ) -> jax.Array:
     """``x @ Sym`` via the flattened symm program, one fused kernel.
 
@@ -1593,14 +1392,11 @@ def fused_symm_matmul(
     transposes live in VMEM only, fp32 VMEM accumulation, one HBM write
     per output tile, no dense Sym (or S + S^t) buffer ever exists.
 
-    ``pipeline_depth``/``operand_dtype``/``acc_dtype`` as in
-    :func:`fused_ata_packed` (``operand_dtype`` quantizes both ``x`` and
-    the packed stack).
+    ``operand_dtype`` as in :func:`fused_ata_packed` (it quantizes both
+    ``x`` and the packed stack).
     """
     interpret = _auto_interpret(interpret, site="fused_symm_matmul")
-    depth = _resolve_pipeline_depth(pipeline_depth, interpret)
     op_dt = _resolve_operand_dtype(operand_dtype)
-    acc_dt = _resolve_acc_dtype(acc_dtype)
     if x.ndim != 2 or s_packed.ndim != 2:
         raise ValueError(f"bad ranks: {x.shape} x packed {s_packed.shape}")
     bs = s_packed.shape[1]
@@ -1631,8 +1427,7 @@ def fused_symm_matmul(
         x = x.astype(jnp.dtype(op_dt))
         s_packed = s_packed.astype(jnp.dtype(op_dt))
     spec = _bind(plan, n_out=(M // bm) * T, n_tj=T, q_i=nbm, q_j=q,
-                 n_k=q, bi=bm, bj=bs, bc=bs, diag_sym=diag_sym,
-                 pipeline_depth=depth, acc_dtype=acc_dt)
+                 n_k=q, bi=bm, bj=bs, bc=bs, diag_sym=diag_sym)
     out = _execute(spec, x, s_packed, out_dtype, interpret)
     return out[:m]
 
@@ -1662,16 +1457,16 @@ def _dest_tiles(spec: _Spec, n_dest: int) -> np.ndarray:
 
 def _traffic(spec: _Spec, *, left_bytes: int, right_bytes: int,
              out_bytes: int, cin_bytes: int = 0) -> dict:
-    """Core HBM model of one bound program, as the depth>=2 kernel walks
-    it: per output tile, ``n_k`` steps for each real contribution of its
+    """Core HBM model of one bound program, as the kernel walks it: per
+    output tile, ``n_k`` steps for each real contribution of its
     destination, each fetching that contribution's real left and right
     terms (the count table the kernel reads); one write per output tile;
     plus the incoming stack read for accumulating programs.
 
-    ``padded_grid_steps`` / ``padded_read_bytes`` are the padded grid
-    the depth-1 walk visits (every contribution slot, ``tmax`` tiles a
-    side), and ``skipped_fetch_share`` the share of that grid's tile
-    fetches the depth>=2 walk does not issue."""
+    ``padded_grid_steps`` / ``padded_read_bytes`` are the padded table
+    grid (every contribution slot, ``tmax`` tiles a side), and
+    ``skipped_fetch_share`` the share of that grid's tile fetches the
+    walk does not issue."""
     counts = _program_tables(spec.kind, spec.levels, spec.variant,
                              spec.gram, spec.trans_a, spec.trans_b,
                              spec.gram_of)[5]
@@ -1681,7 +1476,7 @@ def _traffic(spec: _Spec, *, left_bytes: int, right_bytes: int,
     steps = int(tiles @ counts[:, 0]) * spec.n_k
     n_left = int(tiles @ (counts[:, 1:] >> _COUNT_BITS).sum(1)) * spec.n_k
     n_right = int(tiles @ (counts[:, 1:] & _COUNT_MASK).sum(1)) * spec.n_k
-    padded = spec.grid_steps
+    padded = spec.n_out * spec.n_c * spec.n_k
     l_tile = spec.bi * spec.bc * left_bytes
     r_tile = ((spec.bj * spec.bj) if spec.right_tri
               else spec.bj * spec.bc) * right_bytes
@@ -1689,8 +1484,8 @@ def _traffic(spec: _Spec, *, left_bytes: int, right_bytes: int,
                    if spec.accumulate else 0)
     writes = spec.n_out * spec.bi * spec.bj * out_bytes
     # MXU work per step: one (bi, bc) x (bc, bj) leaf product (the VPU
-    # gather adds are second-order) — feeds the pipelined occupancy term
-    # in cost_model.pipelined_bytes_score
+    # gather adds are second-order) — feeds the compute term of
+    # cost_model.pipelined_bytes_score
     flops = 2 * steps * spec.bi * spec.bc * spec.bj
     return {
         "grid_steps": steps,
@@ -1802,8 +1597,8 @@ def ata_bwd_traffic_model(
     ``S + S^t`` (add) — three dense N^2 buffers.  An
     ``hbm_intermediate_census`` of its compiled HLO lands near this
     (XLA fusion may materialize fewer; the packed entry's unpack scatter
-    adds more).  The fused read term counts the real-entry walk of the
-    depth>=2 kernel, same as the forward model.
+    adds more).  The fused read term counts the kernel's real-entry
+    walk, same as the forward model.
     """
     geo = _ata_geometry(m, n, levels, variant, bk, bn, gram=gram)
     M, N = geo["M"], geo["N"]
@@ -1841,7 +1636,7 @@ def matmul_traffic_model(
     out_bytes: int = 4,
 ) -> dict:
     """HBM bytes of ``fused_matmul`` for ``op(A) (m, k) @ op(B) (k, n)``
-    (the transposes change index maps, not fetch counts)."""
+    (the transposes change tile addresses, not fetch counts)."""
     geo = _matmul_geometry(m, k, n, levels, variant, bm, bk, bn)
     M, K, N = geo["M"], geo["K"], geo["N"]
     spec = _bind(geo["plan"], n_out=(M // bm) * (N // bn), n_tj=N // bn,
@@ -1865,18 +1660,18 @@ def auto_levels(kind: str, m: int, n: int, *, k: Optional[int] = None,
                 gram_of: str = "cols", variant: str = "strassen",
                 gram: str = "strassen", bm: int = 256, bk: int = 256,
                 bn: int = 256, operand_dtype=jnp.float32,
-                out_dtype=jnp.float32, pipeline_depth=None,
-                interpret=None) -> int:
+                out_dtype=jnp.float32) -> int:
     """The Strassen depth ``levels="auto"`` runs on a fused call: the
     cheapest of depths 0 to ``AUTO_MAX_LEVELS`` by the executor's own
-    traffic model, scored with ``cost_model.pipelined_bytes_score`` at
-    the pipeline depth the call will run.  Depths whose scores lie within
-    ``AUTO_TIE_SHARE`` of the best tie, and the shallowest of them wins.
-    Depths past the VMEM fan-in or SMEM table limits are not tried.
+    traffic model, scored with ``cost_model.pipelined_bytes_score``.
+    Depths whose scores lie within ``AUTO_TIE_SHARE`` of the best tie,
+    and the shallowest of them wins.  Depths past the VMEM fan-in or
+    SMEM table limits are not tried.
 
-    The choice rests on the shape, the dtypes and the tiles alone.  The
-    executor recomputes a Strassen product once for each destination it
-    feeds, so on today's walk a deeper program reads more tile bytes
+    The choice rests on the shape, the dtypes and the tiles alone, so
+    interpret mode picks what the chip picks.  The executor recomputes
+    a Strassen product once for each destination it feeds, so on
+    today's walk a deeper program reads more tile bytes
     than it saves in products, and the rule picks classical blocking; a
     schedule that makes Strassen cheaper in bytes is picked by the same
     rule.  The depth that ran is recorded in the kernel's scope name
@@ -1894,19 +1689,16 @@ def auto_levels(kind: str, m: int, n: int, *, k: Optional[int] = None,
         raise ValueError(f"no auto depth for kind {kind!r}")
     if kind == "matmul" and k is None:
         raise ValueError("matmul needs the contraction length k")
-    depth = _resolve_pipeline_depth(
-        pipeline_depth, _auto_interpret(interpret, site="auto_levels"))
     tiles = {"ata": (bk, bn), "aat": (bm, bk), "rank_k": (bk, bn),
              "matmul": (bm, bk, bn)}[kind]
     return _auto_levels(kind, m, n, k, gram_of, variant, gram, tiles,
                         jnp.dtype(operand_dtype).itemsize,
-                        jnp.dtype(out_dtype).itemsize, depth,
-                        AUTO_MAX_LEVELS)
+                        jnp.dtype(out_dtype).itemsize, AUTO_MAX_LEVELS)
 
 
 @functools.lru_cache(maxsize=None)
 def _auto_levels(kind, m, n, k, gram_of, variant, gram, tiles, in_bytes,
-                 out_bytes, depth, max_levels) -> int:
+                 out_bytes, max_levels) -> int:
     from ..core.cost_model import pipelined_bytes_score
 
     def model(levels):
@@ -1933,7 +1725,7 @@ def _auto_levels(kind, m, n, k, gram_of, variant, gram, tiles, in_bytes,
         t = model(levels)
         scores.append(pipelined_bytes_score(
             t["read_bytes"] + t["intermediate_bytes"], t["write_bytes"],
-            t["flops"], pipeline_depth=depth, grid_steps=t["grid_steps"]))
+            t["flops"], grid_steps=t["grid_steps"]))
     best = min(scores)
     return next(lv for lv, sc in enumerate(scores)
                 if sc <= best * (1 + AUTO_TIE_SHARE))
@@ -1960,13 +1752,11 @@ def fused_matmul(
     out_dtype=None,
     interpret=None,
     bwd: str = "fused",
-    pipeline_depth=None,
     operand_dtype=None,
-    acc_dtype=None,
 ) -> jax.Array:
     """``op(a) @ op(b)`` via the flattened Strassen program, one fused
     kernel; ``op`` transposes when the flag is set — folded into the
-    BlockSpec index maps (mirrored tile fetches), so no transposed copy
+    tile addresses (mirrored tile fetches), so no transposed copy
     of an operand ever exists in HBM.  The engine of the distributed
     ring/2.5D block tasks (``core.distributed``), which are all
     ``A_loc^t @ A_perm`` products.
@@ -1978,7 +1768,7 @@ def fused_matmul(
 
     Differentiable: ``bwd="fused"`` (default) runs both VJP products
     through the same program executor with the transposes folded into
-    the index maps, so the backward costs what the forward costs.
+    the tile addresses, so the backward costs what the forward costs.
     ``bwd="dense"`` keeps the classical ``jnp.dot`` VJP.
     """
     if a.ndim != 2 or b.ndim != 2:
@@ -1990,21 +1780,17 @@ def fused_matmul(
             f"bad shapes for matmul: {a.shape} x {b.shape} "
             f"(trans_a={trans_a}, trans_b={trans_b})")
     interpret = _auto_interpret(interpret, site="fused_matmul")
-    depth = _resolve_pipeline_depth(pipeline_depth, interpret)
     op_dt = _resolve_operand_dtype(operand_dtype)
-    acc_dt = _resolve_acc_dtype(acc_dtype)
     out_dtype = (jnp.promote_types(jnp.promote_types(a.dtype, b.dtype),
                                    jnp.float32)
                  if out_dtype is None else jnp.dtype(out_dtype))
     return _fused_matmul_core(a, b, levels, variant, bm, bk, bn, trans_a,
-                              trans_b, out_dtype, interpret, bwd, depth,
-                              op_dt, acc_dt)
+                              trans_b, out_dtype, interpret, bwd, op_dt)
 
 
 def _fused_matmul_exec(a, b, levels, variant, bm, bk, bn, out_dtype,
                        interpret, trans_a=False, trans_b=False,
-                       pipeline_depth=1, operand_dtype=None,
-                       acc_dtype="float32"):
+                       operand_dtype=None):
     """Executor binding for C = op(a) @ op(b)."""
     m, k_dim = a.shape[::-1] if trans_a else a.shape
     n, _ = b.shape if trans_b else b.shape[::-1]
@@ -2024,37 +1810,29 @@ def _fused_matmul_exec(a, b, levels, variant, bm, bk, bn, out_dtype,
 
     nbm, nbn = mb // bm, nb // bn
     spec = _bind(plan, n_out=(M // bm) * (N // bn), n_tj=N // bn,
-                 q_i=nbm, q_j=nbn, n_k=kb // bk, bi=bm, bj=bn, bc=bk,
-                 pipeline_depth=pipeline_depth, acc_dtype=acc_dtype)
+                 q_i=nbm, q_j=nbn, n_k=kb // bk, bi=bm, bj=bn, bc=bk)
     out = _execute(spec, a, b, out_dtype, interpret)
     return out[:m, :n]
 
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(2, 3, 4, 5, 6, 7, 8, 9, 10, 11, 12, 13,
-                                    14))
+                   nondiff_argnums=(2, 3, 4, 5, 6, 7, 8, 9, 10, 11, 12))
 def _fused_matmul_core(a, b, levels, variant, bm, bk, bn, trans_a, trans_b,
-                       out_dtype, interpret, bwd, pipeline_depth,
-                       operand_dtype, acc_dtype):
+                       out_dtype, interpret, bwd, operand_dtype):
     return _fused_matmul_exec(a, b, levels, variant, bm, bk, bn, out_dtype,
                               interpret, trans_a=trans_a, trans_b=trans_b,
-                              pipeline_depth=pipeline_depth,
-                              operand_dtype=operand_dtype,
-                              acc_dtype=acc_dtype)
+                              operand_dtype=operand_dtype)
 
 
 def _fused_matmul_fwd(a, b, levels, variant, bm, bk, bn, trans_a, trans_b,
-                      out_dtype, interpret, bwd, pipeline_depth,
-                      operand_dtype, acc_dtype):
+                      out_dtype, interpret, bwd, operand_dtype):
     return (_fused_matmul_core(a, b, levels, variant, bm, bk, bn, trans_a,
                                trans_b, out_dtype, interpret, bwd,
-                               pipeline_depth, operand_dtype, acc_dtype),
-            (a, b))
+                               operand_dtype), (a, b))
 
 
 def _fused_matmul_bwd(levels, variant, bm, bk, bn, trans_a, trans_b,
-                      out_dtype, interpret, bwd, pipeline_depth,
-                      operand_dtype, acc_dtype, res, g):
+                      out_dtype, interpret, bwd, operand_dtype, res, g):
     a, b = res
     acc = jnp.promote_types(jnp.promote_types(a.dtype, b.dtype), jnp.float32)
     gf = g.astype(acc)
@@ -2072,13 +1850,12 @@ def _fused_matmul_bwd(levels, variant, bm, bk, bn, trans_a, trans_b,
             db = db.T
     else:
         # the VJP products are themselves matmul programs with the
-        # transposes folded into the index maps (the kernel upcasts
+        # transposes folded into the tile addresses (the kernel upcasts
         # tile-wise in VMEM, so bf16 residuals feed the backward
         # without an HBM-wide fp32 copy):
         ex = functools.partial(_fused_matmul_exec, levels=levels,
                                variant=variant, out_dtype=acc,
-                               interpret=interpret,
-                               pipeline_depth=pipeline_depth)
+                               interpret=interpret)
         if not trans_a and not trans_b:
             # da = g b^t; db = a^t g
             da = ex(gf, b, bm=bm, bk=bn, bn=bk, trans_b=True)

@@ -30,19 +30,21 @@ def _rand(shape, seed=0, dtype=jnp.float32):
 
 
 def _model(kind, m, n, levels, *, k=None, gram_of="cols", in_bytes=4,
-           out_bytes=4):
+           out_bytes=4, tile=256):
     if kind == "ata":
         return sf.ata_traffic_model(m, n, levels=levels, in_bytes=in_bytes,
-                                    out_bytes=out_bytes)
+                                    out_bytes=out_bytes, bk=tile, bn=tile)
     if kind == "aat":
         return sf.aat_traffic_model(m, n, levels=levels, in_bytes=in_bytes,
-                                    out_bytes=out_bytes)
+                                    out_bytes=out_bytes, bm=tile, bk=tile)
     if kind == "rank_k":
         return sf.rank_k_traffic_model(m, n, levels=levels, gram_of=gram_of,
                                        in_bytes=in_bytes,
-                                       state_bytes=out_bytes)
+                                       state_bytes=out_bytes, bk=tile,
+                                       bn=tile)
     return sf.matmul_traffic_model(m, k, n, levels=levels,
-                                   in_bytes=in_bytes, out_bytes=out_bytes)
+                                   in_bytes=in_bytes, out_bytes=out_bytes,
+                                   bm=tile, bk=tile, bn=tile)
 
 
 def _argmin(scores):
@@ -60,18 +62,22 @@ def _argmin(scores):
     ("matmul", 5000, 5000, 5000, "cols", jnp.float32),
     ("matmul", 1024, 3072, 2048, "cols", jnp.bfloat16),
 ])
-@pytest.mark.parametrize("depth", [1, 2])
+@pytest.mark.parametrize("tile", [256, 128])
 def test_auto_is_the_traffic_model_argmin(kind, m, n, k, gram_of, in_dt,
-                                          depth):
+                                          tile):
+    """At the default tile and at a block size the autotuner passes; the
+    depths tried stop where the fan-in or SMEM clamp stops them."""
     in_bytes = jnp.dtype(in_dt).itemsize
     scores = []
-    for lv in range(AUTO_MAX_LEVELS + 1):
-        t = _model(kind, m, n, lv, k=k, gram_of=gram_of, in_bytes=in_bytes)
+    for lv in range(sf._fan_in_depth(kind, AUTO_MAX_LEVELS, "strassen")
+                    + 1):
+        t = _model(kind, m, n, lv, k=k, gram_of=gram_of, in_bytes=in_bytes,
+                   tile=tile)
         scores.append(pipelined_bytes_score(
             t["read_bytes"] + t["intermediate_bytes"], t["write_bytes"],
-            t["flops"], pipeline_depth=depth, grid_steps=t["grid_steps"]))
+            t["flops"], grid_steps=t["grid_steps"]))
     got = sf.auto_levels(kind, m, n, k=k, gram_of=gram_of,
-                         operand_dtype=in_dt, pipeline_depth=depth)
+                         operand_dtype=in_dt, bm=tile, bk=tile, bn=tile)
     assert got == _argmin(scores)
 
 
@@ -91,7 +97,7 @@ def test_auto_tie_goes_to_fewer_levels(monkeypatch, scores, want):
     monkeypatch.setattr(sf, "ata_traffic_model", fake)
     sf._auto_levels.cache_clear()
     try:
-        assert sf.auto_levels("ata", 4096, 4096, pipeline_depth=2) == want
+        assert sf.auto_levels("ata", 4096, 4096) == want
     finally:
         sf._auto_levels.cache_clear()
 
@@ -107,10 +113,10 @@ def test_auto_tie_goes_to_fewer_levels(monkeypatch, scores, want):
 ])
 def test_the_cells_shapes_resolve_to_classical_blocking(kind, m, n, gram_of,
                                                         in_dt):
-    """At 256 tiles and the compiled pipeline depth, a deeper program
-    reads more bytes on every benchmark shape."""
-    assert sf.auto_levels(kind, m, n, gram_of=gram_of, operand_dtype=in_dt,
-                          interpret=False) <= 1
+    """At 256 tiles a deeper program reads more bytes on every benchmark
+    shape."""
+    assert sf.auto_levels(kind, m, n, gram_of=gram_of,
+                          operand_dtype=in_dt) <= 1
 
 
 def test_reference_mode_keeps_the_natural_depth():
@@ -145,7 +151,7 @@ def test_fused_ata_runs_the_policy_depth(monkeypatch):
     seen = _spy(monkeypatch, ops, "ata_fused")
     a = _rand((512, 512), seed=2)
     got = ata(a, levels="auto", mode="fused", interpret=True)
-    assert seen == [sf.auto_levels("ata", 512, 512, interpret=True)]
+    assert seen == [sf.auto_levels("ata", 512, 512)]
     np.testing.assert_allclose(np.asarray(got), np.tril(np.asarray(a.T @ a)),
                                rtol=1e-4, atol=1e-3)
 
@@ -155,8 +161,8 @@ def test_fused_strassen_matmul_runs_the_policy_depth(monkeypatch):
     a, b = _rand((40, 24), seed=3), _rand((24, 56), seed=4)
     got = strassen_matmul(a, b, levels="auto", mode="fused", block=8,
                           interpret=True)
-    assert seen == [sf.auto_levels("matmul", 40, 56, k=24, bm=8, bk=8, bn=8,
-                                   interpret=True)]
+    assert seen == [sf.auto_levels("matmul", 40, 56, k=24, bm=8, bk=8,
+                                   bn=8)]
     np.testing.assert_allclose(np.asarray(got), np.asarray(a @ b),
                                rtol=1e-4, atol=1e-4)
 
@@ -169,7 +175,7 @@ def test_stack_update_runs_the_policy_depth(monkeypatch, gram_of):
     st = stream.stack_update(st, chunk, levels="auto", block=8,
                              gram_of=gram_of, interpret=True)
     assert seen == [sf.auto_levels("rank_k", 40, 64, gram_of=gram_of, bk=8,
-                                   bn=16, interpret=True)]
+                                   bn=16)]
     c = np.asarray(chunk, np.float64)
     want = c @ c.T if gram_of == "rows" else c.T @ c
     np.testing.assert_allclose(np.asarray(stream.stack_finalize(st, 64)),
@@ -186,7 +192,7 @@ def test_distributed_gram_default_follows_the_policy(monkeypatch):
     a = _rand((512, 256), seed=6)
     got = distributed_gram(a, mesh, scheme="allreduce", mode="fused",
                            out_dtype=jnp.float32, interpret=True)
-    assert seen == [sf.auto_levels("ata", 512, 256, interpret=True)]
+    assert seen == [sf.auto_levels("ata", 512, 256)]
     c = np.asarray(a, np.float64)
     np.testing.assert_allclose(np.asarray(got), c.T @ c, rtol=1e-4,
                                atol=1e-3)

@@ -53,7 +53,7 @@ def test_fused_ata_matches_oracle(m, n, levels):
     assert np.abs(np.triu(np.asarray(got), 1)).max() == 0.0
 
 
-@pytest.mark.parametrize("levels", [1, 2])
+@pytest.mark.parametrize("levels", [0, 1, 2])
 def test_fused_ata_odd_rectangular(levels):
     a = _rand((257, 511), seed=7)
     got = fused_ata(a, levels=levels, bk=64, bn=64, interpret=True)

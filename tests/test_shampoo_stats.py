@@ -62,15 +62,14 @@ def test_ir_scaled_accumulate(levels, beta, alpha, gram_of):
 @pytest.mark.parametrize("beta,alpha", SCALES)
 @pytest.mark.parametrize("levels", [0, 1, 2, 3])
 def test_executor_scaled_accumulate(levels, beta, alpha, gram_of):
-    """The executor (pipelined walk, depth 2) on a ragged operand whose
-    Gram side is narrower than the stack: beta * C + alpha * tril(Gram)
-    against float64 numpy."""
+    """The executor on a ragged operand whose Gram side is narrower than
+    the stack: beta * C + alpha * tril(Gram) against float64 numpy."""
     t = 8
     a = _rand((40, 56), seed=levels)
     c0 = _rand((t * (t + 1) // 2 * TILE, TILE), seed=10 + levels)
     out = fused_rank_k_update(c0, a, levels=levels, gram_of=gram_of,
                               beta=beta, alpha=alpha, bk=TILE,
-                              interpret=True, pipeline_depth=2)
+                              interpret=True)
     g = _gram64(a, gram_of)
     want = beta * _dense(c0, t * TILE)
     want[:g.shape[0], :g.shape[0]] += alpha * np.tril(g)
@@ -78,19 +77,20 @@ def test_executor_scaled_accumulate(levels, beta, alpha, gram_of):
     assert np.abs(got - want).max() / np.abs(want).max() < 1e-6
 
 
-@pytest.mark.parametrize("depth", [1, 2])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("gram_of", ["cols", "rows"])
 @pytest.mark.parametrize("levels", [0, 1, 2, 3])
 def test_unit_scales_are_the_plain_accumulation_bit_for_bit(levels,
-                                                            gram_of, depth):
+                                                            gram_of, dtype):
     """beta = alpha = 1 is the plain rank-k update: from a zero stack it
     is the non-accumulating program's stack bit for bit (ata for the
     columns side, aat for the rows side), and from any stack it is the
-    update with the scales left at their defaults."""
-    a = _rand((64, 64), seed=levels + 20)
+    update with the scales left at their defaults — on fp32 rows and on
+    the bf16 rows the streamed normal equations feed."""
+    a = _rand((64, 64), seed=levels + 20).astype(dtype)
     t = 8
     zero = jnp.zeros((t * (t + 1) // 2 * TILE, TILE), jnp.float32)
-    kw = dict(levels=levels, interpret=True, pipeline_depth=depth)
+    kw = dict(levels=levels, interpret=True)
     got = fused_rank_k_update(zero, a, gram_of=gram_of, beta=1.0,
                               alpha=1.0, bk=TILE, **kw)
     if gram_of == "rows":
